@@ -50,6 +50,7 @@ import dataclasses
 import math
 
 import jax
+from jax.extend.core import Literal
 
 from repro.analysis import Finding
 from repro.analysis.jaxpr_lints import _eqn_subjaxprs
@@ -91,14 +92,14 @@ class _Interp:
     # -- facts ------------------------------------------------------------
 
     def fact(self, env, v) -> Fact:
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, Literal):
             return Fact(const=_scalar(v.val))
         return env.get(v, _EMPTY)
 
     @staticmethod
     def _ident(env, v):
         """The provenance identity of ``v``: its fact origin, else itself."""
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, Literal):
             return None
         f = env.get(v)
         return f.origin if f is not None and f.origin is not None else v
@@ -128,7 +129,7 @@ class _Interp:
                 # keep OUTER provenance identity across the boundary so
                 # mask origins still match after re-entering the caller
                 ienv[iv] = f if f.origin is not None or isinstance(
-                    ov, jax.core.Literal) else dataclasses.replace(
+                    ov, Literal) else dataclasses.replace(
                         f, origin=ov)
         self.run_jaxpr(inner, ienv)
         for ov, iv in zip(eqn.outvars, inner.outvars):
@@ -138,7 +139,7 @@ class _Interp:
 
     def _eqn(self, eqn, env, producer) -> None:
         name = eqn.primitive.name
-        if name == "pjit":
+        if name == "jit":
             if eqn.params.get("name") == "take_along_axis":
                 self._jnp_rescore_sink(eqn, env, producer)
             self._recurse(eqn, env)
@@ -351,36 +352,36 @@ class _Interp:
         def scan(jaxpr):
             hit = False
             for e in jaxpr.eqns:
-                lit_neg = any(isinstance(v, jax.core.Literal)
+                lit_neg = any(isinstance(v, Literal)
                               and _scalar(v.val) == _NEG_INF
                               for v in e.invars)
                 if lit_neg or any(v in neginf for v in e.invars
-                                  if not isinstance(v, jax.core.Literal)):
+                                  if not isinstance(v, Literal)):
                     if e.primitive.name in ("broadcast_in_dim",
                                             "convert_element_type"):
                         neginf.update(e.outvars)
                 if e.primitive.name == "select_n":
                     pred = e.invars[0]
                     cases = e.invars[1:]
-                    if (not isinstance(pred, jax.core.Literal)
+                    if (not isinstance(pred, Literal)
                             and pred in derived
-                            and any((not isinstance(c, jax.core.Literal)
+                            and any((not isinstance(c, Literal)
                                      and c in neginf)
-                                    or (isinstance(c, jax.core.Literal)
+                                    or (isinstance(c, Literal)
                                         and _scalar(c.val) == _NEG_INF)
                                     for c in cases)):
                         hit = True
-                if any(not isinstance(v, jax.core.Literal) and v in derived
+                if any(not isinstance(v, Literal) and v in derived
                        for v in e.invars):
                     derived.update(e.outvars)
                 closed = e.params.get("jaxpr")
                 if closed is not None and hasattr(closed, "jaxpr"):
-                    # jnp.where traces as pjit[_where] even inside kernel
+                    # jnp.where traces as jit[_where] even inside kernel
                     # bodies — carry the derived/-inf sets across the call
                     # boundary, then back out to the call's outvars
                     sub = closed.jaxpr
                     for iv, ov in zip(sub.invars, e.invars):
-                        if isinstance(ov, jax.core.Literal):
+                        if isinstance(ov, Literal):
                             if _scalar(ov.val) == _NEG_INF:
                                 neginf.add(iv)
                             continue
@@ -391,7 +392,7 @@ class _Interp:
                     inner_hit = scan(sub)
                     hit = inner_hit or hit
                     for ov, iv in zip(e.outvars, sub.outvars):
-                        if not isinstance(iv, jax.core.Literal):
+                        if not isinstance(iv, Literal):
                             if iv in derived:
                                 derived.add(ov)
                             if iv in neginf:
@@ -451,7 +452,7 @@ def _scalar_operands(eqn, constmap) -> list:
         aval = getattr(v, "aval", None)
         if aval is None or aval.shape:
             continue
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, Literal):
             vals.append(_scalar(v.val))
         elif v in constmap:
             vals.append(_scalar(constmap[v]))
@@ -476,7 +477,7 @@ def check_segment_offsets(label: str, closed_jaxpr) -> list[Finding]:
     base_n = None
     findings: list[Finding] = []
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name != "pjit":
+        if eqn.primitive.name != "jit":
             continue
         name = eqn.params.get("name")
         if name == "_scan_topk" and base_n is None:

@@ -5,7 +5,7 @@ execution, no traffic) on a tiny synthetic corpus shaped to exercise the
 invariant, and the resulting jaxpr is walked statically:
 
   * **fused-dispatch** — the top level of a traced entry point must contain
-    exactly the expected number of *compute dispatches* (pjit eqns whose
+    exactly the expected number of *compute dispatches* (jit eqns whose
     inner jaxpr does real work: dot_general / scan / top_k / pallas_call /
     collectives). ``DenseIndex.search_projected`` and
     ``ShardedDenseIndex.search_projected`` must be ONE; a
@@ -33,17 +33,18 @@ from typing import Callable, Iterable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.analysis import Finding
 
-# primitives that mark a pjit eqn as a real compute dispatch (vs a trivial
-# jnp wrapper like atleast_2d, which also traces as a named pjit)
+# primitives that mark a jit eqn as a real compute dispatch (vs a trivial
+# jnp wrapper like atleast_2d, which also traces as a named jit)
 _COMPUTE_PRIMS = frozenset({
     "dot_general", "scan", "while", "pallas_call", "top_k", "sort",
     "all_gather", "all_reduce", "psum", "reduce_sum", "reduce_max",
     "argmax", "shard_map",
 })
-_DISPATCH_PRIMS = frozenset({"pjit", "xla_call", "pallas_call"})
+_DISPATCH_PRIMS = frozenset({"jit", "pallas_call"})
 # host round-trips that must never appear inside a traced hot path
 _CALLBACK_PRIMS = frozenset({
     "pure_callback", "io_callback", "debug_callback", "debug_print",
@@ -55,12 +56,9 @@ _WIDTH = {"int8": 1, "bfloat16": 2, "float16": 2, "float32": 4,
 
 def iter_all_eqns(jaxpr) -> Iterable:
     """Every eqn of ``jaxpr`` and (recursively) of every sub-jaxpr in eqn
-    params — scan bodies, cond branches, pjit/pallas inner jaxprs."""
-    stack = [jaxpr]
-    while stack:
-        j = stack.pop()
+    params — scan bodies, cond branches, jit/pallas inner jaxprs."""
+    for j in _walk(jaxpr):
         yield from j.eqns
-        stack.extend(jax.core.subjaxprs(j))
 
 
 def _contains_compute(eqn) -> bool:
@@ -76,15 +74,15 @@ def _contains_compute(eqn) -> bool:
 
 def _eqn_subjaxprs(eqn):
     for v in eqn.params.values():
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, Jaxpr):
             yield v
         elif isinstance(v, (tuple, list)):
             for x in v:
-                if isinstance(x, jax.core.ClosedJaxpr):
+                if isinstance(x, ClosedJaxpr):
                     yield x.jaxpr
-                elif isinstance(x, jax.core.Jaxpr):
+                elif isinstance(x, Jaxpr):
                     yield x
 
 
@@ -93,7 +91,8 @@ def _walk(jaxpr):
     while stack:
         j = stack.pop()
         yield j
-        stack.extend(jax.core.subjaxprs(j))
+        for eqn in j.eqns:
+            stack.extend(_eqn_subjaxprs(eqn))
 
 
 def compute_dispatches(fn: Callable, *args) -> list:

@@ -16,9 +16,10 @@ checker closes that gap statically, with no TPU and no execution:
     n + pad_rows`` with ``pad_rows < block_n``, batch and fold likewise.
   * **traced index-map bounds** — best-effort introspection of the traced
     ``pallas_call``: every BlockSpec index map is evaluated at the grid
-    corners and the resulting block windows must lie inside the (padded)
-    operand. Guarded per JAX version; introspection failure degrades to a
-    warn, never a crash.
+    corners and every block window must start inside its operand (the
+    last window may overhang: Pallas clips that edge block and the kernels
+    mask its rows). Guarded per JAX version; introspection failure
+    degrades to a warn, never a crash.
   * **alignment warnings** — lane (128) / sublane (8) misalignment wastes
     VMEM and MXU occupancy without being wrong; reported at warn severity.
 """
@@ -318,10 +319,10 @@ def _grid_corners(grid: Sequence[int]):
 def check_traced_index_maps(label: str, fn: Callable, args: Sequence
                             ) -> list[Finding]:
     """Trace ``fn``, locate its ``pallas_call`` eqns and evaluate every
-    BlockSpec index map at the grid corners: each block window must lie
-    inside its (padded) operand. Introspection details vary across JAX
-    versions, so any failure to introspect degrades to a warn finding
-    rather than an error or a crash."""
+    BlockSpec index map at the grid corners: each block window must start
+    inside its operand (only the edge block may overhang). Introspection
+    details vary across JAX versions, so any failure to introspect
+    degrades to a warn finding rather than an error or a crash."""
     findings: list[Finding] = []
     try:
         jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
@@ -349,10 +350,11 @@ def check_traced_index_maps(label: str, fn: Callable, args: Sequence
                         imap.jaxpr, imap.consts,
                         *(np.int32(c) for c in corner))
                     for ax, (bi, bs) in enumerate(zip(idx, block)):
+                        bs = getattr(bs, "block_size", bs)   # Blocked(n)
                         if bs is None or not isinstance(bs, int):
                             continue
                         start = int(bi) * bs
-                        if start < 0 or start + bs > shape[ax]:
+                        if start < 0 or start >= shape[ax]:
                             findings.append(Finding(
                                 check="pallas.index-map",
                                 where=f"{label}:axis{ax}",

@@ -54,6 +54,7 @@ from repro.core.index import (
     _topk_merge,
     project_queries,
 )
+from repro.kernels.topk_score import PRECISION
 
 
 def _shortlist(cids: jax.Array) -> jax.Array:
@@ -100,7 +101,7 @@ def _cascade_dense_projected(Dc, scale_c, Df, scale_f, W, mean, Q, k: int,
     uids = _shortlist(cids)
     q = qf if scale_f is None else qf * scale_f[None, :]
     rows = jnp.take(Df, jnp.maximum(uids, 0), axis=0)
-    s = q @ rows.T.astype(jnp.float32)
+    s = jnp.dot(q, rows.T.astype(jnp.float32), precision=PRECISION)
     s = jnp.where(uids[None, :] >= 0, s, -jnp.inf)
     return _topk_merge(s, jnp.broadcast_to(uids[None, :], s.shape), k)
 
@@ -118,7 +119,7 @@ def _segment_rescore(D, scale, qf, uids, offset, n_valid):
     local = uids - offset
     valid = (uids >= 0) & (local >= 0) & (local < n_valid)
     rows = jnp.take(D, jnp.clip(local, 0, D.shape[0] - 1), axis=0)
-    s = q @ rows.T.astype(jnp.float32)
+    s = jnp.dot(q, rows.T.astype(jnp.float32), precision=PRECISION)
     return jnp.where(valid[None, :], s, -jnp.inf)
 
 

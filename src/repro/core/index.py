@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.kernels.topk_score import PRECISION
 from repro.par import compat
 
 
@@ -58,7 +59,7 @@ def project_queries(q: jax.Array, W: jax.Array,
     q = jnp.atleast_2d(q).astype(jnp.float32)
     if mean is not None:
         q = q - mean[None, :]
-    q = q @ W
+    q = jnp.dot(q, W, precision=PRECISION)
     if scale is not None:
         q = q * scale[None, :]
     return q
@@ -146,17 +147,17 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
         guard, and ascending-id strips lose first-occurrence ties.
         Skipping on equality is exact for the same ascending-id reason.
 
+    The index is never padded or copied: when ``block`` does not divide
+    n, the last strip slides back to end at row n and masks the rows the
+    previous strip already scored.
+
     ``vma_axes``: when called inside shard_map over those axes, the scan
-    carry must be marked varying (compat.mark_varying) to typecheck on
-    JAX versions with VMA tracking.
+    carry must be marked varying (compat.mark_varying) to typecheck.
     """
     n, d = D.shape
     B = Q.shape[0]
     block = min(block, n)
     nblocks = -(-n // block)
-    pad = nblocks * block - n
-    Dp = jnp.pad(D, ((0, pad), (0, 0))) if pad else D
-    blocks = Dp.reshape(nblocks, block, d)
     Qf = Q.astype(jnp.float32)
     kk = min(k, block)   # strip-local candidate count
 
@@ -164,7 +165,7 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
         # single strip (block == n): the running list is empty, a guard can
         # never fire, and the two-stage detour just adds a second sort —
         # select directly
-        s = Qf @ Dp.T.astype(jnp.float32)
+        s = jnp.dot(Qf, D.T.astype(jnp.float32), precision=PRECISION)
         ids = jnp.broadcast_to(
             jnp.arange(block, dtype=jnp.int32)[None, :], (B, block))
         if k > block:
@@ -176,19 +177,21 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
                 [jnp.full((B, k), -1, jnp.int32), ids], axis=1)
         return _topk_merge(s, ids, k)
 
-    def body(carry, inp):
+    def body(carry, start):
         bs, bi = carry
-        blk, start = inp
-        s = Qf @ blk.T.astype(jnp.float32)                       # (B, block)
-        ids = start + jnp.arange(block, dtype=jnp.int32)[None, :]
-        s = jnp.where(ids < n, s, -jnp.inf)
+        at = jnp.minimum(start, n - block)
+        blk = jax.lax.dynamic_slice_in_dim(D, at, block, axis=0)
+        s = jnp.dot(Qf, blk.T.astype(jnp.float32),
+                    precision=PRECISION)                         # (B, block)
+        ids = at + jnp.arange(block, dtype=jnp.int32)[None, :]
+        s = jnp.where(ids >= start, s, -jnp.inf)
 
         imp = jnp.max(s, axis=1) > jnp.min(bs, axis=1)           # (B,)
 
         def merge(carry_in):
             bs0, bi0 = carry_in
             ss, si = jax.lax.top_k(s, kk)                        # (B, kk)
-            gi = start + si.astype(jnp.int32)
+            gi = at + si.astype(jnp.int32)
             # running list first: at -inf ties its (-1) pads win the
             # first-occurrence tie-break, matching the kernel's pads
             cs = jnp.concatenate([bs0, ss], axis=1)              # (B, k+kk)
@@ -210,7 +213,7 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
     if vma_axes:
         init = compat.mark_varying(init, vma_axes)
     starts = jnp.arange(nblocks, dtype=jnp.int32) * block
-    (scores, ids), _ = jax.lax.scan(body, init, (blocks, starts))
+    (scores, ids), _ = jax.lax.scan(body, init, starts)
     return scores, ids
 
 
@@ -258,15 +261,26 @@ class DenseIndex:
         """Load from an on-disk ``IndexStore`` (path or open handle).
 
         Chunks are memory-mapped and copied to device one at a time — the
-        host never holds a full-index copy beyond the OS page cache.
+        host never holds a full-index copy beyond the OS page cache — and
+        written in place into one preallocated buffer, so the device holds
+        the index plus one chunk, never two copies of the index.
         """
         from repro.core.store import IndexStore
         if isinstance(store, (str, os.PathLike)):
             store = IndexStore.open(store)
         _check_flat_loadable(store)
-        parts = [jnp.asarray(np.ascontiguousarray(c))
-                 for c in store.iter_chunks()]
-        vectors = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+        chunks = list(store.iter_chunks())          # memory maps, not reads
+        vectors = jnp.zeros((sum(map(len, chunks)), chunks[0].shape[1]),
+                            chunks[0].dtype)
+        start = 0
+        for c in chunks:
+            vectors = _fill_rows(vectors, jnp.asarray(np.ascontiguousarray(c)),
+                                 np.int32(start))
+            # dispatch is asynchronous: without the wait every chunk's
+            # upload is queued ahead of its fill, and the queued chunks add
+            # up to a second copy of the index on the device
+            vectors.block_until_ready()
+            start += len(c)
         s = store.scale()
         return cls(vectors=vectors,
                    scale=None if s is None else jnp.asarray(s),
@@ -352,7 +366,7 @@ class ShardedDenseIndex:
     an all-gather of (B, k) scores + ids per shard (k·chips ≪ n).
 
     ``backend`` selects the per-shard scan: 'jnp' (blocked XLA scan) or
-    'pallas' (fused score-and-select kernel — interpreted off-TPU).
+    'pallas' (fused score-and-select kernel).
     ``merge`` selects the global candidate merge: 'flat' (one all-gather
     over every axis, k·ndev candidates per query) or 'hierarchical' (one
     stage per mesh dimension — within the minor axis, then across the
@@ -572,7 +586,8 @@ def _delta_topk(D, scale, Q, n_valid, offset, k: int):
     if scale is not None:
         q = q * scale[None, :]
     cap = D.shape[0]
-    s = q @ D.T.astype(jnp.float32)                          # (B, cap) f32
+    s = jnp.dot(q, D.T.astype(jnp.float32),
+                precision=PRECISION)                         # (B, cap) f32
     ids = jnp.arange(cap, dtype=jnp.int32)[None, :]
     live = ids < n_valid
     s = jnp.where(live, s, -jnp.inf)
@@ -586,6 +601,13 @@ def _delta_update(D, block, start):
     """Patch appended rows into a delta's fixed-capacity buffer — O(rows)
     per append instead of re-uploading the whole capacity. ``start`` is
     traced, so steady-state appends of one block size compile once."""
+    return jax.lax.dynamic_update_slice(D, block, (start, 0))
+
+
+@partial(jax.jit, donate_argnums=0)
+def _fill_rows(D, block, start):
+    """``_delta_update`` into a buffer no one else holds (a load's): ``D``
+    is donated, so the rows land in place instead of in a copy."""
     return jax.lax.dynamic_update_slice(D, block, (start, 0))
 
 

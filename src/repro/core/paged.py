@@ -54,6 +54,7 @@ import numpy as np
 
 from repro.core.index import (Backend, DenseIndex, SegmentedIndex,
                               _project_nofold, _topk_merge, project_queries)
+from repro.kernels.topk_score import PRECISION
 
 
 class PageExtent(NamedTuple):
@@ -114,9 +115,10 @@ def _paged_core(pool, tail, pt, scale, nv, off, lo, hi, Qf, k: int,
         if tail is not None:
             pgt = tail[jnp.clip(phys - pool_pages, 0, tail.shape[0] - 1)]
             pg = jnp.where(phys >= pool_pages, pgt, pg)
-        q = Qf if scale is None else Qf * scale[t][None, :]
+        q = Qf if scale is None else Qf * scale[t]
         s = jax.lax.dot_general(q, pg.astype(jnp.float32),
                                 dimension_numbers=(((1,), (1,)), ((), ())),
+                                precision=PRECISION,
                                 preferred_element_type=jnp.float32)  # (B, R)
         iota = jnp.arange(R, dtype=jnp.int32)[None, :]
         gids = jnp.broadcast_to(off[t] + iota, s.shape)
@@ -206,11 +208,12 @@ def _paged_rescore(pool, tail, pt, scale, nv, off, lo, hi, qf, uids, acc):
         if tail is not None:
             pgt = tail[jnp.clip(phys - pool_pages, 0, tail.shape[0] - 1)]
             pg = jnp.where(phys >= pool_pages, pgt, pg)
-        q = qf if scale is None else qf * scale[t][None, :]
+        q = qf if scale is None else qf * scale[t]
         local = uids - off[t]
         valid = (uids >= 0) & (local >= 0) & (local < nv[t]) & live
         rows = jnp.take(pg, jnp.clip(local, 0, R - 1), axis=0)   # (U, m)
-        s = q @ rows.T.astype(jnp.float32)                       # (B, U)
+        s = jnp.dot(q, rows.T.astype(jnp.float32),
+                    precision=PRECISION)                         # (B, U)
         return jnp.maximum(acc, jnp.where(valid[None, :], s, -jnp.inf)), None
 
     acc, _ = jax.lax.scan(body, acc, jnp.arange(table_cap, dtype=jnp.int32))
@@ -230,6 +233,12 @@ def _jit_cache_sizes() -> dict:
                         _pool_drain)}
     sizes["topk_score_paged_pallas"] = topk_score_paged_pallas._cache_size()
     return sizes
+
+
+def _device_scale(sc: np.ndarray | None) -> jax.Array | None:
+    """Per-page scale rows on device as (table_cap, 1, m): the paged
+    kernel DMAs one page's row over the untiled leading axis."""
+    return None if sc is None else jnp.asarray(sc[:, None, :])
 
 
 class _Mut:
@@ -319,7 +328,7 @@ class _Mut:
             pool=self.st.pool if pool is None else pool,
             tail=tail_dev,
             page_table=jnp.asarray(self.pt),
-            page_scale=None if self.sc is None else jnp.asarray(self.sc),
+            page_scale=_device_scale(self.sc),
             page_nvalid=jnp.asarray(self.nv),
             page_offset=jnp.asarray(self.off),
             pt_host=self.pt, nvalid_host=self.nv, offset_host=self.off,
@@ -344,7 +353,7 @@ class PagedIndexStorage:
     pool: jax.Array                    # (pool_pages, R, m) stable tier
     tail: jax.Array                    # (tail_pages, R, m) write arena
     page_table: jax.Array              # (table_cap,) int32; -1 = host tier
-    page_scale: jax.Array | None       # (table_cap, m) f32 (int8 pools)
+    page_scale: jax.Array | None       # (table_cap, 1, m) f32 (int8 pools)
     page_nvalid: jax.Array             # (table_cap,) int32 live rows/page
     page_offset: jax.Array             # (table_cap,) int32 first global id
     pt_host: np.ndarray
@@ -466,7 +475,7 @@ class PagedIndexStorage:
             pool=jnp.asarray(pool_np), tail=jnp.asarray(
                 np.zeros((tail_pages, R, m), vec.dtype)),
             page_table=jnp.asarray(pt),
-            page_scale=None if sc is None else jnp.asarray(sc),
+            page_scale=_device_scale(sc),
             page_nvalid=jnp.asarray(nv), page_offset=jnp.asarray(off),
             pt_host=pt, nvalid_host=nv, offset_host=off, scale_host=sc,
             tail_host=np.zeros((tail_pages, R, m), vec.dtype),
@@ -991,7 +1000,7 @@ class PagedIndex:
         pt = np.full(W, -1, np.int32)
         pt[:len(slots)] = np.arange(len(slots), dtype=np.int32)
         return (jnp.asarray(buf), jnp.asarray(pt),
-                None if sc is None else jnp.asarray(sc), jnp.asarray(nv),
+                _device_scale(sc), jnp.asarray(nv),
                 jnp.asarray(off), len(slots))
 
     def _waves(self, lo: int, hi: int) -> list:

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.kernels.topk_score import PRECISION
 from repro.par import compat
 
 
@@ -84,7 +85,7 @@ def gram(D: jax.Array, block_rows: int = 8192) -> jax.Array:
 
     def body(acc, blk):
         blk = blk.astype(jnp.float32)
-        return acc + blk.T @ blk, None
+        return acc + jnp.dot(blk.T, blk, precision=PRECISION), None
 
     acc0 = jnp.zeros((d, d), jnp.float32)
     out, _ = jax.lax.scan(body, acc0, blocks)
@@ -100,8 +101,11 @@ def gram_streaming(batches: Iterable[np.ndarray | jax.Array]) -> tuple[jax.Array
     G = None
     colsum = None
     n = 0
-    step = jax.jit(lambda g, s, b: (g + b.T.astype(jnp.float32) @ b.astype(jnp.float32),
-                                    s + b.sum(0, dtype=jnp.float32)))
+    def _step(g, s, b):
+        b = b.astype(jnp.float32)
+        return g + jnp.dot(b.T, b, precision=PRECISION), s + b.sum(0)
+
+    step = jax.jit(_step)
     for b in batches:
         b = jnp.asarray(b)
         if G is None:
@@ -133,7 +137,8 @@ def gram_distributed(D: jax.Array, mesh: Mesh) -> jax.Array:
 
     def local_gram(strip):
         strip = strip.astype(jnp.float32)
-        return jax.lax.psum(strip.T @ strip, axes)
+        return jax.lax.psum(jnp.dot(strip.T, strip, precision=PRECISION),
+                            axes)
 
     fn = compat.shard_map(local_gram, mesh=mesh, in_specs=(spec,),
                           out_specs=P(None, None))
@@ -207,7 +212,7 @@ def transform(X: jax.Array, state: PCAState, m: int | None = None) -> jax.Array:
     if m is not None:
         W = W[:, :m]
     Xc = X - state.mean if state.centered else X
-    return (Xc @ W).astype(X.dtype)
+    return jnp.dot(Xc, W, precision=PRECISION).astype(X.dtype)
 
 
 def transform_query(q: jax.Array, state: PCAState, m: int | None = None) -> jax.Array:

@@ -29,7 +29,8 @@ binary qrels, COVID a domain-shifted factor basis (for RQ2/out-of-domain).
 from __future__ import annotations
 
 import dataclasses
-from typing import Mapping
+import hashlib
+from typing import Callable, Iterator, Mapping
 
 import numpy as np
 
@@ -61,6 +62,14 @@ class RetrievalDataset:
     d: int
 
 
+def stable_seed(*parts) -> int:
+    """A 64-bit RNG seed from ints/strings that every process agrees on
+    (``hash(str)`` is salted per process, so it cannot seed data)."""
+    key = "\x1f".join(map(str, parts)).encode()
+    return int.from_bytes(hashlib.blake2b(key, digest_size=8).digest(),
+                          "little")
+
+
 def _orthonormal(d: int, r: int, rng: np.random.Generator) -> np.ndarray:
     A = rng.standard_normal((d, r))
     Q, _ = np.linalg.qr(A)
@@ -84,7 +93,7 @@ def make_corpus(encoder: str = "tasb", *, n_docs: int = 20000, d: int = 768,
     PCA transfer (RQ2) meaningful.
     """
     prof = ENCODER_PROFILES[encoder]
-    enc_rng = np.random.default_rng(seed * 1_000_003 + abs(hash(encoder)) % (2**31))
+    enc_rng = np.random.default_rng(stable_seed("encoder", encoder, seed))
     lam = np.arange(1, d + 1, dtype=np.float64) ** (-prof["alpha"])
     lam /= lam.sum()
     F = _orthonormal(d, d, enc_rng)
@@ -108,7 +117,7 @@ def make_corpus(encoder: str = "tasb", *, n_docs: int = 20000, d: int = 768,
 def _make_query_set(aux: Mapping, name: str, *, seed: int,
                     ) -> tuple[np.ndarray, dict[int, dict[int, int]]]:
     prof = QUERY_SET_PROFILES[name]
-    rng = np.random.default_rng(seed * 7_000_003 + abs(hash(name)) % (2**31))
+    rng = np.random.default_rng(stable_seed("queries", name, seed))
     F, lam, Z, sigma = aux["F"], aux["lam"], aux["Z"], aux["sigma"]
     n, d = Z.shape
     nq = prof["n_queries"]
@@ -178,3 +187,52 @@ def make_ood_corpus(base_encoder: str, *, n_docs: int = 20000, d: int = 768,
     D, _ = make_corpus(base_encoder, n_docs=n_docs, d=d, seed=seed,
                        domain_seed=domain_seed)
     return D
+
+
+def _embedder(encoder: str, d: int, seed: int
+              ) -> Callable[[np.random.Generator, int], np.ndarray]:
+    """``draw(rng, rows)`` -> (rows, d) float32 unit embeddings from the
+    encoder's generative model (``make_corpus``'s, in float32)."""
+    prof = ENCODER_PROFILES[encoder]
+    lam = np.arange(1, d + 1, dtype=np.float64) ** (-prof["alpha"])
+    sd = np.sqrt(lam / lam.sum()).astype(np.float32)[None, :]
+    Ft = _orthonormal(d, d, np.random.default_rng(
+        stable_seed("basis", encoder, seed))).T.astype(np.float32)
+    noise_sd = np.float32(prof["sigma"] / np.sqrt(d))
+
+    def draw(rng: np.random.Generator, rows: int) -> np.ndarray:
+        z = rng.standard_normal((rows, d), dtype=np.float32) * sd
+        eps = rng.standard_normal((rows, d), dtype=np.float32)
+        return _normalize(z @ Ft + noise_sd * eps).astype(np.float32)
+
+    return draw
+
+
+def corpus_blocks(encoder: str = "tasb", *, n_docs: int, d: int = 768,
+                  seed: int = 0, block_rows: int = 65536
+                  ) -> Callable[[], Iterator[np.ndarray]]:
+    """A corpus too large to hold, as a zero-arg callable returning a fresh
+    iterator over (<= block_rows, d) float32 row blocks.
+
+    Block ``b`` draws from its own stream seeded by ``(encoder, seed, b)``:
+    host memory is O(block_rows × d), and every pass
+    (``StaticPruner.build_index_to`` makes two) regenerates identical
+    blocks.
+    """
+    draw = _embedder(encoder, d, seed)
+
+    def blocks() -> Iterator[np.ndarray]:
+        for b, lo in enumerate(range(0, n_docs, block_rows)):
+            rng = np.random.default_rng(stable_seed("block", encoder, seed, b))
+            yield draw(rng, min(block_rows, n_docs - lo))
+
+    return blocks
+
+
+def corpus_queries(encoder: str = "tasb", *, n_queries: int, d: int = 768,
+                   seed: int = 0) -> np.ndarray:
+    """(n_queries, d) float32 queries in the embedding space of
+    ``corpus_blocks(encoder, d=d, seed=seed)``, from a query-only stream."""
+    draw = _embedder(encoder, d, seed)
+    rng = np.random.default_rng(stable_seed("query", encoder, seed))
+    return draw(rng, n_queries)

@@ -39,7 +39,7 @@ def _gram_kernel(d_ref, acc_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def gram_pallas(D: jax.Array, *, block_rows: int = 1024,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool = False) -> jax.Array:
     """``D^T D`` in fp32 via the blocked Pallas kernel.
 
     ``D``: (n, d), any float dtype. Rows are zero-padded to a multiple of

@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (the container is CPU-only; TPU is
-the compile target). On a real TPU backend the wrappers run the compiled
-Mosaic kernels.
+``interpret=None`` picks by backend: the compiled Mosaic kernels on a TPU,
+the Pallas interpreter elsewhere (the CPU test suite). The kernels
+themselves default to compiled.
 """
 from __future__ import annotations
 

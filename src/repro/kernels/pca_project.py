@@ -54,7 +54,7 @@ def _project_quant_kernel(x_ref, w_ref, scale_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def pca_project_pallas(D: jax.Array, W: jax.Array, *, block_rows: int = 1024,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool = False) -> jax.Array:
     """``D @ W`` (fp32 accumulate), blocked over rows."""
     n, d = D.shape
     d2, m = W.shape
@@ -77,7 +77,7 @@ def pca_project_pallas(D: jax.Array, W: jax.Array, *, block_rows: int = 1024,
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def pca_project_quant_pallas(D: jax.Array, W: jax.Array, scale: jax.Array, *,
-                             block_rows: int = 1024, interpret: bool = True
+                             block_rows: int = 1024, interpret: bool = False
                              ) -> jax.Array:
     """``int8(round((D @ W) / scale))`` with the quantisation fused in VMEM."""
     n, d = D.shape

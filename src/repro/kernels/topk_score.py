@@ -76,7 +76,7 @@ DMA'd alongside and folded into the *query* (``q * scale``, the same
 fold order as the segmented path, so scores are bit-identical). Dead
 table slots (``slot >= n_slots``) are masked, never DMA'd. The page
 count is a *traced* scalar — growing or shrinking the index never
-recompiles. A ``(table_cap, page_rows)`` ``ids_pool`` switches to the
+recompiles. A ``(table_cap, 1, page_rows)`` ``ids_pool`` switches to the
 rescore mode (report gathered ids, mask negatives, merge-on-equality
 guard), and an optional ``carry`` seeds the running top-k so an
 oversubscribed index can stream through a small pool in waves.
@@ -91,6 +91,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 _NEG = float("-inf")
+# f32 scores at full f32 precision on the MXU (several bf16 passes): the
+# chip's default single bf16 pass rounds the query to 8 mantissa bits, which
+# reorders near-tied candidates against an exact f32 reference. On CPU this
+# is a no-op, so interpret-mode results are unchanged.
+PRECISION = jax.lax.Precision.HIGHEST
 _BIG = jnp.iinfo(jnp.int32).max
 
 
@@ -114,7 +119,7 @@ class TopKGeometry(NamedTuple):
     block_n: int      # index strip rows (clamped)
     block_b: int      # query tile rows (clamped)
     nblocks: int      # index strips in the grid
-    pad_rows: int     # corpus padding rows appended
+    pad_rows: int     # rows the last strip overhangs n (masked, not padded)
     b_pad: int        # padded batch
     nbt: int          # batch tiles in the grid
     fold_w: int       # stage-1 candidate-lane width (~2k, lane-aligned)
@@ -250,6 +255,7 @@ def _make_kernel(k: int, n_valid: int, block_n: int, nblocks: int,
         blk = d_ref[...].astype(jnp.float32)      # dequant/upcast in-register
         s = jax.lax.dot_general(
             q, blk, dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=PRECISION,
             preferred_element_type=jnp.float32)               # (bb, block_n)
         if with_ids:
             # rescore mode: report the gathered rows' true doc ids; negative
@@ -257,6 +263,8 @@ def _make_kernel(k: int, n_valid: int, block_n: int, nblocks: int,
             gids = jnp.broadcast_to(ids_ref[...], s.shape)
             s = jnp.where(gids >= 0, s, _NEG)
         else:
+            # also masks the ragged last strip: rows past the array end are
+            # unspecified (possibly NaN), and ``where`` discards them
             gids = i * block_n + jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                                           1)
             s = jnp.where(gids < n_valid, s, _NEG)
@@ -282,7 +290,7 @@ def _make_kernel(k: int, n_valid: int, block_n: int, nblocks: int,
                                              "n_valid", "interpret", "guard"))
 def topk_score_pallas(D: jax.Array, Q: jax.Array, *, k: int,
                       block_n: int = 1024, block_b: int = 128,
-                      n_valid: int | None = None, interpret: bool = True,
+                      n_valid: int | None = None, interpret: bool = False,
                       row_ids: jax.Array | None = None, guard: str = "row"
                       ) -> tuple[jax.Array, jax.Array]:
     """Fused exact search: top-k of ``Q @ D^T`` per query row.
@@ -304,8 +312,8 @@ def topk_score_pallas(D: jax.Array, Q: jax.Array, *, k: int,
     B = Q.shape[0]
     nv = n if n_valid is None else min(n_valid, n)
     g = topk_geometry(n, m, B, k, block_n=block_n, block_b=block_b)
-    if g.pad_rows:
-        D = jnp.pad(D, ((0, g.pad_rows), (0, 0)))   # dtype-preserving
+    # D is never padded here: the grid's last strip may run past row n, and
+    # the kernel masks those rows, so a dispatch never copies the index
     Qf = Q.astype(jnp.float32)
     if g.b_pad != B:
         Qf = jnp.pad(Qf, ((0, g.b_pad - B), (0, 0)))
@@ -469,20 +477,20 @@ def _make_paged_kernel(k: int, table_cap: int, page_rows: int,
                 slot = j % depth
                 page_copy(j, slot)(lambda c: c.start())
                 if with_scale:
-                    pltpu.make_async_copy(scale_ref.at[pl.ds(j, 1)],
+                    pltpu.make_async_copy(scale_ref.at[j],
                                           sbuf.at[slot], ssem.at[slot]).start()
                 if with_ids:
-                    pltpu.make_async_copy(idsp_ref.at[pl.ds(j, 1)],
+                    pltpu.make_async_copy(idsp_ref.at[j],
                                           ibuf.at[slot], isem.at[slot]).start()
 
             def wait(j):
                 slot = j % depth
                 page_copy(j, slot)(lambda c: c.wait())
                 if with_scale:
-                    pltpu.make_async_copy(scale_ref.at[pl.ds(j, 1)],
+                    pltpu.make_async_copy(scale_ref.at[j],
                                           sbuf.at[slot], ssem.at[slot]).wait()
                 if with_ids:
-                    pltpu.make_async_copy(idsp_ref.at[pl.ds(j, 1)],
+                    pltpu.make_async_copy(idsp_ref.at[j],
                                           ibuf.at[slot], isem.at[slot]).wait()
 
             # warm-up: fill the prefetch window (dead slots never DMA)
@@ -509,6 +517,7 @@ def _make_paged_kernel(k: int, table_cap: int, page_rows: int,
                     q = q * sbuf[slot]                  # (bb, m) * (1, m)
                 s = jax.lax.dot_general(
                     q, page, dimension_numbers=(((1,), (1,)), ((), ())),
+                    precision=PRECISION,
                     preferred_element_type=jnp.float32)      # (bb, R)
                 iota = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
                 if with_ids:
@@ -555,7 +564,7 @@ def topk_score_paged_pallas(pool: jax.Array, page_table: jax.Array,
                             carry: tuple[jax.Array, jax.Array] | None = None,
                             depth: int = 2, block_b: int = 128,
                             guard: str = "row", finalize: bool = True,
-                            interpret: bool = True
+                            interpret: bool = False
                             ) -> tuple[jax.Array, jax.Array]:
     """Fused exact search over a paged index: top-k of ``Q @ pages^T``.
 
@@ -569,10 +578,13 @@ def topk_score_paged_pallas(pool: jax.Array, page_table: jax.Array,
     lo, hi:      *traced* scalar slot bounds — the kernel walks logical
                  slots [lo, hi), so index growth/shrink never recompiles
                  and an oversubscribed walk splits into device/host runs.
-    page_scale:  optional (table_cap, m) f32 per-page dequant scales,
+    page_scale:  optional (table_cap, 1, m) f32 per-page dequant scales,
                  folded into Q per page (int8 pools).
-    ids_pool:    optional (table_cap, R) int32 true doc ids per page row —
-                 rescore mode (negative = masked sentinel).
+    ids_pool:    optional (table_cap, 1, R) int32 true doc ids per page
+                 row — rescore mode (negative = masked sentinel).
+                 Both keep a unit axis before the tiled one so a page's
+                 row is a DMA over the untiled leading axis: Mosaic
+                 refuses a one-row slice of a tiled (8-row) axis.
     carry:       optional (B, k) scores/ids seeding the running list —
                  chain runs/waves. Pass the *un-clamped* ids of a
                  ``finalize=False`` call back in.
@@ -596,7 +608,7 @@ def topk_score_paged_pallas(pool: jax.Array, page_table: jax.Array,
         with_carry=carry is not None, finalize=finalize)
 
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    anyspace = pl.BlockSpec(memory_space=pltpu.ANY)
+    anyspace = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [smem, smem, smem, smem,
                 pl.BlockSpec((g.block_b, m), lambda b: (b, 0))]
     bounds = jnp.stack([jnp.asarray(lo, jnp.int32).reshape(()),

@@ -112,7 +112,7 @@ import numpy as np
 from repro.core import CascadeIndex, DenseIndex, IndexStore, ShardedDenseIndex, StaticPruner
 from repro.core.store import save_index
 from repro.data.synthetic import make_dataset
-from repro.util import force_host_device_count
+from repro.util import compile_cache_dir, force_host_device_count
 
 
 class TimedOut(RuntimeError):
@@ -942,6 +942,7 @@ def main() -> None:
             ap.error("--cascade M and N must both be >= 1")
 
     force_host_device_count(args.host_devices or (4 if args.sharded else 0))
+    jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
 
     if args.fleet > 0:
         if args.sharded or args.cascade or args.live_append > 0:

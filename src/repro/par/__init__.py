@@ -1,5 +1,5 @@
 """Parallelism substrate: sharding rules, logical axes, collective helpers,
-and the version-portable JAX compat shim (``repro.par.compat``)."""
+and the one seam for the JAX collective API (``repro.par.compat``)."""
 from repro.par import compat
 from repro.par.compat import abstract_mesh, axis_size, mark_varying, shard_map
 from repro.par.sharding import (

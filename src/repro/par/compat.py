@@ -1,146 +1,68 @@
-"""Version-portable JAX compatibility layer for the distributed code paths.
+"""One seam for the JAX collective surface the distributed code paths use.
 
 The sharded hot paths (Gram psum, sharded top-k merge, compressed-gradient
-all-reduce) are written against the *current* JAX surface — ``jax.shard_map``
-with ``check_vma``, ``jax.lax.pcast`` varying-marks, the two-argument
-``AbstractMesh(axis_sizes, axis_names)``. Those APIs moved or do not exist
-on older releases (the pinned toolchain ships 0.4.x, where ``shard_map``
-still lives under ``jax.experimental`` with a ``check_rep`` kwarg). This
-module is the single seam every call site goes through:
+all-reduce) call these helpers rather than ``jax.shard_map`` /
+``jax.lax.pcast`` directly, so a future API move is a one-file change:
 
   * ``shard_map(f, mesh=..., in_specs=..., out_specs=..., check_vma=...)``
-      Resolves ``jax.shard_map`` vs ``jax.experimental.shard_map.shard_map``
-      and translates ``check_vma`` to whichever replication/varying-check
-      kwarg the installed version understands (dropping it if neither does).
-  * ``mark_varying(tree, axes)``
-      ``jax.lax.pcast(..., to="varying")`` over a pytree where pcast exists;
-      the identity elsewhere (pre-VMA shard_map needs no marking).
-  * ``abstract_mesh(shape, names)``
-      Builds ``jax.sharding.AbstractMesh`` through either constructor
-      signature: new ``(axis_sizes, axis_names)`` or old
-      ``(((name, size), ...),)`` pairs.
-  * ``axis_size(axis)``
-      ``jax.lax.axis_size`` where present, else ``psum(1, axis)`` (which
-      constant-folds to the mesh axis size under tracing).
+  * ``mark_varying(tree, axes)`` — ``jax.lax.pcast(..., to="varying")``
+      over a pytree; a scan carry created inside ``shard_map`` must be
+      varying before collectives see it.
+  * ``abstract_mesh(shape, names)`` — a device-less ``AbstractMesh``.
+  * ``axis_size(axis)`` / ``axis_index(axis)`` — size and flat row-major
+      index over one named mesh axis or a tuple of them.
   * ``shard_map_eqn_body(eqn)`` / ``shard_map_eqn_device_count(eqn)``
       Jaxpr-introspection helpers for cost accounting: the sub-jaxpr and
-      global device multiplier of a ``shard_map`` equation, tolerant of the
-      param-layout differences between versions.
+      global device multiplier of a ``shard_map`` equation.
+
+Written against the installed JAX (see ``pyproject.toml``).
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any, Sequence
 
 import jax
-import numpy as np
 from jax.sharding import AbstractMesh
 
 __all__ = [
-    "JAX_VERSION", "HAS_NATIVE_SHARD_MAP", "HAS_PCAST",
     "shard_map", "mark_varying", "abstract_mesh", "axis_size", "axis_index",
     "shard_map_eqn_body", "shard_map_eqn_device_count",
 ]
 
-JAX_VERSION: tuple[int, ...] = tuple(
-    int(p) for p in jax.__version__.split(".")[:3] if p.isdigit())
-
-HAS_NATIVE_SHARD_MAP: bool = hasattr(jax, "shard_map")
-
-if HAS_NATIVE_SHARD_MAP:
-    _shard_map_impl = jax.shard_map
-else:  # 0.4.x: still experimental
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SHARD_MAP_KWARGS = frozenset(inspect.signature(_shard_map_impl).parameters)
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None,
               **kwargs):
-    """``jax.shard_map`` across JAX versions.
-
-    ``check_vma`` follows the newest spelling; it is forwarded as
-    ``check_vma`` or ``check_rep`` depending on what the installed
-    ``shard_map`` accepts, and silently dropped if it accepts neither.
-    """
+    """``jax.shard_map``; ``check_vma=None`` keeps JAX's default."""
     if check_vma is not None:
-        if "check_vma" in _SHARD_MAP_KWARGS:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in _SHARD_MAP_KWARGS:
-            kwargs["check_rep"] = check_vma
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
-
-
-HAS_PCAST: bool = hasattr(jax.lax, "pcast")
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def mark_varying(tree: Any, axes: Sequence[str] | None) -> Any:
-    """Mark every leaf varying over ``axes`` (VMA typing), where supported.
-
-    On JAX versions with varying-manual-axes tracking, a scan carry created
-    inside ``shard_map`` must be ``pcast`` to varying before collectives see
-    it. Pre-VMA versions have no such distinction — identity there.
-    """
-    if not HAS_PCAST or not axes:
+    """Mark every leaf varying over ``axes`` (identity for no axes)."""
+    if not axes:
         return tree
     return jax.tree.map(
         lambda x: jax.lax.pcast(x, tuple(axes), to="varying"), tree)
 
 
-_AM_OLD_SIGNATURE = "shape_tuple" in inspect.signature(
-    AbstractMesh.__init__).parameters
-
-
 def abstract_mesh(shape: Sequence[int], names: Sequence[str]) -> AbstractMesh:
-    """Device-less mesh from parallel ``shape`` / ``names`` sequences.
-
-    Newer JAX takes ``AbstractMesh(axis_sizes, axis_names)``; 0.4.x takes a
-    single tuple of ``(name, size)`` pairs. Both yield a mesh whose
-    ``.shape`` / ``.axis_names`` drive the sharding-rule engine without
-    touching device state.
-    """
+    """Device-less mesh from parallel ``shape`` / ``names`` sequences."""
     shape, names = tuple(shape), tuple(names)
     if len(shape) != len(names):
         raise ValueError(f"shape {shape} and names {names} length mismatch")
-    if _AM_OLD_SIGNATURE:
-        return AbstractMesh(tuple(zip(names, shape)))
     return AbstractMesh(shape, names)
 
 
-if hasattr(jax.lax, "axis_size"):
-    def axis_size(axis) -> int:
-        """Size of a named mesh axis (or product over a tuple of axes)."""
-        return jax.lax.axis_size(axis)
-else:
-    def axis_size(axis) -> int:
-        """Size of a named mesh axis (or product over a tuple of axes).
-
-        ``psum`` of a non-tracer constant folds to ``value * axis_size`` at
-        trace time, so this is free inside jit/shard_map.
-        """
-        return jax.lax.psum(1, axis)
+def axis_size(axis) -> int:
+    """Size of a named mesh axis (or product over a tuple of axes)."""
+    return jax.lax.axis_size(axis)
 
 
 def axis_index(axis) -> jax.Array:
-    """Flat linear index over one or more named mesh axes (row-major).
-
-    Newer JAX accepts a tuple of axis names directly; older releases only
-    take a single name, so the flat index is folded manually as
-    ``idx = idx * size(ax) + axis_index(ax)`` — identical row-major order.
-    """
-    if isinstance(axis, str):
-        return jax.lax.axis_index(axis)
-    names = tuple(axis)
-    if len(names) == 1:
-        return jax.lax.axis_index(names[0])
-    try:
-        return jax.lax.axis_index(names)
-    except (TypeError, ValueError):
-        idx = jax.lax.axis_index(names[0])
-        for name in names[1:]:
-            idx = idx * axis_size(name) + jax.lax.axis_index(name)
-        return idx
+    """Flat linear index over one or more named mesh axes (row-major)."""
+    return jax.lax.axis_index(axis if isinstance(axis, str) else tuple(axis))
 
 
 # ---------------------------------------------------------------------------
@@ -157,17 +79,6 @@ def shard_map_eqn_body(eqn) -> Any | None:
 
 
 def shard_map_eqn_device_count(eqn) -> float:
-    """Global device multiplier of a ``shard_map`` equation.
-
-    Body shapes are per-shard; costs scale back to global by the mesh
-    device count. Falls back to 1.0 when the mesh param is unreadable.
-    """
-    mesh = eqn.params.get("mesh")
-    for extract in (lambda m: np.prod(list(m.shape.values())),
-                    lambda m: np.prod(m.axis_sizes),
-                    lambda m: m.size):
-        try:
-            return float(extract(mesh))
-        except Exception:
-            continue
-    return 1.0
+    """Global device multiplier of a ``shard_map`` equation: body shapes
+    are per-shard, so costs scale back to global by the mesh size."""
+    return float(eqn.params["mesh"].size)

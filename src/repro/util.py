@@ -27,3 +27,16 @@ def force_host_device_count(n: int) -> bool:
     os.environ["XLA_FLAGS"] = (
         f"{flags} --xla_force_host_platform_device_count={n}".strip())
     return True
+
+
+def compile_cache_dir() -> str:
+    """Where JAX's persistent compilation cache lives.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` when it is set (JAX reads it itself, and
+    the entry points then set no other); otherwise one fixed directory in
+    the checkout, ``.jax_cache/`` (git-ignored). The path must not move
+    between runs for a later run to hit what an earlier one compiled.
+    """
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), ".jax_cache")

@@ -1,9 +1,15 @@
 """Data pipelines: determinism, prefetch, graph sampling, corpus structure."""
+import hashlib
+import os
+import subprocess
+import sys
+
 import numpy as np
 
 from repro.data.graph import CSRGraph, NeighborSampler, batched_molecules, random_graph
 from repro.data.recsys import ctr_batch, two_tower_batch
-from repro.data.synthetic import ENCODER_PROFILES, make_corpus, make_dataset
+from repro.data.synthetic import (ENCODER_PROFILES, corpus_blocks, corpus_queries, make_corpus,
+                                  make_dataset)
 from repro.data.tokens import Prefetcher, token_batch
 
 
@@ -92,3 +98,46 @@ def test_dataset_has_queries_and_graded_qrels():
     assert 3 in grades          # graded judgments
     grades_dev = {g for q in ds.qrels["devsmall"].values() for g in q.values()}
     assert grades_dev == {1}    # binary shallow judgments
+
+
+# sha256 of the first (32, 16) block of corpus_blocks("tasb", seed=0), rounded
+# to 4 decimals so BLAS summation order cannot flip it
+_BLOCK0_SHA256 = "f05de8e091929bd74aa1269c95a6328206efb18c7fc6f72887accae270ab1741"
+_BLOCK0_SNIPPET = (
+    "import hashlib, numpy as np\n"
+    "from repro.data.synthetic import corpus_blocks\n"
+    "b = next(iter(corpus_blocks(n_docs=64, d=16, seed=0, block_rows=32)()))\n"
+    "print(hashlib.sha256(np.round(b, 4).tobytes()).hexdigest())\n")
+
+
+def test_corpus_block_checksum_is_pinned_across_processes():
+    """Seeds must not depend on the per-process salt of ``hash(str)``: the
+    same block comes out in a process with another PYTHONHASHSEED."""
+    b = next(iter(corpus_blocks(n_docs=64, d=16, seed=0, block_rows=32)()))
+    assert hashlib.sha256(np.round(b, 4).tobytes()).hexdigest() == _BLOCK0_SHA256
+    env = dict(os.environ, PYTHONHASHSEED="12345",
+               PYTHONPATH=os.pathsep.join(sys.path))
+    out = subprocess.run([sys.executable, "-c", _BLOCK0_SNIPPET], env=env,
+                         capture_output=True, text=True, timeout=120, check=True)
+    assert out.stdout.strip() == _BLOCK0_SHA256
+    a, _ = make_corpus("tasb", n_docs=32, d=16, seed=3)
+    code = ("import hashlib, numpy as np\n"
+            "from repro.data.synthetic import make_corpus\n"
+            "a, _ = make_corpus('tasb', n_docs=32, d=16, seed=3)\n"
+            "print(hashlib.sha256(np.round(a, 4).tobytes()).hexdigest())\n")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120, check=True)
+    assert out.stdout.strip() == hashlib.sha256(np.round(a, 4).tobytes()).hexdigest()
+
+
+def test_corpus_blocks_repeat_per_pass_and_cover_n_docs():
+    gen = corpus_blocks("tasb", n_docs=100, d=16, seed=1, block_rows=32)
+    first, second = list(gen()), list(gen())
+    assert [b.shape for b in first] == [(32, 16)] * 3 + [(4, 16)]
+    for a, b in zip(first, second):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_allclose(np.linalg.norm(np.concatenate(first), axis=1),
+                               1.0, rtol=1e-5)
+    q = corpus_queries("tasb", n_queries=8, d=16, seed=1)
+    assert q.shape == (8, 16) and q.dtype == np.float32
+    assert not any(np.allclose(q[:4], b[:4]) for b in first)

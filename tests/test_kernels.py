@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.analysis.jaxpr_lints import iter_all_eqns
 from repro.kernels import ops, ref
 
 RNG = np.random.default_rng(42)
@@ -142,19 +143,10 @@ def test_topk_int8_streams_native():
     D, scale = _quantized(_rand((300, 32), jnp.float32))
     Q = _rand((4, 32), jnp.float32) * scale[None, :]
 
-    def find_pallas_eqn(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                return eqn
-        for sub in jax.core.subjaxprs(jaxpr):
-            got = find_pallas_eqn(sub)
-            if got is not None:
-                return got
-        return None
-
     jaxpr = jax.make_jaxpr(
         lambda d, q: ops.topk_score(d, q, k=5, interpret=True))(D, Q)
-    eqn = find_pallas_eqn(jaxpr.jaxpr)
+    eqn = next((e for e in iter_all_eqns(jaxpr.jaxpr)
+                if e.primitive.name == "pallas_call"), None)
     assert eqn is not None
     in_dtypes = {str(v.aval.dtype) for v in eqn.invars}
     assert "int8" in in_dtypes

@@ -145,8 +145,8 @@ def test_paged_kernel_int8_per_page_scale():
         buf, idx = (pool8, phys) if phys < pool_pages \
             else (tail8, phys - pool_pages)
         buf[idx, :nv[j]] = q8
-    ps = np.zeros((table_cap, m), np.float32)
-    ps[:npages] = scale
+    ps = np.zeros((table_cap, 1, m), np.float32)   # kernel's DMA layout
+    ps[:npages, 0] = scale
     # reference: per-page scale folded into the query, jnp dot per page
     parts_s, parts_i = [], []
     for j in range(npages):
@@ -178,9 +178,10 @@ def test_paged_kernel_ids_pool_rescore_mode():
         buf, idx = (pool, phys) if phys < pool_pages \
             else (tail, phys - pool_pages)
         buf[idx, :nv[j]] = D[j * R: j * R + nv[j]]
-    ids_pool = np.full((table_cap, R), -1, np.int32)
+    ids_pool = np.full((table_cap, 1, R), -1, np.int32)   # kernel's DMA layout
     for j in range(npages):
-        ids_pool[j, :nv[j]] = np.arange(j * R, j * R + nv[j], dtype=np.int32)
+        ids_pool[j, 0, :nv[j]] = np.arange(j * R, j * R + nv[j],
+                                           dtype=np.int32)
     ref = topk_score_pallas(
         jnp.asarray(D), jnp.asarray(Q), k=k, block_n=R * npages,
         row_ids=jnp.asarray(np.arange(n, dtype=np.int32)), interpret=True)

@@ -507,8 +507,16 @@ def test_compact_reconciles_racing_appends():
         up._iter_dequant_rows = orig_iter
     assert up.index.n == 480
     assert up.compactions == 1
-    _, ids = up.search(jnp.asarray(racing[7][None, :]), k=5)
-    assert (450 + 7) in np.asarray(ids)[0].tolist()
+    # the racing rows answer exactly as in a brute-force search over all
+    # 480 rows under the post-compaction projection, and they are found
+    _, ids = up.search(jnp.asarray(racing), k=5)
+    ids = np.asarray(ids)
+    rows = np.asarray(up.pruner.prune_index(jnp.asarray(
+        np.concatenate([D, _corpus(50, 24, seed=5), racing]))))
+    q = np.asarray(up.pruner.transform_queries(jnp.asarray(racing)))
+    exact = np.argsort(-(q @ rows.T), axis=1, kind="stable")[:, :5]
+    assert [set(r) for r in ids.tolist()] == [set(r) for r in exact.tolist()]
+    assert sum(450 + i in ids[i] for i in range(len(racing))) >= len(racing) // 2
 
 
 # ---------------------------------------------------------------------------

@@ -264,7 +264,9 @@ def test_worker_stats_safe_while_completer_appends(served):
         th.join(timeout=10.0)
         server.close()
     assert not errs
-    assert counts == sorted(counts)    # log only grows between resets
+    # the log only grows between resets (the drive resets once, after its
+    # warmup query): any drop must be a reset to empty
+    assert all(b >= a or b == 0 for a, b in zip(counts, counts[1:]))
 
 
 def test_submit_validation_tracks_index_swap(served):

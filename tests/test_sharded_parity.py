@@ -290,7 +290,7 @@ def test_search_projected_is_single_dispatch_dense():
     prims = {e.primitive.name for e in jaxpr.jaxpr.eqns}
     # the (B, d) @ (d, m) projection is a dot_general at this level; the
     # scan carries the streamed top-k — both inside one traced computation
-    assert "dot_general" in prims or "pjit" in prims
+    assert "dot_general" in prims or "jit" in prims
     flat = jaxpr.pretty_print(use_color=False)
     assert "dot_general" in flat and ("scan" in flat or "top_k" in flat)
 

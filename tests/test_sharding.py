@@ -13,7 +13,7 @@ from repro.par.sharding import (
 )
 
 # rules resolve against mesh *shape* only — an abstract mesh needs no
-# devices; compat.abstract_mesh handles both AbstractMesh signatures
+# devices
 MESH2 = abstract_mesh((1, 2), ("data", "model"))
 
 

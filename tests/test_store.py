@@ -306,6 +306,11 @@ def test_streaming_int8_build_peak_memory_is_o_block(tmp_path):
         for _ in range(n // rows):
             yield rng.standard_normal((rows, d)).astype(np.float32)
 
+    # one untraced build first: a process's first build also allocates
+    # one-time state (lazy imports, jit compiles) whose size depends on
+    # what ran before in the process, not on the corpus
+    StaticPruner(cutoff=0.5).build_index_to(str(tmp_path / "warm"), gen,
+                                            quantize_int8=True)
     tracemalloc.start()
     tracemalloc.reset_peak()
     st = StaticPruner(cutoff=0.5).build_index_to(str(tmp_path / "st"), gen,
