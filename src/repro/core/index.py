@@ -55,14 +55,17 @@ def project_queries(q: jax.Array, W: jax.Array,
     path (pinned by tests/test_sharded_parity.py). Lower-precision raw
     queries upcast here, whereas ``transform`` casts its result back to
     the input dtype — feed f32 when exact parity matters.
+
+    Its ops carry the device scope ``project`` (see ``_scan_topk``).
     """
-    q = jnp.atleast_2d(q).astype(jnp.float32)
-    if mean is not None:
-        q = q - mean[None, :]
-    q = jnp.dot(q, W, precision=PRECISION)
-    if scale is not None:
-        q = q * scale[None, :]
-    return q
+    with jax.named_scope("project"):
+        q = jnp.atleast_2d(q).astype(jnp.float32)
+        if mean is not None:
+            q = q - mean[None, :]
+        q = jnp.dot(q, W, precision=PRECISION)
+        if scale is not None:
+            q = q * scale[None, :]
+        return q
 
 
 @partial(jax.jit, static_argnames=("k", "block", "backend"))
@@ -108,16 +111,18 @@ def _staged_topk_merge(s: jax.Array, ids: jax.Array, k: int,
     preserved: a global top-k entry is a top-k entry of every intermediate
     device group it belongs to, so it survives each stage. Gather order is
     row-major by mesh position in both layouts, so tie-breaks (and thus the
-    selected ids) are bit-identical between flat and staged merges.
+    selected ids) are bit-identical between flat and staged merges. Its
+    ops carry the device scope ``merge``.
     """
-    for stage in stages:
-        stage = tuple(stage)
-        if not stage:
-            continue
-        s_all = jax.lax.all_gather(s, stage, axis=1, tiled=True)
-        i_all = jax.lax.all_gather(ids, stage, axis=1, tiled=True)
-        s, ids = _topk_merge(s_all, i_all, k)
-    return s, ids
+    with jax.named_scope("merge"):
+        for stage in stages:
+            stage = tuple(stage)
+            if not stage:
+                continue
+            s_all = jax.lax.all_gather(s, stage, axis=1, tiled=True)
+            i_all = jax.lax.all_gather(ids, stage, axis=1, tiled=True)
+            s, ids = _topk_merge(s_all, i_all, k)
+        return s, ids
 
 
 @partial(jax.jit, static_argnames=("k", "block", "vma_axes", "guard"))
@@ -153,6 +158,13 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
 
     ``vma_axes``: when called inside shard_map over those axes, the scan
     carry must be marked varying (compat.mark_varying) to typecheck.
+
+    Device scopes (``jax.named_scope``: each op's HLO ``op_name`` holds
+    ``/score/`` or ``/select/``, names a profile can follow across
+    refactors): ``score`` is the strip's slice, upcast, matmul and mask;
+    ``select`` the improvement test and the whole guarded merge, per-strip
+    ``top_k`` included. ``project_queries`` adds ``project`` and
+    ``_staged_topk_merge`` ``merge``.
     """
     n, d = D.shape
     B = Q.shape[0]
@@ -165,49 +177,56 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
         # single strip (block == n): the running list is empty, a guard can
         # never fire, and the two-stage detour just adds a second sort —
         # select directly
-        s = jnp.dot(Qf, D.T.astype(jnp.float32), precision=PRECISION)
-        ids = jnp.broadcast_to(
-            jnp.arange(block, dtype=jnp.int32)[None, :], (B, block))
-        if k > block:
-            # fewer rows than k: sentinels first so they win -inf ties,
-            # matching the scan init and the Pallas kernel's -1 pads
-            s = jnp.concatenate(
-                [jnp.full((B, k), -jnp.inf, jnp.float32), s], axis=1)
-            ids = jnp.concatenate(
-                [jnp.full((B, k), -1, jnp.int32), ids], axis=1)
-        return _topk_merge(s, ids, k)
+        with jax.named_scope("score"):
+            s = jnp.dot(Qf, D.T.astype(jnp.float32), precision=PRECISION)
+        with jax.named_scope("select"):
+            ids = jnp.broadcast_to(
+                jnp.arange(block, dtype=jnp.int32)[None, :], (B, block))
+            if k > block:
+                # fewer rows than k: sentinels first so they win -inf
+                # ties, matching the scan init and the Pallas kernel's -1
+                # pads
+                s = jnp.concatenate(
+                    [jnp.full((B, k), -jnp.inf, jnp.float32), s], axis=1)
+                ids = jnp.concatenate(
+                    [jnp.full((B, k), -1, jnp.int32), ids], axis=1)
+            return _topk_merge(s, ids, k)
 
     def body(carry, start):
         bs, bi = carry
-        at = jnp.minimum(start, n - block)
-        blk = jax.lax.dynamic_slice_in_dim(D, at, block, axis=0)
-        s = jnp.dot(Qf, blk.T.astype(jnp.float32),
-                    precision=PRECISION)                         # (B, block)
-        ids = at + jnp.arange(block, dtype=jnp.int32)[None, :]
-        s = jnp.where(ids >= start, s, -jnp.inf)
+        with jax.named_scope("score"):
+            at = jnp.minimum(start, n - block)
+            blk = jax.lax.dynamic_slice_in_dim(D, at, block, axis=0)
+            s = jnp.dot(Qf, blk.T.astype(jnp.float32),
+                        precision=PRECISION)                     # (B, block)
+            ids = at + jnp.arange(block, dtype=jnp.int32)[None, :]
+            s = jnp.where(ids >= start, s, -jnp.inf)
 
-        imp = jnp.max(s, axis=1) > jnp.min(bs, axis=1)           # (B,)
+        with jax.named_scope("select"):
+            imp = jnp.max(s, axis=1) > jnp.min(bs, axis=1)       # (B,)
 
-        def merge(carry_in):
-            bs0, bi0 = carry_in
-            ss, si = jax.lax.top_k(s, kk)                        # (B, kk)
-            gi = at + si.astype(jnp.int32)
-            # running list first: at -inf ties its (-1) pads win the
-            # first-occurrence tie-break, matching the kernel's pads
-            cs = jnp.concatenate([bs0, ss], axis=1)              # (B, k+kk)
-            ci = jnp.concatenate([bi0, gi], axis=1)
-            ms, mi = _topk_merge(cs, ci, k)
+            def merge(carry_in):
+                bs0, bi0 = carry_in
+                ss, si = jax.lax.top_k(s, kk)                    # (B, kk)
+                gi = at + si.astype(jnp.int32)
+                # running list first: at -inf ties its (-1) pads win the
+                # first-occurrence tie-break, matching the kernel's pads
+                cs = jnp.concatenate([bs0, ss], axis=1)          # (B, k+kk)
+                ci = jnp.concatenate([bi0, gi], axis=1)
+                ms, mi = _topk_merge(cs, ci, k)
+                if guard == "row":
+                    # masked merge: non-improving rows keep their list
+                    # bitwise
+                    ms = jnp.where(imp[:, None], ms, bs0)
+                    mi = jnp.where(imp[:, None], mi, bi0)
+                return ms, mi
+
             if guard == "row":
-                # masked merge: non-improving rows keep their list bitwise
-                ms = jnp.where(imp[:, None], ms, bs0)
-                mi = jnp.where(imp[:, None], mi, bi0)
-            return ms, mi
-
-        if guard == "row":
-            can_improve = jnp.any(imp)
-        else:
-            can_improve = jnp.max(s) > jnp.min(bs)
-        return jax.lax.cond(can_improve, merge, lambda c: c, (bs, bi)), None
+                can_improve = jnp.any(imp)
+            else:
+                can_improve = jnp.max(s) > jnp.min(bs)
+            return jax.lax.cond(can_improve, merge, lambda c: c,
+                                (bs, bi)), None
 
     init = (jnp.full((B, k), -jnp.inf, jnp.float32), jnp.full((B, k), -1, jnp.int32))
     if vma_axes:

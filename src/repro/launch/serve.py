@@ -104,10 +104,12 @@ import queue
 import threading
 import time
 from collections import deque
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import CascadeIndex, DenseIndex, IndexStore, ShardedDenseIndex, StaticPruner
 from repro.core.store import save_index
@@ -119,6 +121,39 @@ class TimedOut(RuntimeError):
     """A reply's deadline expired before its batch posted. Delivered as the
     reply payload (and re-raised by ``query``) — an explicit timeout, never
     a silently dropped request."""
+
+
+#: why a batch closed: it filled, its deadline passed, the device drained
+#: after the batch was held for a full one (``kick``), or the server closed
+FLUSHES = ("full", "deadline", "idle", "drain")
+#: batches ``RetrievalServer.batch_log`` keeps: about 23 minutes of full
+#: batches at 1,500 queries/s
+BATCH_LOG_BOUND = 1 << 16
+
+
+class BatchRecord(NamedTuple):
+    """One executed batch, as ``RetrievalServer.batch_log`` keeps it. The
+    first three fields are the log's original row; readers that index rows
+    (``b[0]``, ``b[2]``) read the same numbers from either."""
+
+    size: int            # real queries (pad rows excluded)
+    t_dispatch: float    # perf_counter at the start of ``serve.stage``
+    t_done: float        # perf_counter when the results reached the host
+    seq: int             # per-server batch number, the spans' ``batch``
+    wait_s: float        # sum over its requests of formation - submit time
+    flush: str           # one of ``FLUSHES``
+    stage_s: float       # end of ``serve.stage`` minus ``t_dispatch``
+    resolve_s: float     # length of ``serve.resolve`` (nan while it runs)
+
+
+class Formed(NamedTuple):
+    """A batch as the batcher closed it, before staging."""
+
+    vecs: list
+    replies: list
+    seq: int
+    flush: str
+    wait_s: float
 
 
 class Reply(queue.Queue):
@@ -137,11 +172,16 @@ class Reply(queue.Queue):
     delivery goes through ``resolve``: first writer wins, later writers
     are no-ops — a posted result never overwrites a timeout or vice versa,
     and nobody ever blocks on the single reply slot.
+
+    ``submitted_at`` (``perf_counter``, stamped by ``BatchingQueue.submit``)
+    is where the request's queue wait starts.
     """
 
-    def __init__(self, deadline: float | None = None):
+    def __init__(self, deadline: float | None = None,
+                 submitted_at: float | None = None):
         super().__init__(maxsize=1)
         self.completed_at: float | None = None
+        self.submitted_at = submitted_at
         self.deadline = deadline
         self.done = False
         self._claim = threading.Lock()
@@ -174,6 +214,12 @@ class BatchingQueue:
     them anyway) and the batch that is dispatched ahead carries no padding.
     The moment the predicate flips (device idle — see ``kick()``), the
     deadline policy resumes and a partial batch flushes immediately.
+
+    ``form`` is ``next_batch`` for the server: it leaves the vectors
+    unstacked (stacking is staging work) and says which batch it closed
+    (``seq``), why (``flush``), and how long its requests queued
+    (``wait_s``). Its waits are the stager spans ``serve.wait_empty``,
+    ``serve.hold_full`` and ``serve.wait_deadline``.
     """
 
     def __init__(self, max_batch: int = 32, deadline_ms: float = 2.0):
@@ -181,10 +227,11 @@ class BatchingQueue:
         self.deadline = deadline_ms / 1e3
         self._items: deque = deque()
         self._cv = threading.Condition()
+        self._seq = 0     # number of the next batch to close
 
     def submit(self, qvec: np.ndarray,
                deadline: float | None = None) -> "Reply":
-        reply = Reply(deadline=deadline)
+        reply = Reply(deadline=deadline, submitted_at=time.perf_counter())
         with self._cv:
             self._items.append((qvec, reply))
             self._cv.notify_all()
@@ -208,30 +255,75 @@ class BatchingQueue:
         with self._cv:
             return not self._items
 
+    @property
+    def next_seq(self) -> int:
+        """Number the next batch will close under."""
+        with self._cv:
+            return self._seq
+
     def next_batch(self, timeout: float = 30.0,
                    stop: threading.Event | None = None,
                    want_full=None) -> tuple[np.ndarray, list] | None:
+        formed = self.form(timeout, stop, want_full)
+        if formed is None:
+            return None
+        return np.stack(formed.vecs), formed.replies
+
+    def form(self, timeout: float = 30.0,
+             stop: threading.Event | None = None,
+             want_full=None) -> Formed | None:
+        """``next_batch``, with the batch's number, flush reason and
+        queue wait, and its vectors left unstacked."""
         with self._cv:
-            ready = self._cv.wait_for(
-                lambda: self._items or (stop is not None and stop.is_set()),
-                timeout=timeout)
+            seq = self._seq
+            with TraceAnnotation("serve.wait_empty", batch=seq):
+                ready = self._cv.wait_for(
+                    lambda: self._items or (stop is not None
+                                            and stop.is_set()),
+                    timeout=timeout)
             if not ready or not self._items:
                 return None
             flush_at = time.monotonic() + self.deadline
-            while len(self._items) < self.max_batch:
-                if want_full is not None and want_full():
-                    # device busy: hold out for a full batch; a kick() or
-                    # new submit re-evaluates (1 s backstop vs lost wakeups)
-                    self._cv.wait(timeout=1.0)
-                    continue
-                rem = flush_at - time.monotonic()
-                if rem <= 0 or not self._cv.wait(timeout=rem):
-                    break
-            items = [self._items.popleft()
-                     for _ in range(min(self.max_batch, len(self._items)))]
-        vecs = np.stack([x[0] for x in items])
-        replies = [x[1] for x in items]
-        return vecs, replies
+            flush, held, phase, span = "full", False, None, None
+            try:
+                while len(self._items) < self.max_batch:
+                    hold = want_full is not None and want_full()
+                    name = ("serve.hold_full" if hold
+                            else "serve.wait_deadline")
+                    if name != phase:
+                        # one span per phase of the batch, not per wakeup
+                        if span is not None:
+                            span.__exit__(None, None, None)
+                        phase, span = name, TraceAnnotation(name, batch=seq)
+                        span.__enter__()
+                    if hold:
+                        # device busy: hold out for a full batch; a kick()
+                        # or new submit re-evaluates (1 s backstop vs lost
+                        # wakeups)
+                        held = True
+                        self._cv.wait(timeout=1.0)
+                        continue
+                    rem = flush_at - time.monotonic()
+                    if rem <= 0 or not self._cv.wait(timeout=rem):
+                        flush = ("drain" if stop is not None
+                                 and stop.is_set()
+                                 else "idle" if held else "deadline")
+                        break
+                items = [self._items.popleft()
+                         for _ in range(min(self.max_batch,
+                                            len(self._items)))]
+                self._seq += 1
+                t = time.perf_counter()
+                replies = [x[1] for x in items]
+                wait_s = sum(t - r.submitted_at for r in replies
+                             if r.submitted_at is not None)
+                return Formed([x[0] for x in items], replies, seq, flush,
+                              wait_s)
+            finally:
+                # the phase's span covers the batch's close too: the
+                # stager's spans leave no gap but the call into staging
+                if span is not None:
+                    span.__exit__(None, None, None)
 
 
 class RetrievalServer:
@@ -245,12 +337,33 @@ class RetrievalServer:
 
     ``pipeline_depth`` >= 2 (default 3) runs the stager/completer pipeline
     with that many batches in flight; <= 1 is the synchronous loop. Every
-    executed batch is logged as ``(size, t_dispatch, t_done)`` so both
-    occupancy and worker-side throughput are reportable: ``worker_qps``
-    (queries / busy-span wall time — the honest pipelined number, overlap
-    counted once) and ``service_qps`` (queries / summed per-batch service
-    time — matches the old sync metric, but double-counts overlapped
-    seconds when pipelined).
+    executed batch is logged in ``batch_log`` as a ``BatchRecord``: its
+    first fields ``(size, t_dispatch, t_done)`` give occupancy and
+    worker-side throughput — ``worker_qps`` (queries / busy-span wall time —
+    the honest pipelined number, overlap counted once) and ``service_qps``
+    (queries / summed per-batch service time — matches the old sync metric,
+    but double-counts overlapped seconds when pipelined) — and the rest its
+    batch number, queue wait, flush reason, staging and resolve times.
+    ``batch_log`` is a deque bounded at ``BATCH_LOG_BOUND`` records (the
+    newest kept), so a long-running server does not grow with every batch.
+
+    Spans (``jax.profiler.TraceAnnotation``; they land on the profiler's
+    host plane, on the device ops' clock, and cost about a microsecond
+    each when no profiler runs). Each carries ``batch=<seq>``, which links
+    a batch's stager and completer spans. The stager's spans follow each
+    other with no gap, so any moment the device idles falls in one:
+
+    * ``serve.slot_wait`` — waiting for an in-flight slot (pipelined only);
+    * ``serve.wait_empty`` — batcher empty, waiting for a first request;
+    * ``serve.hold_full`` — device busy, holding out for a full batch;
+    * ``serve.wait_deadline`` — collecting until the deadline flush;
+    * ``serve.stage`` — stack, pad, host-to-device copy, enqueue of the
+      fused search;
+
+    and the completer's (the same thread in the synchronous loop):
+
+    * ``serve.d2h`` — device finish plus the transfer of scores and ids;
+    * ``serve.resolve`` — ``batch_log`` append, replies, deadline sweep.
 
     ``bucket_batches=True`` pads partial batches to the next bucket in
     {8, 16, 32, …, max_batch} instead of always ``max_batch`` — a handful
@@ -285,9 +398,9 @@ class RetrievalServer:
         self._buckets = tuple(caps)
         self.pipeline_depth = max(1, pipeline_depth)
         self.batcher = BatchingQueue(max_batch=max_batch)
-        # (size, t_dispatch, t_done) per executed batch; appended by the
-        # completer, snapshotted by stats readers on other threads
-        self.batch_log: list[tuple[int, float, float]] = []
+        # one BatchRecord per executed batch; appended by the completer,
+        # snapshotted by stats readers on other threads
+        self.batch_log: deque[BatchRecord] = deque(maxlen=BATCH_LOG_BOUND)
         self._log_lock = threading.Lock()
         self._index_lock = threading.Lock()
         self.swap_count = 0
@@ -392,10 +505,23 @@ class RetrievalServer:
             return index.search_projected(q, W, k=self.k, mean=mean)
         return index.search(q, k=self.k)
 
-    def _post(self, scores, ids, replies, t0):
+    def _stage(self, formed: Formed):
+        """Stack, pad and enqueue one formed batch inside ``serve.stage``:
+        (scores, ids, replies, t_dispatch, record), the record still
+        without its completion time."""
+        t0 = time.perf_counter()
+        with TraceAnnotation("serve.stage", batch=formed.seq):
+            scores, ids = self._dispatch_guarded(formed.vecs, formed.replies)
+            rec = BatchRecord(len(formed.replies), t0, t0, formed.seq,
+                              formed.wait_s, formed.flush,
+                              time.perf_counter() - t0, float("nan"))
+            return scores, ids, formed.replies, t0, rec
+
+    def _post(self, scores, ids, replies, t0, rec: BatchRecord):
         try:
-            scores = np.asarray(scores)   # blocks on this batch's D2H only
-            ids = np.asarray(ids)         # (both BEFORE taking any lock)
+            with TraceAnnotation("serve.d2h", batch=rec.seq):
+                scores = np.asarray(scores)   # blocks on this batch's D2H
+                ids = np.asarray(ids)         # (both BEFORE any lock)
         except BaseException as e:
             # a poisoned device result must fail ITS batch's clients, not
             # strand them: resolve in-hand replies before the crash
@@ -405,12 +531,21 @@ class RetrievalServer:
                 r.resolve(e, t)
             raise
         t1 = time.perf_counter()
-        with self._log_lock:
-            self.batch_log.append((len(replies), t0, t1))
-        for i, r in enumerate(replies):
-            # first-writer-wins: an already-expired reply keeps its
-            # TimedOut (and the single slot is never double-filled)
-            r.resolve((scores[i], ids[i]), t1)
+        with TraceAnnotation("serve.resolve", batch=rec.seq):
+            # logged before the replies, so a client that saw its reply
+            # sees its batch; the resolve time is filled in once known
+            rec = rec._replace(t_done=t1)
+            with self._log_lock:
+                self.batch_log.append(rec)
+            for i, r in enumerate(replies):
+                # first-writer-wins: an already-expired reply keeps its
+                # TimedOut (and the single slot is never double-filled)
+                r.resolve((scores[i], ids[i]), t1)
+            self._expire_overdue()
+            done = rec._replace(resolve_s=time.perf_counter() - t1)
+            with self._log_lock:
+                if self.batch_log and self.batch_log[-1] is rec:
+                    self.batch_log[-1] = done
 
     # -- deadline expiry ----------------------------------------------------
     def _dl_poll(self) -> float:
@@ -476,23 +611,20 @@ class RetrievalServer:
         # thread, a dispatch that hangs also hangs the sweep — prompt
         # in-hang expiry needs pipeline_depth >= 2 (completer-side sweep)
         while not (self._stop.is_set() and self.batcher.empty()):
-            self._expire_overdue()
-            item = self.batcher.next_batch(stop=self._stop,
-                                           timeout=self._dl_poll())
-            if item is None:
+            formed = self.batcher.form(stop=self._stop,
+                                       timeout=self._dl_poll())
+            if formed is None:
+                self._expire_overdue()     # _post sweeps after a batch
                 continue
-            vecs, replies = item
-            t0 = time.perf_counter()
-            scores, ids = self._dispatch_guarded(vecs, replies)
-            self._post(scores, ids, replies, t0)
+            self._post(*self._stage(formed))
 
     def _dispatch_guarded(self, vecs, replies):
-        """_dispatch, but a crash resolves the in-hand batch's replies with
-        the exception before propagating to _guard — the batch being
-        assembled is accepted work, and accepted work never silently
-        strands its clients."""
+        """_dispatch of the stacked ``vecs``, but a crash resolves the
+        in-hand batch's replies with the exception before propagating to
+        _guard — the batch being assembled is accepted work, and accepted
+        work never silently strands its clients."""
         try:
-            return self._dispatch(vecs)
+            return self._dispatch(np.stack(vecs))
         except BaseException as e:
             t = time.perf_counter()
             for r in replies:
@@ -508,20 +640,19 @@ class RetrievalServer:
     def _stage_loop(self):
         while not ((self._stop.is_set() and self.batcher.empty())
                    or self.error is not None):
-            if not self._slots.acquire(timeout=0.2):
+            with TraceAnnotation("serve.slot_wait",
+                                 batch=self.batcher.next_seq):
+                got = self._slots.acquire(timeout=0.2)
+            if not got:
                 continue                           # re-check stop, try again
-            item = self.batcher.next_batch(stop=self._stop,
-                                           want_full=self._busy)
-            if item is None:
+            formed = self.batcher.form(stop=self._stop, want_full=self._busy)
+            if formed is None:
                 self._slots.release()
                 continue
-            vecs, replies = item
-            t0 = time.perf_counter()
-            # async — does not block
-            scores, ids = self._dispatch_guarded(vecs, replies)
+            staged = self._stage(formed)           # async — does not block
             with self._inflight_lock:
                 self._inflight_n += 1
-            self._inflight.put((scores, ids, replies, t0))
+            self._inflight.put(staged)
         self._inflight.put(None)                   # drain sentinel
 
     def _complete_loop(self):
@@ -538,7 +669,6 @@ class RetrievalServer:
             if item is None:
                 return
             self._post(*item)
-            self._expire_overdue()
             with self._inflight_lock:
                 self._inflight_n -= 1
                 idle = self._inflight_n == 0
@@ -601,22 +731,40 @@ class RetrievalServer:
             self.batch_log.clear()
 
     def worker_stats(self) -> dict:
-        """Occupancy + worker-side throughput from the executed batches."""
+        """Occupancy + worker-side throughput from the executed batches,
+        and from ``BatchRecord`` rows: mean queue wait per request
+        (``wait_ms``), mean staging, in-flight (end of staging to results
+        on the host: ``d2h_ms``) and resolve ms per batch, and the count of
+        each flush reason. Rows are read by index: a plain
+        ``(size, t_dispatch, t_done)`` log gets the first five keys."""
         with self._log_lock:
             log = list(self.batch_log)
         if not log:
             return dict(batches=0, mean_batch=0.0, occupancy=0.0,
-                        worker_qps=0.0, service_qps=0.0)
-        sizes = np.array([s for s, _, _ in log], dtype=np.float64)
-        t0s = np.array([a for _, a, _ in log], dtype=np.float64)
-        t1s = np.array([b for _, _, b in log], dtype=np.float64)
+                        worker_qps=0.0, service_qps=0.0, wait_ms=0.0,
+                        stage_ms=0.0, d2h_ms=0.0, resolve_ms=0.0,
+                        flushes=dict.fromkeys(FLUSHES, 0))
+        sizes = np.array([b[0] for b in log], dtype=np.float64)
+        t0s = np.array([b[1] for b in log], dtype=np.float64)
+        t1s = np.array([b[2] for b in log], dtype=np.float64)
         span = float(t1s.max() - t0s.min())
         busy = float((t1s - t0s).sum())
-        return dict(batches=len(log),
-                    mean_batch=float(sizes.mean()),
-                    occupancy=float(sizes.mean() / self.max_batch),
-                    worker_qps=float(sizes.sum() / max(span, 1e-9)),
-                    service_qps=float(sizes.sum() / max(busy, 1e-9)))
+        out = dict(batches=len(log),
+                   mean_batch=float(sizes.mean()),
+                   occupancy=float(sizes.mean() / self.max_batch),
+                   worker_qps=float(sizes.sum() / max(span, 1e-9)),
+                   service_qps=float(sizes.sum() / max(busy, 1e-9)))
+        if len(log[0]) > 3:
+            stage = np.array([b[6] for b in log])
+            out.update(
+                wait_ms=1e3 * sum(b[4] for b in log) / sizes.sum(),
+                stage_ms=1e3 * float(stage.mean()),
+                d2h_ms=1e3 * float((t1s - t0s - stage).mean()),
+                # the newest record's resolve time may still be running
+                resolve_ms=1e3 * float(np.mean(
+                    [b[7] for b in log if not np.isnan(b[7])] or [0.0])),
+                flushes={f: sum(b[5] == f for b in log) for f in FLUSHES})
+        return out
 
     def close(self):
         """Stop accepting work *after* draining: every already-submitted
@@ -626,6 +774,15 @@ class RetrievalServer:
         self.batcher.kick()
         for t in self._threads:
             t.join(timeout=60.0)
+
+
+def _worker_times(stats: dict) -> str:
+    """The ``[serve] worker:`` line of where a batch's time went."""
+    flushes = " ".join(f"{f}={n}" for f, n in stats["flushes"].items())
+    return (f"[serve] worker: queue wait {stats['wait_ms']:.2f} ms/query, "
+            f"stage {stats['stage_ms']:.2f} ms, d2h "
+            f"{stats['d2h_ms']:.2f} ms, resolve {stats['resolve_ms']:.2f} "
+            f"ms per batch; flushes {flushes}")
 
 
 def _serve_mesh(ndev: int, merge: str):
@@ -1166,6 +1323,7 @@ def main() -> None:
           f"{stats['batches']} batches, mean batch "
           f"{stats['mean_batch']:.1f}/{args.batch} "
           f"({stats['occupancy']*100:.0f}% occupancy)")
+    print(_worker_times(stats))
 
     if args.open_loop > 0:
         res = _drive_open(server, Q, rate=args.open_loop)
@@ -1176,6 +1334,7 @@ def main() -> None:
               f"p99={res['p99_ms']:.2f}ms  "
               f"worker={ostats['worker_qps']:.1f} qps "
               f"({ostats['occupancy']*100:.0f}% occupancy)")
+        print(_worker_times(ostats))
 
     def _delta_units(idx) -> str:
         if hasattr(idx, "storage"):
