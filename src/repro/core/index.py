@@ -23,6 +23,7 @@ Scores are always accumulated in fp32 regardless of index dtype.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 from functools import partial
@@ -125,6 +126,43 @@ def _staged_topk_merge(s: jax.Array, ids: jax.Array, k: int,
         return s, ids
 
 
+BUCKET = 128   # rows per select bucket: one lane width
+
+
+def _select_plan(k: int, block: int) -> int:
+    """Bucket width for ``_scan_topk``'s per-strip select, 0 for a direct
+    ``top_k`` over the strip.
+
+    Bucketing pays when the k buckets it keeps are a small share of the
+    strip (k·W ≤ block / 8): the served k=10 over 65,536-row strips keeps
+    1,280 of 65,536 columns. A large k (the cascade's coarse scan) or a
+    strip that is not a whole number of buckets selects directly.
+    """
+    if block % BUCKET == 0 and 8 * k * BUCKET <= block:
+        return BUCKET
+    return 0
+
+
+def _bucket_candidates(s3: jax.Array, bmax: jax.Array, at: jax.Array,
+                       k: int) -> tuple[jax.Array, jax.Array]:
+    """The (B, k·W) scores and ids of the k buckets with the largest maxima.
+
+    ``s3`` is the strip as (B, block / W, W) buckets of W contiguous rows,
+    ``bmax`` each bucket's max. A strip entry outside the kept buckets is
+    preceded, in (score desc, id asc) order, by the max of each of the k
+    kept ones (``top_k`` breaks equal maxima towards the lower bucket), so
+    the strip's top-k lies in them. Kept buckets go in ascending order, so
+    candidate ids ascend and ``top_k``'s first-occurrence tie-break sees
+    them as it sees the strip.
+    """
+    B, _, W = s3.shape
+    _, top = jax.lax.top_k(bmax, k)                            # (B, k)
+    top = jnp.sort(top, axis=1)
+    cs = jnp.take_along_axis(s3, top[:, :, None], axis=1)      # (B, k, W)
+    ci = at + top[:, :, None] * W + jnp.arange(W, dtype=jnp.int32)
+    return cs.reshape(B, k * W), ci.reshape(B, k * W)
+
+
 @partial(jax.jit, static_argnames=("k", "block", "vma_axes", "guard"))
 def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
                vma_axes: tuple[str, ...] | None = None, guard: str = "row"
@@ -138,11 +176,16 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
       * the index blocks keep their storage dtype (int8 stays int8 in the
         scan carry's xs); each block upcasts to f32 only for its matmul —
         no full-index fp32 shadow copy;
-      * two-stage select: ``top_k`` over the (B, block) strip alone, then a
-        tiny (B, 2k) merge with the running list — never a sort over the
-        (B, k + block) concat;
+      * exact bucketed select (``_select_plan``: k·W ≤ block / 8 with
+        W = 128): the strip's per-bucket maxima (B, block / W) pick the k
+        buckets that must hold its top-k; one ``top_k`` over the running
+        list followed by those buckets' (B, k·W) scores merges them —
+        never a sort over the whole strip. Otherwise ``top_k`` over the
+        strip alone, then a tiny (B, 2k) merge with the running list.
+        Both give the same scores and ids, ties included;
       * block-skip guard: a strip that cannot improve the running top-k
-        skips selection entirely under ``lax.cond``. ``guard="row"``
+        skips selection entirely under ``lax.cond``; the strip's max is
+        the max of its bucket maxima, read once. ``guard="row"``
         (default): row b improves iff ``max(s[b]) > min(run_s[b])``; the
         strip is skipped iff *no* row improves (a strictly weaker skip
         condition than the legacy ``guard="batch"`` global compare, so
@@ -163,8 +206,11 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
     ``/score/`` or ``/select/``, names a profile can follow across
     refactors): ``score`` is the strip's slice, upcast, matmul and mask;
     ``select`` the improvement test and the whole guarded merge, per-strip
-    ``top_k`` included. ``project_queries`` adds ``project`` and
-    ``_staged_topk_merge`` ``merge``.
+    ``top_k`` included. On the bucketed path ``select`` nests ``bucket``
+    (the bucket maxima) and ``refine`` (the guarded merge: bucket
+    ``top_k``, gather, merge).
+    ``project_queries`` adds ``project`` and ``_staged_topk_merge``
+    ``merge``.
     """
     n, d = D.shape
     B = Q.shape[0]
@@ -172,6 +218,7 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
     nblocks = -(-n // block)
     Qf = Q.astype(jnp.float32)
     kk = min(k, block)   # strip-local candidate count
+    W = _select_plan(k, block)
 
     if nblocks == 1:
         # single strip (block == n): the running list is empty, a guard can
@@ -203,15 +250,26 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
             s = jnp.where(ids >= start, s, -jnp.inf)
 
         with jax.named_scope("select"):
-            imp = jnp.max(s, axis=1) > jnp.min(bs, axis=1)       # (B,)
+            if W:
+                with jax.named_scope("bucket"):
+                    # one (B, block / W, W) view for the maxima and the
+                    # gather: the TPU relayouts the strip once
+                    s = s.reshape(B, block // W, W)
+                    smax = s.max(axis=2)                         # (B, nb)
+            else:
+                smax = s
+            imp = jnp.max(smax, axis=1) > jnp.min(bs, axis=1)    # (B,)
 
             def merge(carry_in):
                 bs0, bi0 = carry_in
-                ss, si = jax.lax.top_k(s, kk)                    # (B, kk)
-                gi = at + si.astype(jnp.int32)
+                if W:
+                    ss, gi = _bucket_candidates(s, smax, at, k)
+                else:
+                    ss, si = jax.lax.top_k(s, kk)                # (B, kk)
+                    gi = at + si.astype(jnp.int32)
                 # running list first: at -inf ties its (-1) pads win the
                 # first-occurrence tie-break, matching the kernel's pads
-                cs = jnp.concatenate([bs0, ss], axis=1)          # (B, k+kk)
+                cs = jnp.concatenate([bs0, ss], axis=1)
                 ci = jnp.concatenate([bi0, gi], axis=1)
                 ms, mi = _topk_merge(cs, ci, k)
                 if guard == "row":
@@ -224,9 +282,11 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
             if guard == "row":
                 can_improve = jnp.any(imp)
             else:
-                can_improve = jnp.max(s) > jnp.min(bs)
-            return jax.lax.cond(can_improve, merge, lambda c: c,
-                                (bs, bi)), None
+                can_improve = jnp.max(smax) > jnp.min(bs)
+            with (jax.named_scope("refine") if W
+                  else contextlib.nullcontext()):
+                return jax.lax.cond(can_improve, merge, lambda c: c,
+                                    (bs, bi)), None
 
     init = (jnp.full((B, k), -jnp.inf, jnp.float32), jnp.full((B, k), -1, jnp.int32))
     if vma_axes:

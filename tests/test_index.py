@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import DenseIndex, ShardedDenseIndex
 from repro.core.quantization import dequantize_int8, quantization_error, quantize_int8_per_dim
@@ -95,3 +96,100 @@ def test_sharded_index_pads_uneven_rows():
     sidx = ShardedDenseIndex.build(D, mesh)
     s, ids = sidx.search(Q, k=5)
     assert int(ids.max()) < 1000
+
+
+# ---------------------------------------------------------------------------
+# the scan's bucketed select against its direct per-strip top_k
+# ---------------------------------------------------------------------------
+
+def _direct_scan(monkeypatch, *args, **kw):
+    """``_scan_topk`` traced afresh with the bucketed select off: today's
+    per-strip ``top_k``, the oracle of the bucketed path."""
+    from repro.core import index as ix
+    with monkeypatch.context() as mp:
+        mp.setattr(ix, "_select_plan", lambda k, block: 0)
+        scan = jax.jit(ix._scan_topk.__wrapped__,
+                       static_argnames=("k", "block", "vma_axes", "guard"))
+        return scan(*args, **kw)
+
+
+def _tied_data(n, d, B, dtype, rng):
+    """Integer-valued rows, a quarter of them copies of others, and zero
+    rows (+0 and -0), so that equal scores fall in many buckets and strips;
+    the first query is all negative against non-negative rows, so its best
+    scores are zeros of both signs."""
+    D = rng.integers(0, 3, (n, d)).astype(np.float32)
+    D[rng.integers(0, n, n // 4)] = D[rng.integers(0, n, n // 4)]
+    D[rng.integers(0, n, n // 20)] = 0.0
+    D[rng.integers(0, n, n // 20)] = -0.0
+    Q = rng.integers(-1, 2, (B, d)).astype(np.float32)
+    Q[0] = -rng.integers(1, 3, d)
+    D = D.astype(np.int8) if dtype == "int8" else D
+    return jnp.asarray(D), jnp.asarray(Q)
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(np.int32) if x.dtype == np.float32 else x
+
+
+@pytest.mark.parametrize("B", [1, 32])
+@pytest.mark.parametrize("guard", ["row", "batch"])
+@pytest.mark.parametrize("dtype", ["f32", "int8"])
+def test_bucketed_select_bitwise_matches_direct(monkeypatch, dtype, guard, B):
+    """k=8 over 8,192-row strips engages the bucketed select; 3 strips and
+    a sliding masked last one. Scores (to the bit) and ids, ties included,
+    equal the direct path's."""
+    from repro.core.index import BUCKET, _scan_topk, _select_plan
+    assert _select_plan(8, 8192) == BUCKET
+    D, Q = _tied_data(3 * 8192 + 1000, 16, B, dtype,
+                      np.random.default_rng(B))
+    got = _scan_topk(D, Q, 8, block=8192, guard=guard)
+    want = _direct_scan(monkeypatch, D, Q, k=8, block=8192, guard=guard)
+    for g, w in zip(got, want):
+        assert (_bits(g) == _bits(w)).all()
+    assert (np.asarray(want[0])[0] == 0).all()   # the zeros case is hit
+
+
+def test_bucketed_select_sharded_one_device(monkeypatch):
+    """The per-shard scan under shard_map (``vma_axes``) takes the bucketed
+    select too, with the direct path's bits."""
+    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    D, Q = _tied_data(3 * 8192 + 1000, 16, 32, "f32",
+                      np.random.default_rng(3))
+    got = ShardedDenseIndex.build(D, mesh).search(Q, k=8, block=8192)
+    want = _direct_scan(monkeypatch, D, Q, k=8, block=8192)
+    for g, w in zip(got, want):
+        assert (_bits(g) == _bits(w)).all()
+
+
+@pytest.mark.parametrize("k,block,engaged", [
+    (10, 65536, True),       # the served shape
+    (8, 8192, True),
+    (640, 65536, False),     # a cascade coarse scan, N·k = 64·10
+    (10, 65536 + 64, False),  # not a whole number of buckets
+    (10, 256, False),        # a small test strip
+])
+def test_select_plan(k, block, engaged):
+    from repro.core.index import BUCKET, _select_plan
+    assert _select_plan(k, block) == (BUCKET if engaged else 0)
+
+
+def test_bucketed_select_scopes_in_hlo():
+    """When the path engages, the compiled scan's op_names nest
+    ``/select/bucket/`` and ``/select/refine/`` under ``select``; the
+    direct path has neither."""
+    import re
+    from repro.core.index import _scan_topk
+    D, Q = _data(3 * 8192 + 1000, 16)
+
+    def names(block):
+        hlo = _scan_topk.lower(D, Q, 8, block=block).compile().as_text()
+        return re.findall(r'op_name="([^"]*)"', hlo)
+
+    bucketed = names(8192)
+    assert any("/select/bucket/" in n for n in bucketed)
+    assert any("/select/refine/" in n for n in bucketed)
+    direct = names(256)
+    assert any("/select/" in n for n in direct)
+    assert not any("/bucket/" in n or "/refine/" in n for n in direct)
