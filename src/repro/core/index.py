@@ -209,8 +209,8 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
     ``top_k`` included. On the bucketed path ``select`` nests ``bucket``
     (the bucket maxima) and ``refine`` (the guarded merge: bucket
     ``top_k``, gather, merge).
-    ``project_queries`` adds ``project`` and ``_staged_topk_merge``
-    ``merge``.
+    ``project_queries`` adds ``project``; ``ShardedDenseIndex``'s id
+    offset, pad mask and cut and ``_staged_topk_merge`` add ``merge``.
     """
     n, d = D.shape
     B = Q.shape[0]
@@ -542,10 +542,20 @@ class ShardedDenseIndex:
             b += self.scale.size * self.scale.dtype.itemsize
         return b
 
+    @property
+    def query_sharding(self) -> NamedSharding:
+        """Where a server stages a batch of queries: replicated on every
+        device of the mesh, the layout the search takes them in, so no
+        device receives them first and copies them on."""
+        return NamedSharding(self.mesh, P())
+
     def search(self, queries: jax.Array, k: int = 10,
                merge: Merge | None = None,
                block: int | None = None) -> tuple[jax.Array, jax.Array]:
-        q = jnp.atleast_2d(queries).astype(jnp.float32)
+        # a (B, d) batch goes in as it is: ``atleast_2d`` would run as a
+        # program of its own on every device, once a batch
+        q = queries if np.ndim(queries) == 2 else jnp.atleast_2d(queries)
+        q = q.astype(jnp.float32)
         if self.scale is not None:
             q = q * self.scale[None, :]
         k = min(k, self.n)
@@ -606,9 +616,6 @@ class ShardedDenseIndex:
             stages = (axes,)
 
         def shard_fn(D_local, q_rep):
-            # Which shard am I? Flat linear index over mesh axes.
-            idx = compat.axis_index(axes)
-            base = idx * rows_per
             if backend == "pallas":
                 from repro.kernels import ops as kops
                 if block is None:
@@ -620,12 +627,16 @@ class ShardedDenseIndex:
                 s, ids = _scan_topk(D_local, q_rep, kp,
                                     block=65536 if block is None else block,
                                     vma_axes=axes)
-            ids = jnp.where(ids >= 0, ids + base, -1)
-            padded = ids >= n_real
-            s = jnp.where(padded, -jnp.inf, s)
-            ids = jnp.where(padded, -1, ids)
-            if pad:
-                s, ids = _topk_merge(s, ids, k)
+            with jax.named_scope("merge"):
+                # shard-local ids to global ones (flat linear shard index
+                # over the mesh axes), then the pad mask and cut
+                base = compat.axis_index(axes) * rows_per
+                ids = jnp.where(ids >= 0, ids + base, -1)
+                padded = ids >= n_real
+                s = jnp.where(padded, -jnp.inf, s)
+                ids = jnp.where(padded, -1, ids)
+                if pad:
+                    s, ids = _topk_merge(s, ids, k)
             # Gather every shard's candidates and merge (1 or 2 stages).
             return _staged_topk_merge(s, ids, k, stages)
 

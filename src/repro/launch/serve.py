@@ -491,6 +491,11 @@ class RetrievalServer:
         The (index, projection) pair is snapshotted under the swap lock:
         the whole batch runs against one consistent segment set even if
         ``swap_index`` lands mid-flight.
+
+        The padded batch goes from the host straight to where the index
+        takes it: every device of a ``ShardedDenseIndex``'s mesh (its
+        ``query_sharding``), so no device receives it first and copies it
+        on to the rest; any other index gets it on the default device.
         """
         with self._index_lock:
             index, proj = self.index, self._proj
@@ -499,7 +504,7 @@ class RetrievalServer:
         if b < cap:
             vecs = np.concatenate(
                 [vecs, np.zeros((cap - b, vecs.shape[1]), vecs.dtype)])
-        q = jnp.asarray(vecs)
+        q = jax.device_put(vecs, getattr(index, "query_sharding", None))
         if proj is not None:
             W, mean = proj
             return index.search_projected(q, W, k=self.k, mean=mean)

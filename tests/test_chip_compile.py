@@ -140,3 +140,31 @@ def test_sharded_dispatch_compiles_on_v5e_2x2(topo, compiled_kernels, merge,
     hlo = c.as_text()
     assert "all-gather" in hlo
     assert ("tpu_custom_call" in hlo) == (backend == "pallas")
+
+
+def test_unpruned_sharded_search_at_published_widths_on_v5e_2x2(topo):
+    """The unpruned baseline as served over the four chips of one host:
+    MS MARCO v1 at 768-d f32, 27.2 GB, row-sharded (8,841,824 rows with
+    one padding row, 6.79 GB a chip), raw (32, 768) queries replicated,
+    ``ShardedDenseIndex._search_fn``'s program."""
+    devs = np.array(topo.devices)
+    mesh = Mesh(devs.reshape(4), ("data",))
+    n_pad = MSMARCO_ROWS + (-MSMARCO_ROWS) % 4
+    assert n_pad - MSMARCO_ROWS == 1
+    rows = NamedSharding(mesh, P(mesh.axis_names, None))
+    vectors = jax.ShapeDtypeStruct((n_pad, D_IN), jnp.float32, sharding=rows)
+    index = ShardedDenseIndex(vectors=vectors, mesh=mesh,
+                              n_real=MSMARCO_ROWS)
+    c = jax.jit(index._search_fn(K, "flat")).lower(
+        vectors, _spec(index.query_sharding, (B, D_IN),
+                       jnp.float32)).compile()
+    mem = c.memory_analysis()
+    shard_bytes = n_pad // 4 * D_IN * 4
+    assert mem.argument_size_in_bytes <= shard_bytes + B * D_IN * 4
+    # the scan scores each 65,536 x 768 f32 strip where it lies: a copy of
+    # one strip (201,326,592 B, an upcast or a materialised slice) would
+    # reach this limit; the scores and the select's buffers stay far below
+    # it (518,144 B with JAX 0.9)
+    strip = 65536 * D_IN * 4
+    assert mem.temp_size_in_bytes < strip
+    assert "all-gather" in c.as_text()

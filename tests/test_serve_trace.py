@@ -266,3 +266,34 @@ def test_device_scopes_in_compiled_hlo():
     hlo = fn.lower(sharded.vectors, W, sharded.scale, None,
                    Q).compile().as_text()
     assert _scopes(hlo) == {"project", "score", "select", "merge"}
+
+
+# the scan's loop and its set-up (the empty running list, the strip
+# starts): the loop's time is its body's, which sits in score and select
+SCAN_PLUMBING = {"while", "broadcast_in_dim", "iota", "mul"}
+
+
+@pytest.mark.parametrize("merge", ["flat", "hierarchical"])
+def test_sharded_search_ops_all_scoped(merge):
+    """Every operation of the unpruned sharded search sits in ``score``,
+    ``select`` or ``merge``: the shard-local id offset, the pad mask and
+    the pad cut (n = 3 mod 4, so one padding row) included, which the
+    cross-chip merge waits on."""
+    rng = np.random.default_rng(6)
+    D = jnp.asarray(rng.standard_normal((3003, 16)), jnp.float32)
+    shape = (4,) if merge == "flat" else (2, 2)
+    mesh = jax.make_mesh(shape, ("data",) if merge == "flat"
+                         else ("row", "col"))
+    index = ShardedDenseIndex.build(D, mesh, merge=merge)
+    q = jax.device_put(np.zeros((8, 16), np.float32), index.query_sharding)
+    hlo = jax.jit(index._search_fn(5, merge, 1024)).lower(
+        index.vectors, q).compile().as_text()
+    ops = [n for n in re.findall(r'op_name="([^"]*)"', hlo)
+           if n.startswith("jit(shard_fn)/shard_map/")]
+    assert ops
+    unscoped = {n for n in ops
+                if not any(f"/{s}/" in n for s in ("score", "select",
+                                                   "merge"))}
+    assert {n.rsplit("/", 1)[1] for n in unscoped} <= SCAN_PLUMBING
+    assert all("/jit(_scan_topk)/" in n for n in unscoped)
+    assert _scopes(hlo) == {"score", "select", "merge"}

@@ -310,3 +310,72 @@ def test_compat_axis_size_inside_shard_map():
                            mesh=mesh, in_specs=(), out_specs=P(),
                            check_vma=False)()
     assert int(out) == 4
+
+
+SERVED_N, SERVED_D, SERVED_K = 20_011, 768, 10   # n = 3 mod 4: one pad row
+# f32 accumulation of a 768-term dot product, in any order, errs by at most
+# gamma_d * sum|x_i q_i| <= gamma_d * |x| |q| with gamma_d = d u / (1 - d u),
+# u = 2**-24; rows and queries are unit vectors, so about 4.6e-5
+GAMMA_D = SERVED_D * 2.0 ** -24 / (1 - SERVED_D * 2.0 ** -24)
+COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                  "/jax/core/compile/backend_compile_duration")
+
+
+@pytest.fixture(scope="module")
+def served_unpruned():
+    """A ``RetrievalServer`` over a four-device ``ShardedDenseIndex`` of
+    unit 768-d f32 rows, raw queries (no pruner), warmed up; the float64
+    rows and their unit queries."""
+    from functools import partial
+
+    from repro.launch.serve import RetrievalServer
+    rng = np.random.default_rng(15)
+    D = rng.standard_normal((SERVED_N, SERVED_D)).astype(np.float32)
+    D /= np.linalg.norm(D, axis=1, keepdims=True)
+    Q = rng.standard_normal((32, SERVED_D)).astype(np.float32)
+    Q /= np.linalg.norm(Q, axis=1, keepdims=True)
+    index = ShardedDenseIndex.build(jnp.asarray(D), _mesh(4))
+    assert index.vectors.shape[0] == SERVED_N + 1
+    # 5,003 rows a shard in strips of 4,096: the second strip is ragged
+    index.search = partial(index.search, block=4096)
+    server = RetrievalServer(index, None, k=SERVED_K)
+    server.warmup()
+    yield server, D.astype(np.float64), Q
+    server.close()
+
+
+@pytest.mark.parametrize("nq", [1, 7, 32])
+def test_served_unpruned_sharded_matches_exact(served_unpruned, nq):
+    """The served raw-query path over four shards against a float64 exact
+    top-10: ids wherever the 10th and 11th exact scores lie apart, scores
+    within the f32 accumulation bound, and nothing compiled after
+    ``warmup()``."""
+    server, D64, Q = served_unpruned
+    compiles = []
+
+    def listen(event, duration, **_):
+        if event in COMPILE_EVENTS:
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        replies = [server.submit(q) for q in Q[:nq]]
+        got = [r.get(timeout=120) for r in replies]
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+    exact = Q[:nq].astype(np.float64) @ D64.T                 # (nq, n)
+    order = np.argsort(-exact, axis=1, kind="stable")
+    norms = np.linalg.norm(D64, axis=1)
+    tol = GAMMA_D * norms.max() * np.linalg.norm(Q[:nq].astype(np.float64),
+                                                 axis=1).max()
+    for i, (scores, ids) in enumerate(got):
+        ids = np.asarray(ids, np.int64)
+        assert ids.shape == (SERVED_K,) and len(set(ids.tolist())) == SERVED_K
+        assert ((ids >= 0) & (ids < SERVED_N)).all()
+        assert (np.diff(scores) <= 0).all()
+        np.testing.assert_allclose(scores, exact[i, ids], rtol=0, atol=tol)
+        kth, next_ = exact[i, order[i, SERVED_K - 1]], exact[i, order[i,
+                                                                    SERVED_K]]
+        if kth - next_ > 2 * tol:
+            assert set(ids.tolist()) == set(order[i, :SERVED_K].tolist())
