@@ -35,7 +35,8 @@ from repro.analysis import Finding
 from repro.analysis.jaxpr_lints import iter_all_eqns
 from repro.kernels.pca_project import project_geometry
 from repro.kernels.topk_score import (PagedTopKGeometry, TopKGeometry,
-                                      paged_topk_geometry, topk_geometry)
+                                      exact_in_bf16, paged_topk_geometry,
+                                      topk_geometry)
 
 #: per-core VMEM on current TPU generations; the checker budget defaults to
 #: this minus a safety margin for compiler-managed temporaries.
@@ -68,14 +69,17 @@ def estimate_topk_vmem(g: TopKGeometry, dtype: str,
     already priced as ``gids``).
     """
     w = _width(dtype)
-    q_tile = 2 * g.block_b * g.m * 4                  # f32 query tile
+    # rows bf16 holds exactly meet the query's three bf16 terms: a (3bb, m)
+    # bf16 tile, the strip in bf16 and a (3bb, block_n) f32 product
+    terms = 3 if exact_in_bf16(dtype) else 1
+    q_tile = 2 * terms * g.block_b * g.m * (2 if terms == 3 else 4)
     d_strip = 2 * g.block_n * g.m * w                 # storage-dtype strip
     ids_strip = 2 * g.block_n * 4 if with_ids else 0  # row_ids int32 strip
     outs = 2 * g.block_b * g.k * (4 + 4)              # scores + ids
     scratch = g.block_b * g.k * (4 + 4)               # running top-k
-    scores = g.block_b * g.block_n * 4                # S_blk f32
+    scores = terms * g.block_b * g.block_n * 4        # S_blk f32
     gids = g.block_b * g.block_n * 4                  # iota/broadcast int32
-    dequant = g.block_n * g.m * 4 if w < 4 else 0     # in-register upcast
+    dequant = g.block_n * g.m * (2 if terms == 3 else 4) if w < 4 else 0
     fold = g.block_b * g.fold_r * g.fold_w * (4 + 4)  # fs + fi
     cand = g.block_b * (g.k + g.fold_w) * (4 + 4)     # merge buffer
     parts = dict(q_tile=q_tile, d_strip=d_strip, ids_strip=ids_strip,
@@ -103,13 +107,17 @@ def estimate_paged_topk_vmem(g: PagedTopKGeometry, dtype: str,
     """
     w = _width(dtype)
     R = g.page_rows
+    # rows bf16 holds exactly: the query tile splits per page into three
+    # bf16 terms, the page converts to bf16, the product is (3bb, R) f32
+    terms = 3 if exact_in_bf16(dtype) else 1
     parts = dict(
         q_tile=2 * g.block_b * g.m * 4,               # f32 query tile
+        q_terms=g.block_b * g.m * 2 * 3 if terms == 3 else 0,
         page_window=g.depth * R * g.m * w,            # DMA buffers x depth
         scale_window=g.depth * g.m * 4 if with_scale else 0,
         ids_window=g.depth * R * 4 if with_ids else 0,
-        dequant=R * g.m * 4 if w < 4 else 0,          # in-register upcast
-        scores=g.block_b * R * 4,                     # per-page strip
+        dequant=R * g.m * (2 if terms == 3 else 4) if w < 4 else 0,
+        scores=terms * g.block_b * R * 4,             # per-page strip
         gids=g.block_b * R * 4,
         fold=g.block_b * g.fold_r * g.fold_w * (4 + 4),
         cand=g.block_b * (g.k + g.fold_w) * (4 + 4),
@@ -383,7 +391,8 @@ SERVING_TOPK_CONFIGS = (
     (1_000_000, 128, 128, 10, 1024, 128, "int8"),
     (1_000_000, 128, 128, 10, 1024, 128, "float32"),
     (1_000_000, 256, 64, 100, 1024, 128, "float32"),
-    # bn=4096 at k=100 busts the budget (14.9 MiB: fold + dequant strips);
+    # bn=4096 at k=100 busts the budget (17.0 MiB: the three-term score
+    # product + fold strips);
     # 2048 is the largest power-of-two strip that fits with headroom
     (10_000_000, 256, 256, 100, 2048, 128, "int8"),
 )

@@ -54,7 +54,7 @@ from repro.core.index import (
     _topk_merge,
     project_queries,
 )
-from repro.kernels.topk_score import PRECISION
+from repro.kernels.topk_score import query_terms, score_rows
 
 
 def _shortlist(cids: jax.Array) -> jax.Array:
@@ -81,8 +81,8 @@ def _cascade_dense_projected(Dc, scale_c, Df, scale_f, W, mean, Q, k: int,
     gather + exact rescore + final top-k (dense×dense cascade).
 
     The U = B·nk shortlist rows gather from ``Df`` in storage dtype — the
-    (U, m) upcast inside the rescore matmul/kernel IS the second stage's
-    dequant unit (mirroring the scan's per-strip in-register dequant).
+    (U, m) conversion inside the rescore matmul/kernel IS the second
+    stage's dequant unit (mirroring the scan's per-strip one).
     """
     qf = project_queries(Q, W, scale=None, mean=mean)
     mc = Dc.shape[1]
@@ -101,7 +101,7 @@ def _cascade_dense_projected(Dc, scale_c, Df, scale_f, W, mean, Q, k: int,
     uids = _shortlist(cids)
     q = qf if scale_f is None else qf * scale_f[None, :]
     rows = jnp.take(Df, jnp.maximum(uids, 0), axis=0)
-    s = jnp.dot(q, rows.T.astype(jnp.float32), precision=PRECISION)
+    s = score_rows(query_terms(q, rows.dtype), rows)
     s = jnp.where(uids[None, :] >= 0, s, -jnp.inf)
     return _topk_merge(s, jnp.broadcast_to(uids[None, :], s.shape), k)
 
@@ -119,7 +119,7 @@ def _segment_rescore(D, scale, qf, uids, offset, n_valid):
     local = uids - offset
     valid = (uids >= 0) & (local >= 0) & (local < n_valid)
     rows = jnp.take(D, jnp.clip(local, 0, D.shape[0] - 1), axis=0)
-    s = jnp.dot(q, rows.T.astype(jnp.float32), precision=PRECISION)
+    s = score_rows(query_terms(q, rows.dtype), rows)
     return jnp.where(valid[None, :], s, -jnp.inf)
 
 

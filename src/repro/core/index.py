@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.kernels.topk_score import PRECISION
+from repro.kernels.topk_score import PRECISION, query_terms, score_rows
 from repro.par import compat
 
 
@@ -174,8 +174,12 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
     the kernel's structure:
 
       * the index blocks keep their storage dtype (int8 stays int8 in the
-        scan carry's xs); each block upcasts to f32 only for its matmul —
-        no full-index fp32 shadow copy;
+        scan carry's xs) and each is scored by ``score_rows`` as the
+        rows' dtype selects: f32 rows by an f32 matmul at ``HIGHEST``;
+        int8 or bf16 rows, which bf16 holds exactly, convert to bf16 and
+        meet the query's three-term bf16 split (``query_terms``, made
+        once before the loop) in one matmul with f32 accumulation — no
+        full-index shadow copy, no strip in f32;
       * exact bucketed select (``_select_plan``: k·W ≤ block / 8 with
         W = 128): the strip's per-bucket maxima (B, block / W) pick the k
         buckets that must hold its top-k; one ``top_k`` over the running
@@ -204,7 +208,8 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
 
     Device scopes (``jax.named_scope``: each op's HLO ``op_name`` holds
     ``/score/`` or ``/select/``, names a profile can follow across
-    refactors): ``score`` is the strip's slice, upcast, matmul and mask;
+    refactors): ``score`` is the query's split (once, before the loop)
+    and the strip's slice, conversion, matmul and mask;
     ``select`` the improvement test and the whole guarded merge, per-strip
     ``top_k`` included. On the bucketed path ``select`` nests ``bucket``
     (the bucket maxima) and ``refine`` (the guarded merge: bucket
@@ -216,16 +221,17 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
     B = Q.shape[0]
     block = min(block, n)
     nblocks = -(-n // block)
-    Qf = Q.astype(jnp.float32)
     kk = min(k, block)   # strip-local candidate count
     W = _select_plan(k, block)
+    with jax.named_scope("score"):
+        qt = query_terms(Q.astype(jnp.float32), D.dtype)
 
     if nblocks == 1:
         # single strip (block == n): the running list is empty, a guard can
         # never fire, and the two-stage detour just adds a second sort —
         # select directly
         with jax.named_scope("score"):
-            s = jnp.dot(Qf, D.T.astype(jnp.float32), precision=PRECISION)
+            s = score_rows(qt, D)
         with jax.named_scope("select"):
             ids = jnp.broadcast_to(
                 jnp.arange(block, dtype=jnp.int32)[None, :], (B, block))
@@ -244,8 +250,7 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
         with jax.named_scope("score"):
             at = jnp.minimum(start, n - block)
             blk = jax.lax.dynamic_slice_in_dim(D, at, block, axis=0)
-            s = jnp.dot(Qf, blk.T.astype(jnp.float32),
-                        precision=PRECISION)                     # (B, block)
+            s = score_rows(qt, blk)                              # (B, block)
             ids = at + jnp.arange(block, dtype=jnp.int32)[None, :]
             s = jnp.where(ids >= start, s, -jnp.inf)
 
@@ -676,8 +681,7 @@ def _delta_topk(D, scale, Q, n_valid, offset, k: int):
     if scale is not None:
         q = q * scale[None, :]
     cap = D.shape[0]
-    s = jnp.dot(q, D.T.astype(jnp.float32),
-                precision=PRECISION)                         # (B, cap) f32
+    s = score_rows(query_terms(q, D.dtype), D)               # (B, cap) f32
     ids = jnp.arange(cap, dtype=jnp.int32)[None, :]
     live = ids < n_valid
     s = jnp.where(live, s, -jnp.inf)

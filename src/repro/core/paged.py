@@ -54,7 +54,7 @@ import numpy as np
 
 from repro.core.index import (Backend, DenseIndex, SegmentedIndex,
                               _project_nofold, _topk_merge, project_queries)
-from repro.kernels.topk_score import PRECISION
+from repro.kernels.topk_score import query_terms, score_rows
 
 
 class PageExtent(NamedTuple):
@@ -116,10 +116,7 @@ def _paged_core(pool, tail, pt, scale, nv, off, lo, hi, Qf, k: int,
             pgt = tail[jnp.clip(phys - pool_pages, 0, tail.shape[0] - 1)]
             pg = jnp.where(phys >= pool_pages, pgt, pg)
         q = Qf if scale is None else Qf * scale[t]
-        s = jax.lax.dot_general(q, pg.astype(jnp.float32),
-                                dimension_numbers=(((1,), (1,)), ((), ())),
-                                precision=PRECISION,
-                                preferred_element_type=jnp.float32)  # (B, R)
+        s = score_rows(query_terms(q, pg.dtype), pg)             # (B, R)
         iota = jnp.arange(R, dtype=jnp.int32)[None, :]
         gids = jnp.broadcast_to(off[t] + iota, s.shape)
         s = jnp.where((iota < nv[t]) & live, s, -jnp.inf)
@@ -212,8 +209,7 @@ def _paged_rescore(pool, tail, pt, scale, nv, off, lo, hi, qf, uids, acc):
         local = uids - off[t]
         valid = (uids >= 0) & (local >= 0) & (local < nv[t]) & live
         rows = jnp.take(pg, jnp.clip(local, 0, R - 1), axis=0)   # (U, m)
-        s = jnp.dot(q, rows.T.astype(jnp.float32),
-                    precision=PRECISION)                         # (B, U)
+        s = score_rows(query_terms(q, rows.dtype), rows)         # (B, U)
         return jnp.maximum(acc, jnp.where(valid[None, :], s, -jnp.inf)), None
 
     acc, _ = jax.lax.scan(body, acc, jnp.arange(table_cap, dtype=jnp.int32))

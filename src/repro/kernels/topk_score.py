@@ -11,7 +11,9 @@ adaptation:
     query before the kernel), so a pruned+quantised index really moves
     n·m·1 bytes, not a 4x-inflated fp32 shadow copy;
   * a (block_b, m) query tile stays VMEM-resident while (block_n, m) strips
-    of the index hit the MXU: ``S_blk = Q · D_blkᵀ``. The grid is
+    of the index hit the MXU: ``S_blk = Q · D_blkᵀ`` (for int8/bf16 strips
+    the tile is the query's three bf16 terms, ``score_terms``, so the
+    kernel scores as the jnp scan does). The grid is
     (batch tiles, index strips) with strips minor, so arbitrarily large B
     works — each batch tile re-streams the index once;
   * a running top-k candidate list (scores + global ids) lives in VMEM
@@ -97,6 +99,71 @@ _NEG = float("-inf")
 # is a no-op, so interpret-mode results are unchanged.
 PRECISION = jax.lax.Precision.HIGHEST
 _BIG = jnp.iinfo(jnp.int32).max
+
+
+def exact_in_bf16(dtype) -> bool:
+    """Every value of ``dtype`` is a bf16 value: bf16 itself, and integers
+    of one byte (bf16 has 8 significant bits)."""
+    dtype = jnp.dtype(dtype)
+    return dtype == jnp.bfloat16 or (jnp.issubdtype(dtype, jnp.integer)
+                                     and dtype.itemsize == 1)
+
+
+def _bf16_head(x: jax.Array) -> jax.Array:
+    """``x`` with its f32 significand cut to bf16's 8 bits, toward zero.
+    Cut on the bits: a compiler that keeps bf16 values at f32 precision
+    (XLA's excess precision) may drop an f32 -> bf16 -> f32 round trip,
+    which would leave nothing for the lower terms."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                        jnp.float32)
+
+
+def query_terms(Qf: jax.Array, rows_dtype) -> jax.Array:
+    """The (B, m) f32 queries as ``score_rows`` takes them against rows of
+    ``rows_dtype``: ``Qf`` itself unless bf16 holds every row value
+    (``exact_in_bf16``), else its split into three bf16 terms stacked as
+    (3B, m), hi then mid then lo.
+
+    hi = the top 8 significant bits of q, mid the next 8, lo the last 8:
+    hi + mid + lo == q exactly, each term is a bf16 value, and all three
+    share q's sign. A query scaled by an int8 fold splits after the fold.
+    """
+    if not exact_in_bf16(rows_dtype):
+        return Qf
+    hi = _bf16_head(Qf)
+    mid = _bf16_head(Qf - hi)
+    # stacked in f32, whose 8-row tiles any B that is a multiple of 8
+    # keeps aligned, then cast: every value is a bf16 value already
+    return jnp.concatenate([hi, mid, Qf - hi - mid]).astype(jnp.bfloat16)
+
+
+def score_terms(q_terms: jax.Array, rows: jax.Array) -> jax.Array:
+    """(B, n) f32 scores of ``rows`` (n, m), which bf16 holds exactly,
+    against the (3B, m) bf16 terms of ``query_terms``: the rows convert
+    straight to bf16, never to f32, and meet the three terms in one
+    (3, B, m) x (m, n) matmul with f32 accumulation, summed hi + (mid +
+    lo). A product of two bf16 values is exact in f32, so the score is
+    the f32 inner product of the same f32 query with the same rows; only
+    the order of rounding in the sum differs from an f32 matmul at
+    ``PRECISION``.
+    """
+    B, m = q_terms.shape[0] // 3, q_terms.shape[1]
+    s = jax.lax.dot_general(q_terms.reshape(3, B, m),
+                            rows.astype(jnp.bfloat16),
+                            (((2,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)  # (3, B, n)
+    return s[0] + (s[1] + s[2])
+
+
+def score_rows(q_terms: jax.Array, rows: jax.Array) -> jax.Array:
+    """(B, n) f32 scores of ``rows`` (n, m), in their storage dtype,
+    against queries prepared by ``query_terms``: ``score_terms`` for rows
+    bf16 holds exactly, else one f32 matmul at ``PRECISION``."""
+    if q_terms.dtype == jnp.float32:
+        return jnp.dot(q_terms, rows.T.astype(jnp.float32),
+                       precision=PRECISION)
+    return score_terms(q_terms, rows)
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -252,11 +319,15 @@ def _make_kernel(k: int, n_valid: int, block_n: int, nblocks: int,
             run_i_ref[...] = neg
 
         q = q_ref[...]
-        blk = d_ref[...].astype(jnp.float32)      # dequant/upcast in-register
-        s = jax.lax.dot_general(
-            q, blk, dimension_numbers=(((1,), (1,)), ((), ())),
-            precision=PRECISION,
-            preferred_element_type=jnp.float32)               # (bb, block_n)
+        if q.dtype == jnp.bfloat16:
+            # the tile's (3bb, m) query terms; rows exact in bf16
+            s = score_terms(q, d_ref[...])                    # (bb, block_n)
+        else:
+            blk = d_ref[...].astype(jnp.float32)  # dequant/upcast in-register
+            s = jax.lax.dot_general(
+                q, blk, dimension_numbers=(((1,), (1,)), ((), ())),
+                precision=PRECISION,
+                preferred_element_type=jnp.float32)           # (bb, block_n)
         if with_ids:
             # rescore mode: report the gathered rows' true doc ids; negative
             # ids mark dedup/pad slots and never surface
@@ -317,11 +388,16 @@ def topk_score_pallas(D: jax.Array, Q: jax.Array, *, k: int,
     Qf = Q.astype(jnp.float32)
     if g.b_pad != B:
         Qf = jnp.pad(Qf, ((0, g.b_pad - B), (0, 0)))
+    Qf = query_terms(Qf, D.dtype)
+    # a batch tile's query rows: its own (3 block_b, m) terms where split
+    rows_b = g.block_b * (Qf.shape[0] // g.b_pad)
+    if rows_b != g.block_b:
+        Qf = Qf.reshape(3, g.nbt, g.block_b, m).swapaxes(0, 1).reshape(-1, m)
 
     kernel = _make_kernel(k, nv, g.block_n, g.nblocks, g.fold_w, g.fold_r,
                           with_ids=row_ids is not None, guard=guard)
     in_specs = [
-        pl.BlockSpec((g.block_b, m), lambda b, i: (b, 0)),  # Q resident
+        pl.BlockSpec((rows_b, m), lambda b, i: (b, 0)),     # Q resident
         pl.BlockSpec((g.block_n, m), lambda b, i: (i, 0)),  # D streams
     ]
     operands = [Qf, D]
@@ -509,16 +585,20 @@ def _make_paged_kernel(k: int, table_cap: int, page_rows: int,
                 wait(i)
 
                 slot = i % depth
-                page = pbuf[slot].astype(jnp.float32)   # in-register dequant
                 q = q_ref[...]
                 if with_scale:
                     # per-page dequant scale folds into the QUERY — the same
                     # fold order as the segmented path, so bitwise-equal
                     q = q * sbuf[slot]                  # (bb, m) * (1, m)
-                s = jax.lax.dot_general(
-                    q, page, dimension_numbers=(((1,), (1,)), ((), ())),
-                    precision=PRECISION,
-                    preferred_element_type=jnp.float32)      # (bb, R)
+                q = query_terms(q, pbuf.dtype)
+                if q.dtype == jnp.bfloat16:
+                    s = score_terms(q, pbuf[slot])           # (bb, R)
+                else:
+                    page = pbuf[slot].astype(jnp.float32)  # in-register
+                    s = jax.lax.dot_general(
+                        q, page, dimension_numbers=(((1,), (1,)), ((), ())),
+                        precision=PRECISION,
+                        preferred_element_type=jnp.float32)  # (bb, R)
                 iota = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
                 if with_ids:
                     gids = jnp.broadcast_to(ibuf[slot], s.shape)

@@ -9,6 +9,7 @@ topology is described inside a fixture, never at import: only one process
 may load the TPU library, and every test worker imports this module.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -88,20 +89,57 @@ def test_paged_kernel_compiles_on_v5e(one_chip, mode):
     assert "tpu_custom_call" in c.as_text()
 
 
+def _score_dots(hlo: str) -> list[tuple[str, list[str]]]:
+    """(line, operand types) of each compiled dot under scope ``score``."""
+    types = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.-]+) = (\w+\[[\d,]*\])",
+                            hlo, re.M))
+    out = []
+    for line in hlo.splitlines():
+        mt = re.search(r"= \S+ convolution\(([^)]*)\)", line)
+        if mt and "/score/dot_general" in line:
+            out.append((line, [types[a.strip()]
+                               for a in mt.group(1).split(",")]))
+    return out
+
+
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8])
 def test_served_dispatch_fits_one_chip_at_msmarco_scale(one_chip, compiled_kernels,
                                                         dtype, backend):
     """The fused dispatch at 8.8M x 384 fits 16 GB: f32 is 13.6 GB of index,
-    so the scan may not copy it (no per-dispatch pad) or upcast it."""
+    so the scan may not copy it (no per-dispatch pad) or upcast it.
+
+    The jnp scan scores each int8 strip against the query's three bf16
+    terms, (3, 32, 384): the dot reads bf16 terms and the strip in its
+    int8 storage, never at ``HIGHEST``, and the split is cut on the bits
+    (two ``and``s under ``score``), which the compiler cannot fold away
+    as it may a bf16 round trip. The f32 ``HIGHEST`` dot on rows upcast
+    to f32, which this replaced, compiled to 485,888 temp bytes (JAX
+    0.9); a (96, 65,536) f32 product or a tripled strip in HBM would add
+    at least 25,165,824 B to that. f32 rows keep the f32 dot at
+    ``HIGHEST``."""
     scale = _spec(one_chip, (M,), jnp.float32) if dtype == jnp.int8 else None
     c = jax.jit(lambda d, s, w, q: _dense_search_projected(
         d, s, w, None, q, K, None, backend)).lower(
         _spec(one_chip, (MSMARCO_ROWS, M), dtype), scale,
         _spec(one_chip, (D_IN, M), jnp.float32),
         _spec(one_chip, (B, D_IN), jnp.float32)).compile()
-    assert c.memory_analysis().temp_size_in_bytes < HBM_TEMP_LIMIT
-    assert ("tpu_custom_call" in c.as_text()) == (backend == "pallas")
+    temp = c.memory_analysis().temp_size_in_bytes
+    assert temp < HBM_TEMP_LIMIT
+    hlo = c.as_text()
+    assert ("tpu_custom_call" in hlo) == (backend == "pallas")
+    if backend == "pallas":
+        return
+    [(line, operands)] = _score_dots(hlo)
+    if dtype == jnp.int8:
+        assert temp < 485_888 + 96 * 65536 * 4
+        assert operands == [f"bf16[3,{B},{M}]", f"s8[65536,{M},1]"]
+        assert "highest" not in line
+        cuts = re.findall(rf"= u32\[{B},{M}\]\S* and\(.*/score/and", hlo)
+        assert len(cuts) == 2
+    else:
+        assert operands == [f"f32[{B},{M}]", f"f32[65536,{M}]"]
+        assert "operand_precision={highest,highest}" in line
 
 
 def test_index_load_fills_in_place_at_msmarco_scale(one_chip):

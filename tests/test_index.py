@@ -1,4 +1,6 @@
 """DenseIndex / ShardedDenseIndex / int8 quantisation."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -193,3 +195,155 @@ def test_bucketed_select_scopes_in_hlo():
     direct = names(256)
     assert any("/select/" in n for n in direct)
     assert not any("/bucket/" in n or "/refine/" in n for n in direct)
+
+
+# ---------------------------------------------------------------------------
+# scoring rows bf16 holds exactly: the three-term bf16 split of the query
+# ---------------------------------------------------------------------------
+
+U = 2.0 ** -24   # unit roundoff of f32
+
+
+@pytest.mark.parametrize("magnitude", [1e-6, 1e-3, 1.0, 1e3, 1e6])
+def test_query_terms_reproduce_query(magnitude):
+    """hi + mid + lo == q in float64 across the range a scale-folded int8
+    query spans (a 768-d query projected and scaled by |x|max / 127 per
+    dimension); bf16 and int8 rows split alike, f32 rows get q itself."""
+    from repro.kernels.topk_score import query_terms
+    q = (RNG.standard_normal((32, 384)) * magnitude).astype(np.float32)
+    for dtype in (jnp.int8, jnp.bfloat16):
+        t = query_terms(jnp.asarray(q), dtype)
+        assert t.dtype == jnp.bfloat16 and t.shape == (96, 384)
+        t = np.asarray(t.astype(jnp.float32), np.float64)
+        np.testing.assert_array_equal(t[:32] + t[32:64] + t[64:], q)
+    assert query_terms(jnp.asarray(q), jnp.float32).dtype == jnp.float32
+
+
+def test_query_terms_reproduce_special_values():
+    """Zeros of both signs, values near the ends of the range the terms
+    hold, and every pattern of the 16 mantissa bits below hi's in one
+    binade."""
+    from repro.kernels.topk_score import query_terms
+    edge = np.array([0.0, -0.0, 1e-30, -1e-30, 1.17549435e-30, 1e30, -1e30,
+                     3e38, -3e38, 1.0 + 2.0 ** -23, -(2.0 - 2.0 ** -23)],
+                    np.float32)
+    mant = (1.0 + np.arange(2 ** 16, dtype=np.float64) * 2.0 ** -23)
+    q = np.concatenate([edge, mant.astype(np.float32),
+                        -mant.astype(np.float32)])
+    q = np.pad(q, (0, (-q.size) % 8)).reshape(8, -1)
+    t = np.asarray(query_terms(jnp.asarray(q), jnp.int8).astype(jnp.float32),
+                   np.float64)
+    np.testing.assert_array_equal(t[:8] + t[8:16] + t[16:], q)
+    # every term shares q's sign: |hi| + |mid| + |lo| == |q|
+    assert (np.sign(t.reshape(3, 8, -1)) * np.sign(q) >= 0).all()
+
+
+def _exact_rows(dtype, n, m, rng):
+    """Rows every value of which bf16 holds: int8, or bf16 rows rounded
+    from f32 draws."""
+    if dtype == "int8":
+        return jnp.asarray(rng.integers(-127, 128, (n, m)), jnp.int8)
+    return jnp.asarray(rng.standard_normal((n, m)), jnp.bfloat16)
+
+
+def _f32_bound(Q, D):
+    """Forward error bound of an f32 inner product of length m: each
+    partial sum rounds once, so |fl(q.r) - q.r| <= m u sum_i |q_i r_i|.
+    The split sums three such products, of hi, mid and lo, which share
+    q's sign so that their absolute values add to |q|, then rounds twice
+    more: (m + 2) u (1 + 2^-7) sum_i |q_i r_i|, the last factor for terms
+    of order u^2, covers both it and the f32 matmul of the rows upcast."""
+    m = Q.shape[1]
+    absdot = np.abs(Q) @ np.abs(D).T
+    return (m + 2) * U * (1 + 2.0 ** -7) * absdot
+
+
+@pytest.mark.parametrize("dtype", ["int8", "bf16"])
+def test_split_scan_within_f32_bound_of_float64(dtype):
+    """The scan over rows bf16 holds exactly: every returned score lies
+    within the f32 accumulation bound of its row's float64 score, the
+    same bound the f32 ``HIGHEST`` path of the same rows meets, and the
+    ids are the float64 top-k (distinct scores, gaps above the bound)."""
+    from repro.core.index import _scan_topk
+    rng = np.random.default_rng(11)
+    D = _exact_rows(dtype, 3 * 4096 + 333, 384, rng)
+    Q = (rng.standard_normal((32, 384)) * 0.02).astype(np.float32)
+    Dn = np.asarray(D.astype(jnp.float32), np.float64)
+    exact = Q.astype(np.float64) @ Dn.T
+    bound = _f32_bound(Q.astype(np.float64), Dn)
+    want = np.argsort(-exact, axis=1, kind="stable")[:, :10]
+    for rows in (D, D.astype(jnp.float32)):          # split, then HIGHEST
+        s, ids = _scan_topk(rows, jnp.asarray(Q), 10, block=4096)
+        s, ids = np.asarray(s, np.float64), np.asarray(ids)
+        np.testing.assert_array_equal(ids, want)
+        err = np.abs(s - np.take_along_axis(exact, ids, 1))
+        assert (err <= np.take_along_axis(bound, ids, 1)).all()
+
+
+@pytest.mark.parametrize("dtype", ["int8", "bf16"])
+@pytest.mark.parametrize("block", [4096, 65536])
+def test_split_scan_ids_match_f32_path(dtype, block):
+    """On untied data the split path returns the ids of the parent's path,
+    ``_scan_topk`` over the rows upcast to f32 beforehand (several strips
+    and a single strip), with scores within the f32 bound of each other."""
+    from repro.core.index import _scan_topk
+    rng = np.random.default_rng(12)
+    D = _exact_rows(dtype, 3 * 4096 + 777, 96, rng)
+    Q = jnp.asarray(rng.standard_normal((9, 96)).astype(np.float32))
+    s1, i1 = _scan_topk(D, Q, 10, block=block)
+    s0, i0 = _scan_topk(D.astype(jnp.float32), Q, 10, block=block)
+    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i0))
+    bound = 2 * _f32_bound(np.asarray(Q, np.float64),
+                           np.asarray(D.astype(jnp.float32), np.float64))
+    gap = np.abs(np.asarray(s1, np.float64) - np.asarray(s0, np.float64))
+    assert (gap <= np.take_along_axis(bound, np.asarray(i0), 1)).all()
+
+
+def _hlo_instructions(hlo: str) -> dict:
+    """name -> (dtype, shape, opcode, operand names, line) of every
+    instruction of an HLO module's text."""
+    out = {}
+    pat = re.compile(r"^\s*(?:ROOT )?%?([\w.-]+) = (\w+)\[([\d,]*)\]\S* "
+                     r"([\w-]+)\(([^)]*)\)")
+    for line in hlo.splitlines():
+        mt = pat.match(line)
+        if mt:
+            name, dtype, dims, op, args = mt.groups()
+            shape = tuple(int(d) for d in dims.split(",") if d)
+            ops = [a.strip().lstrip("%") for a in args.split(",") if a]
+            out[name] = (dtype, shape, op, ops, line)
+    return out
+
+
+@pytest.mark.parametrize("dtype", ["int8", "bf16", "f32"])
+def test_score_dot_form_follows_row_dtype_in_hlo(dtype):
+    """Engagement, read from the lowered HLO: for rows bf16 holds
+    exactly, the one dot under ``score`` multiplies bf16 operands into f32
+    and no strip is converted to f32; for f32 rows it is the f32 dot at
+    ``HIGHEST``."""
+    from repro.core.index import _scan_topk
+    rng = np.random.default_rng(13)
+    n, block = 3 * 4096 + 5, 4096
+    if dtype == "f32":
+        D = jnp.asarray(rng.standard_normal((n, 32)), jnp.float32)
+    else:
+        D = _exact_rows(dtype, n, 32, rng)
+    Q = jnp.asarray(rng.standard_normal((8, 32)).astype(np.float32))
+    hlo = _scan_topk.lower(D, Q, 8, block=block).as_text(dialect="hlo",
+                                                          debug_info=True)
+    ins = _hlo_instructions(hlo)
+    dots = [v for v in ins.values()
+            if v[2] == "dot" and 'op_name="score/' in v[4]]
+    assert len(dots) == 1
+    out_dtype, out_shape, _, operands, line = dots[0]
+    assert out_dtype == "f32"
+    kinds = [ins[o][0] for o in operands]
+    if dtype == "f32":
+        assert kinds == ["f32", "f32"] and out_shape == (8, block)
+        assert "operand_precision={highest,highest}" in line
+        return
+    assert kinds == ["bf16", "bf16"] and out_shape == (3, 8, block)
+    assert "highest" not in line
+    strips = [v for v in ins.values() if v[2] == "convert" and v[0] == "f32"
+              and ins[v[3][0]][1][:1] in ((block,), (n,))]
+    assert strips == []

@@ -45,7 +45,8 @@ from repro.core.store import (
     save_index,
     save_paged_index,
 )
-from repro.kernels.topk_score import topk_score_paged_pallas, topk_score_pallas
+from repro.kernels.topk_score import (query_terms, score_rows,
+                                     topk_score_paged_pallas, topk_score_pallas)
 
 RNG = np.random.default_rng(170)
 
@@ -147,14 +148,13 @@ def test_paged_kernel_int8_per_page_scale():
         buf[idx, :nv[j]] = q8
     ps = np.zeros((table_cap, 1, m), np.float32)   # kernel's DMA layout
     ps[:npages, 0] = scale
-    # reference: per-page scale folded into the query, jnp dot per page
+    # reference: per-page scale folded into the query, then the jnp scan's
+    # scoring of int8 rows (three-term bf16 split of the query) per page
     parts_s, parts_i = [], []
     for j in range(npages):
         qf = jnp.asarray(Q) * jnp.asarray(scale[j])[None, :]
-        sj = jax.lax.dot_general(
-            qf, jnp.asarray(D8[j * R: j * R + nv[j]]).astype(jnp.float32),
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        rows8 = jnp.asarray(D8[j * R: j * R + nv[j]])
+        sj = score_rows(query_terms(qf, rows8.dtype), rows8)
         parts_s.append(sj)
         parts_i.append(jnp.asarray(np.arange(
             j * R, j * R + nv[j], dtype=np.int32)[None, :].repeat(B, 0)))
