@@ -7,7 +7,7 @@
     over PR (written by ``benchmarks.run``)
   * select-path A/B: the two-stage + block-skip ``_scan_topk`` against the
     legacy concat-and-full-top_k select on the same corpus
-  * paged: paged-vs-segmented serve qps under live appends, the Pallas
+  * paged: paged serve qps with and without live appends, the Pallas
     DMA pipeline depth sweep (resident vs oversubscribed, host-tier
     streaming), the zero-recompile page-count lifecycle, and the per-row
     block-skip guard A/B
@@ -36,6 +36,22 @@ from repro.core import DenseIndex, ShardedDenseIndex, StaticPruner
 from repro.core.index import _scan_topk, _topk_merge
 from repro.core.store import IndexStore, save_index
 
+
+def _paged_jit_cache_size() -> int:
+    """Compiled variants of every jit a paged search or lifecycle touches."""
+    from repro.core import paged
+    return sum(paged._jit_cache_sizes().values())
+
+
+def _search_jit_cache_size() -> int:
+    """Compiled variants of every search jit a served drive can touch."""
+    from repro.core import cascade
+    from repro.core.index import _dense_search_projected, _scan_topk
+    return (_paged_jit_cache_size()
+            + sum(cascade._jit_cache_sizes().values())
+            + _dense_search_projected._cache_size()
+            + _scan_topk._cache_size())
+
 N_DOCS = 100_000
 DIM = 768
 N_QUERIES = 16
@@ -48,14 +64,12 @@ PALLAS_MAX_DOCS = 20_000
 N_SERVE = 192
 SERVE_DEPTH = 3
 SERVE_BATCH = 32
-# live_index section: queries per drive, append block, delta capacity (one
-# open delta must absorb the whole drive's appends — steady state means a
-# CONSTANT segment count, which is what makes zero recompiles assertable)
+# live_index section: queries per drive, append block, seal rows (one
+# open delta extent absorbs the whole drive's appends)
 N_LIVE = 160
 LIVE_APPEND_BLOCK = 128
 LIVE_DELTA_CAP = 16384
-LIVE_APPEND_RATES = {"append_0": 0.0, "append_low": 256.0,
-                     "append_high": 2048.0}   # rows/s
+LIVE_APPEND_RATES = {"append_low": 256.0, "append_high": 2048.0}  # rows/s
 # paged section: page geometry, DMA pipeline depths, and the append blocks
 # (in pruned m-dim rows) that walk the page count up during the
 # zero-recompile sweep
@@ -392,24 +406,21 @@ def _live_index(Dh, pruner, Q_raw, emit) -> dict:
     Per dtype {f32, int8}, the same Poisson tape drives four servers at
     ~0.8x the fused batched capacity:
 
-      * ``static``      — monolithic ``DenseIndex`` (the pre-segment
-                          architecture: appends would require a rebuild);
-      * ``append_0``    — ``SegmentedIndex`` server, no appends (the cost
-                          of the segmented read path itself);
-      * ``append_low`` / ``append_high`` — a background ``IndexUpdater``
-                          appends raw documents at that rate while the
-                          drive runs; every append swaps a fresh segment
-                          set into the server atomically.
+      * ``static``      — monolithic ``DenseIndex`` (appends would
+                          require a rebuild);
+      * ``append_low`` / ``append_high`` — a ``PagedIndex`` server while a
+                          background ``IndexUpdater`` appends raw
+                          documents at that rate; every append swaps a
+                          fresh index into the server atomically.
 
-    Each segmented row also records the number of search-path jit
-    compilations during the timed drive (``recompiles_steady``) — the
-    acceptance bar is ZERO: deltas dispatch at fixed padded capacity with
-    traced live counts, so corpus growth never stalls serving on a
-    compile. ``benchmarks/run.py`` schema-checks all of this before
-    BENCH_perf.json is written.
+    Each append row also records the number of paged jit compilations
+    during the timed drive (``recompiles_steady``) — the acceptance bar is
+    ZERO: page-table slot bounds are traced operands, so corpus growth
+    never stalls serving on a compile. ``benchmarks/run.py`` schema-checks
+    all of this before BENCH_perf.json is written.
     """
-    from repro.core.index import SegmentedIndex, segment_jit_cache_size
     from repro.core.maintenance import IndexUpdater
+    from repro.core.paged import PagedIndex
     from repro.launch.serve import RetrievalServer, _drive_open
     d_raw = int(pruner.state.d)
     Q = np.asarray(Q_raw)
@@ -432,24 +443,22 @@ def _live_index(Dh, pruner, Q_raw, emit) -> dict:
         srv.close()
 
         for name, arate in LIVE_APPEND_RATES.items():
-            seg = SegmentedIndex.from_index(idx,
-                                            delta_capacity=LIVE_DELTA_CAP)
-            srv = RetrievalServer(seg, pruner, k=K, max_batch=SERVE_BATCH,
+            live = PagedIndex.from_index(idx, page_rows=PAGED_PAGE_ROWS,
+                                         seal_rows=LIVE_DELTA_CAP)
+            srv = RetrievalServer(live, pruner, k=K, max_batch=SERVE_BATCH,
                                   pipeline_depth=SERVE_DEPTH)
-            up = IndexUpdater(pruner=pruner, index=seg, server=srv,
+            up = IndexUpdater(pruner=pruner, index=live, server=srv,
                               delta_capacity=LIVE_DELTA_CAP)
-            # warm appends (open + a provably NON-widening extend at the
-            # live block size: 0.5x rows already present) + query: compile
-            # the delta scan, the 2-segment merge, the append-side
-            # projection and the extend's update-slice BEFORE the timed
-            # drive — everything after this is steady state (widening
-            # extends do a plain host requant + upload, no jit)
+            # warm appends (open + a NON-widening extend at the live block
+            # size: 0.5x rows already present) + query: compile the page
+            # walk and the append-side projection BEFORE the timed drive —
+            # everything after this is steady state
             warm = rng.standard_normal(
                 (LIVE_APPEND_BLOCK, d_raw)).astype(np.float32)
             up.add_documents(jnp.asarray(warm))
             up.add_documents(jnp.asarray(0.5 * warm))
             srv.query(Qs[0])
-            jit0 = segment_jit_cache_size()
+            jit0 = _paged_jit_cache_size()
             n0 = up.index.n
             stop = threading.Event()
 
@@ -472,7 +481,7 @@ def _live_index(Dh, pruner, Q_raw, emit) -> dict:
             if th is not None:
                 stop.set()
                 th.join(timeout=30.0)
-            recompiles = segment_jit_cache_size() - jit0
+            recompiles = _paged_jit_cache_size() - jit0
             rows[name] = dict(_serve_mode_row(res, srv.worker_stats()),
                               appended_rows=int(up.index.n - n0),
                               swaps=int(srv.swap_count),
@@ -483,7 +492,6 @@ def _live_index(Dh, pruner, Q_raw, emit) -> dict:
             **rows)
         emit(f"live_index_dense_{dtype},{rows['append_high']['p50_ms']*1e3:.0f},"
              f"static={rows['static']['worker_qps']:.1f}qps "
-             f"seg={rows['append_0']['worker_qps']:.1f}qps "
              f"low={rows['append_low']['worker_qps']:.1f}qps"
              f"(+{rows['append_low']['appended_rows']}) "
              f"high={rows['append_high']['worker_qps']:.1f}qps"
@@ -503,12 +511,11 @@ def _live_index(Dh, pruner, Q_raw, emit) -> dict:
 def _paged(Dh, pruner, Q_raw, emit) -> dict:
     """Paged index memory: the four tracked claims, one subsection each.
 
-      * ``configs`` — paged vs segmented serve qps at append rates
-        {0, high} on the live-append harness: per dtype, the same Poisson
-        tape at the same offered rate (0.8x the dense fused capacity)
-        drives four servers — ``{segmented, paged} x {append_0,
-        append_high}`` — with a background ``IndexUpdater`` supplying the
-        appends.  Every row reports the steady-state jit-compile count;
+      * ``configs`` — paged serve qps at append rates {0, high} on the
+        live-append harness: per dtype, the same Poisson tape at the same
+        offered rate (0.8x the dense fused capacity) drives two servers —
+        ``paged_append_0`` and ``paged_append_high`` — with a background
+        ``IndexUpdater`` supplying the appends.  Every row reports the steady-state jit-compile count;
         the schema gate pins it to ZERO (paged appends are page-pointer
         swaps at fixed dispatch shapes — growth must never stall serving
         on a compile).
@@ -529,7 +536,6 @@ def _paged(Dh, pruner, Q_raw, emit) -> dict:
         legacy whole-batch guard on the same blocked scan, asserted
         bit-identical (the guard is an optimisation, never a result).
     """
-    from repro.core.index import SegmentedIndex, segment_jit_cache_size
     from repro.core.maintenance import IndexUpdater
     from repro.core.paged import PagedIndex
     from repro.launch.serve import RetrievalServer, _drive_open
@@ -539,7 +545,7 @@ def _paged(Dh, pruner, Q_raw, emit) -> dict:
     W, mean = pruner.projection()
     rng = np.random.default_rng(17)
 
-    # -- paged vs segmented under live appends ------------------------------
+    # -- paged serve with and without live appends --------------------------
     configs = {}
     for dtype in ("f32", "int8"):
         quant = dtype == "int8"
@@ -548,67 +554,58 @@ def _paged(Dh, pruner, Q_raw, emit) -> dict:
                     jnp.asarray(Qs[:SERVE_BATCH])) / 1e6
         rate = 0.8 * SERVE_BATCH / tb
         rows = {}
-        for layout in ("segmented", "paged"):
-            for name, arate in (("append_0", 0.0),
-                                ("append_high",
-                                 LIVE_APPEND_RATES["append_high"])):
-                if layout == "segmented":
-                    live = SegmentedIndex.from_index(
-                        idx, delta_capacity=LIVE_DELTA_CAP)
-                else:
-                    live = PagedIndex.from_index(
-                        idx, page_rows=PAGED_PAGE_ROWS,
-                        seal_rows=LIVE_DELTA_CAP)
-                srv = RetrievalServer(live, pruner, k=K,
-                                      max_batch=SERVE_BATCH,
-                                      pipeline_depth=SERVE_DEPTH)
-                up = IndexUpdater(pruner=pruner, index=live, server=srv,
-                                  delta_capacity=LIVE_DELTA_CAP)
-                # same warmup contract as live_index: open + non-widening
-                # extend + query compile every steady-state path up front
-                warm = rng.standard_normal(
-                    (LIVE_APPEND_BLOCK, d_raw)).astype(np.float32)
-                up.add_documents(jnp.asarray(warm))
-                up.add_documents(jnp.asarray(0.5 * warm))
-                srv.query(Qs[0])
-                jit0 = segment_jit_cache_size()
-                n0 = up.index.n
-                stop = threading.Event()
+        for name, arate in (("append_0", 0.0),
+                            ("append_high",
+                             LIVE_APPEND_RATES["append_high"])):
+            live = PagedIndex.from_index(idx, page_rows=PAGED_PAGE_ROWS,
+                                         seal_rows=LIVE_DELTA_CAP)
+            srv = RetrievalServer(live, pruner, k=K, max_batch=SERVE_BATCH,
+                                  pipeline_depth=SERVE_DEPTH)
+            up = IndexUpdater(pruner=pruner, index=live, server=srv,
+                              delta_capacity=LIVE_DELTA_CAP)
+            # same warmup contract as live_index: open + non-widening
+            # extend + query compile every steady-state path up front
+            warm = rng.standard_normal(
+                (LIVE_APPEND_BLOCK, d_raw)).astype(np.float32)
+            up.add_documents(jnp.asarray(warm))
+            up.add_documents(jnp.asarray(0.5 * warm))
+            srv.query(Qs[0])
+            jit0 = _paged_jit_cache_size()
+            n0 = up.index.n
+            stop = threading.Event()
 
-                def appender(arate=arate):
-                    while not stop.is_set():
-                        t0 = time.perf_counter()
-                        up.add_documents(jnp.asarray(
-                            rng.standard_normal((LIVE_APPEND_BLOCK, d_raw))
-                            .astype(np.float32)))
-                        lag = (LIVE_APPEND_BLOCK / arate
-                               - (time.perf_counter() - t0))
-                        if lag > 0:
-                            stop.wait(lag)
+            def appender(arate=arate):
+                while not stop.is_set():
+                    t0 = time.perf_counter()
+                    up.add_documents(jnp.asarray(
+                        rng.standard_normal((LIVE_APPEND_BLOCK, d_raw))
+                        .astype(np.float32)))
+                    lag = (LIVE_APPEND_BLOCK / arate
+                           - (time.perf_counter() - t0))
+                    if lag > 0:
+                        stop.wait(lag)
 
-                th = None
-                if arate > 0:
-                    th = threading.Thread(target=appender, daemon=True)
-                    th.start()
-                res = _drive_open(srv, Qs, rate=rate)
-                if th is not None:
-                    stop.set()
-                    th.join(timeout=30.0)
-                recompiles = segment_jit_cache_size() - jit0
-                rows[f"{layout}_{name}"] = dict(
-                    _serve_mode_row(res, srv.worker_stats()),
-                    appended_rows=int(up.index.n - n0),
-                    swaps=int(srv.swap_count),
-                    recompiles_steady=int(recompiles))
-                srv.close()
+            th = None
+            if arate > 0:
+                th = threading.Thread(target=appender, daemon=True)
+                th.start()
+            res = _drive_open(srv, Qs, rate=rate)
+            if th is not None:
+                stop.set()
+                th.join(timeout=30.0)
+            recompiles = _paged_jit_cache_size() - jit0
+            rows[f"paged_{name}"] = dict(
+                _serve_mode_row(res, srv.worker_stats()),
+                appended_rows=int(up.index.n - n0),
+                swaps=int(srv.swap_count),
+                recompiles_steady=int(recompiles))
+            srv.close()
         configs[f"dense_{dtype}"] = dict(
             n=int(Dh.shape[0]), dim=int(Dh.shape[1]), rate_qps=float(rate),
             **rows)
         emit(f"paged_live_dense_{dtype},"
              f"{rows['paged_append_high']['p50_ms']*1e3:.0f},"
-             f"seg0={rows['segmented_append_0']['worker_qps']:.1f}qps "
              f"pg0={rows['paged_append_0']['worker_qps']:.1f}qps "
-             f"segH={rows['segmented_append_high']['worker_qps']:.1f}qps "
              f"pgH={rows['paged_append_high']['worker_qps']:.1f}qps"
              f"(+{rows['paged_append_high']['appended_rows']}r/"
              f"{rows['paged_append_high']['swaps']}sw) "
@@ -688,11 +685,11 @@ def _paged(Dh, pruner, Q_raw, emit) -> dict:
     while warmups < 10:
         pg = lifecycle(pg, 192)
         warmups += 1
-        cur = segment_jit_cache_size()
+        cur = _paged_jit_cache_size()
         if cur == prev and pg.storage.n_host_pages > 0:
             break
         prev = cur
-    jit0 = segment_jit_cache_size()
+    jit0 = _paged_jit_cache_size()
     page_counts = [int(pg.total_pages)]
     host_counts = [int(pg.storage.n_host_pages)]
     for rows in PAGED_SWEEP_ROWS:
@@ -704,7 +701,7 @@ def _paged(Dh, pruner, Q_raw, emit) -> dict:
                  pool_pages=18, warmup_lifecycles=int(warmups),
                  append_rows=[int(r) for r in PAGED_SWEEP_ROWS],
                  page_counts=page_counts, host_pages=host_counts,
-                 recompiles_steady=int(segment_jit_cache_size() - jit0))
+                 recompiles_steady=int(_paged_jit_cache_size() - jit0))
     emit(f"paged_page_count_sweep,0,pages={page_counts} "
          f"host={host_counts} recompiles={sweep['recompiles_steady']}")
 
@@ -779,7 +776,6 @@ def _cascade(Dh, pruner, Q_raw, emit) -> dict:
     full-m f32 oracle plus the steady-state jit-compile count (the
     cascade's zero-recompile contract under fixed nk)."""
     from repro.core import CascadeIndex
-    from repro.core.index import segment_jit_cache_size
     from repro.launch.serve import RetrievalServer, _drive_open
     Q = np.asarray(Q_raw)
     Qs = np.tile(Q, (N_SERVE // len(Q) + 1, 1))[:N_SERVE]
@@ -796,12 +792,12 @@ def _cascade(Dh, pruner, Q_raw, emit) -> dict:
         srv = RetrievalServer(idx, pruner, k=K, max_batch=SERVE_BATCH,
                               pipeline_depth=SERVE_DEPTH)
         srv.query(Qs[0])            # compile the padded batch shape
-        jit0 = segment_jit_cache_size()
+        jit0 = _search_jit_cache_size()
         srv.reset_stats()
         res = _drive_open(srv, Qs, rate=rate, collect=True)
         outs = res.pop("results")
         stats = srv.worker_stats()
-        recompiles = segment_jit_cache_size() - jit0
+        recompiles = _search_jit_cache_size() - jit0
         srv.close()
         ids = np.stack([np.asarray(i) for _, i in outs])
         return dict(_serve_mode_row(res, stats), rate_qps=float(rate),
@@ -1009,14 +1005,14 @@ def run(emit=print) -> dict:
     results["serve_pipeline"] = _serve_pipeline(Dh, pruner, np.asarray(Q),
                                                 emit)
 
-    # live segmented index: serve QPS while a background updater appends
+    # live paged index: serve QPS while a background updater appends
     # (zero steady-state recompiles asserted by the schema check), plus the
     # batch-shape bucketing A/B at low load
     results["live_index"] = _live_index(Dh, pruner, np.asarray(Q), emit)
     results["serve_bucketing"] = _serve_bucketing(Dh, pruner, np.asarray(Q),
                                                   emit)
 
-    # paged index memory: paged-vs-segmented live serve, DMA depth sweep
+    # paged index memory: paged live serve, DMA depth sweep
     # with the oversubscription headline row, zero-recompile page-count
     # lifecycle, and the per-row vs whole-batch guard A/B
     results["paged"] = _paged(Dh, pruner, np.asarray(Q), emit)

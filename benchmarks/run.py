@@ -22,10 +22,10 @@ BENCH_PERF_PATH = REPO_ROOT / "BENCH_perf.json"
 # a refactor that silently drops a bench section must fail CI, not ship a
 # BENCH_perf.json that quietly stopped tracking the serving trajectory
 _SERVE_MODE_KEYS = ("qps", "p50_ms", "p95_ms", "p99_ms", "worker_qps")
-# live_index rows per config: the static baseline + the segmented server at
-# each append rate; segmented rows must additionally report append/swap
+# live_index rows per config: the static baseline + the paged server at
+# each append rate; append rows must additionally report append/swap
 # telemetry and a ZERO steady-state recompile count
-_LIVE_ROWS = ("static", "append_0", "append_low", "append_high")
+_LIVE_ROWS = ("static", "append_low", "append_high")
 _LIVE_APPEND_KEYS = ("appended_rows", "swaps", "recompiles_steady")
 # cascade Pareto rows: every operating point (baselines included) must
 # report its oracle recall and saturated worker qps, at least one
@@ -39,14 +39,13 @@ _CASCADE_ROW_KEYS = ("recall_at_10", "worker_qps", "recompiles_steady")
 _FLEET_ROWS = ("healthy", "kill_restart", "bad_rollout")
 _FLEET_ROW_KEYS = ("n", "n_ok", "p50_ms", "p99_ms", "lost_accepted",
                    "misrouted", "health_ok")
-# paged rows per config: both layouts at both append rates, every row with
+# paged rows per config: both append rates, every row with
 # full serve telemetry and a ZERO steady-state recompile count (paged
 # appends are page-pointer swaps at fixed dispatch shapes); the depth sweep
 # must cover {1,2,4} with a residency bit-identity check per depth, and
 # the oversubscription headline row must hold >=80% of the fully-resident
 # qps at pipeline depth >=2
-_PAGED_ROWS = ("segmented_append_0", "segmented_append_high",
-               "paged_append_0", "paged_append_high")
+_PAGED_ROWS = ("paged_append_0", "paged_append_high")
 _PAGED_DEPTHS = ("depth_1", "depth_2", "depth_4")
 _PAGED_OVERSUB_FLOOR = 0.80
 
@@ -97,7 +96,8 @@ def check_perf_schema(results: dict) -> None:
                         f"live_index.{name}.{rowname}: "
                         f"{cfg[rowname]['recompiles_steady']} steady-state "
                         f"recompiles — appends must never stall serving on "
-                        f"a jit compile (fixed-capacity delta contract)")
+                        f"a jit compile (fixed-shape paged dispatch "
+                        f"contract)")
     ca = results.get("cascade")
     if not isinstance(ca, dict) or not isinstance(ca.get("rows"), dict) \
             or not ca["rows"]:

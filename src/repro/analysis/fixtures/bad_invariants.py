@@ -47,13 +47,3 @@ def unmasked_rescore_jnp(D: jax.Array, q: jax.Array, cids: jax.Array,
                               idx, axis=-1)
     return top_s, ids
 
-
-def overlapping_segments(D1: jax.Array, D2: jax.Array, scale: jax.Array,
-                         q: jax.Array, k: int = 5):
-    """Two delta dispatches whose [offset, offset+capacity) global-id
-    intervals collide — two documents share an id, so the cross-segment
-    merge dedup is wrong. Must trip exactly ``inv.segment-offsets``."""
-    from repro.core.index import _delta_topk
-    a = _delta_topk(D1, scale, q, jnp.int32(D1.shape[0]), jnp.int32(100), k)
-    b = _delta_topk(D2, scale, q, jnp.int32(D2.shape[0]), jnp.int32(132), k)
-    return a, b

@@ -2,10 +2,10 @@
 
 The scan/merge/cascade pipeline leans on *value* contracts that no type
 system sees: the shortlist ids entering the Pallas rescore kernel are
-sorted and duplicate-free, ``-1`` dedup/pad sentinels are masked to
-``-inf`` before any top-k, and segment id offsets partition the global id
-space. PR 7 shipped those as comments; this pass proves them, per traced
-entry point, by tracking value facts through jaxpr equations:
+sorted and duplicate-free, and ``-1`` dedup/pad sentinels are masked to
+``-inf`` before any top-k. Comments state those contracts; this pass
+proves them, per traced entry point, by tracking value facts through
+jaxpr equations:
 
   * ``asc``       — the non-sentinel subsequence is sorted ascending
   * ``distinct``  — the non-sentinel values are pairwise distinct
@@ -32,12 +32,6 @@ checked:
     selected by a ``top_k``): the top-k's score input must carry the
     ``masked-by-those-ids`` fact (``inv.sentinel-mask``), and the ids must
     be ``distinct`` (``inv.dedup-tiebreak``).
-  * **segment offsets** (top-level ``_delta_topk`` / ``_segment_rescore``
-    dispatches): each segment's ``[offset, offset+capacity)`` id interval,
-    read from the call-site literals and operand shapes, must be pairwise
-    disjoint and (for deltas) start at or above the base row count
-    (``inv.segment-offsets``) — what makes ``merge_segment_topk``'s
-    first-occurrence dedup mean "lowest global id".
 
 Unknown primitives drop facts, so the pass errs toward "cannot prove"
 (a finding) rather than wrongly proving; each contract has a known-bad
@@ -256,15 +250,6 @@ class _Interp:
                 env[eqn.outvars[0]] = f
                 return
 
-    def _p_max(self, eqn, env):
-        # _cascade_select folds per-segment rescore parts with elementwise
-        # max; every part masks the same shortlist's sentinels to -inf, so
-        # the fold is still masked by those ids
-        a, b = (self.fact(env, v) for v in eqn.invars[:2])
-        if (a.kind == "masked" and b.kind == "masked"
-                and a.origin is b.origin):
-            env[eqn.outvars[0]] = a
-
     def _p_select_n(self, eqn, env):
         pred, case0, case1 = eqn.invars[:3]
         pf = self.fact(env, pred)
@@ -436,95 +421,6 @@ class _Interp:
 
 
 # ---------------------------------------------------------------------------
-# segment-offset disjointness (top-level dispatch literals)
-# ---------------------------------------------------------------------------
-
-_SEGMENT_DISPATCHES = {
-    # dispatch name -> positional role of its two scalar operands
-    "_delta_topk": ("n_valid", "offset"),
-    "_segment_rescore": ("offset", "n_valid"),
-}
-
-
-def _scalar_operands(eqn, constmap) -> list:
-    vals = []
-    for v in eqn.invars:
-        aval = getattr(v, "aval", None)
-        if aval is None or aval.shape:
-            continue
-        if isinstance(v, Literal):
-            vals.append(_scalar(v.val))
-        elif v in constmap:
-            vals.append(_scalar(constmap[v]))
-        else:
-            vals.append(None)                     # traced: unknowable here
-    return vals
-
-
-def _first_matrix_rows(eqn) -> int | None:
-    for v in eqn.invars:
-        aval = getattr(v, "aval", None)
-        if aval is not None and len(aval.shape) == 2:
-            return int(aval.shape[0])
-    return None
-
-
-def check_segment_offsets(label: str, closed_jaxpr) -> list[Finding]:
-    """Prove the per-segment global-id intervals partition disjointly."""
-    jaxpr = closed_jaxpr.jaxpr
-    constmap = dict(zip(jaxpr.constvars, closed_jaxpr.consts))
-    groups: dict[str, list[tuple[int, int]]] = {}
-    base_n = None
-    findings: list[Finding] = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name != "jit":
-            continue
-        name = eqn.params.get("name")
-        if name == "_scan_topk" and base_n is None:
-            base_n = _first_matrix_rows(eqn)
-        if name not in _SEGMENT_DISPATCHES:
-            continue
-        roles = _SEGMENT_DISPATCHES[name]
-        scalars = _scalar_operands(eqn, constmap)
-        cap = _first_matrix_rows(eqn)
-        if len(scalars) < len(roles) or cap is None or any(
-                s is None for s in scalars[:len(roles)]):
-            findings.append(Finding(
-                check="inv.segment-offsets", where=f"{label}:{name}",
-                message=(f"{label}: cannot statically read the "
-                         f"(offset, n_valid) operands of a {name} "
-                         f"dispatch — segment id disjointness is "
-                         f"unprovable")))
-            continue
-        vals = dict(zip(roles, scalars))
-        off = int(vals["offset"])
-        groups.setdefault(name, []).append((off, off + cap))
-    for name, ivs in sorted(groups.items()):
-        if name == "_delta_topk":
-            if base_n is not None:
-                low = min(o for o, _ in ivs)
-                if low < base_n:
-                    findings.append(Finding(
-                        check="inv.segment-offsets",
-                        where=f"{label}:{name}:base",
-                        message=(f"{label}: delta segment id offset {low} "
-                                 f"overlaps the base index rows "
-                                 f"[0, {base_n}) — delta global ids must "
-                                 f"start past the base")))
-        ivs = sorted(ivs)
-        for (alo, ahi), (blo, bhi) in zip(ivs, ivs[1:]):
-            if blo < ahi:
-                findings.append(Finding(
-                    check="inv.segment-offsets",
-                    where=f"{label}:{name}:{alo}-{blo}",
-                    message=(f"{label}: {name} segment id intervals "
-                             f"[{alo}, {ahi}) and [{blo}, {bhi}) overlap "
-                             f"— two documents share a global id, so the "
-                             f"cross-segment merge dedup is wrong")))
-    return findings
-
-
-# ---------------------------------------------------------------------------
 # entry
 # ---------------------------------------------------------------------------
 
@@ -532,13 +428,12 @@ def check_segment_offsets(label: str, closed_jaxpr) -> list[Finding]:
 def check_entry(label: str, fn, args) -> list[Finding]:
     """All invariant checks for one traced entry point."""
     closed = jax.make_jaxpr(fn)(*args)
-    findings = check_segment_offsets(label, closed)
     interp = _Interp(label)
     env: dict = {}
     for cv, cval in zip(closed.jaxpr.constvars, closed.consts):
         env[cv] = Fact(const=_scalar(cval))
     interp.run_jaxpr(closed.jaxpr, env)
-    return findings + interp.findings
+    return interp.findings
 
 
 def run() -> list[Finding]:

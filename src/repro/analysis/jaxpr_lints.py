@@ -8,9 +8,9 @@ invariant, and the resulting jaxpr is walked statically:
     exactly the expected number of *compute dispatches* (jit eqns whose
     inner jaxpr does real work: dot_general / scan / top_k / pallas_call /
     collectives). ``DenseIndex.search_projected`` and
-    ``ShardedDenseIndex.search_projected`` must be ONE; a
-    ``SegmentedIndex`` is one projection + one per segment + one merge by
-    design. A stray extra dispatch (a projection that escaped the jit, a
+    ``ShardedDenseIndex.search_projected`` and a device-resident
+    ``PagedIndex`` must be ONE; a paged cascade is five by design. A
+    stray extra dispatch (a projection that escaped the jit, a
     device round-trip) is the regression this lint exists to catch.
   * **storage-dtype streaming** — with an int8/bf16 index, no
     ``convert_element_type`` may upcast an operand larger than one scan
@@ -20,10 +20,11 @@ invariant, and the resulting jaxpr is walked statically:
   * **no host callbacks** — ``pure_callback``/``io_callback``/debug
     prints/infeed inside the traced hot path serialise the device behind
     the host; none may appear anywhere in the trace.
-  * **jit-cache stability** — dispatching the segmented search across a
-    sweep of delta live-counts and id offsets must not grow any jit cache
-    (``segment_jit_cache_sizes``): live-count and offset are traced
-    operands by contract, so an append never recompiles.
+  * **jit-cache stability** — appending to a paged index (and a paged
+    cascade) and searching it across a sweep of live counts, and the full
+    page lifecycle, must not grow any jit cache
+    (``repro.core.paged._jit_cache_sizes``): page-table slot bounds are
+    traced operands by contract, so an append never recompiles.
 """
 from __future__ import annotations
 
@@ -215,7 +216,7 @@ def check_recompile_stability(dispatch: Callable[[int, int], None],
     return [Finding(
         check="jaxpr.recompile", where=f"{label}:{name}",
         message=(f"{label}: jit cache of '{name}' grew {b} -> {a} across a "
-                 f"live-count/offset sweep — a segment quantity leaked "
+                 f"live-count/offset sweep — an index quantity leaked "
                  f"into a static cache key, so appends recompile under "
                  f"live traffic")) for name, (b, a) in sorted(grew.items())]
 
@@ -242,7 +243,7 @@ class EntryPoint:
     the storage-dtype streaming check does not apply (f32 storage, or the
     deltas' whole-capacity dequant-by-design). ``bench_key`` names the
     ``BENCH_perf.json`` serve_pipeline config this entry models, when one
-    exists. ``family`` ∈ dense/cascade/sharded/segmented/cascade-seg."""
+    exists. ``family`` ∈ dense/cascade/sharded/paged/cascade-paged."""
 
     label: str
     fn: Callable
@@ -261,7 +262,6 @@ def serving_entry_points() -> tuple[EntryPoint, ...]:
     """Build every serving entry point on the tiny synthetic corpus."""
     from repro.core import (CascadeIndex, DenseIndex, ShardedDenseIndex,
                             StaticPruner)
-    from repro.core.index import SegmentedIndex
 
     D, Q = _tiny()
     pruner = StaticPruner(cutoff=0.5).fit(D)
@@ -324,21 +324,6 @@ def serving_entry_points() -> tuple[EntryPoint, ...]:
             strip_rows=128 if quant else None,
             bench_key="sharded_int8" if quant else "sharded_f32", batch=B))
 
-    # -- segmented: projection + base + one per delta + merge --------------
-    # (storage-dtype streaming of the base is covered by the dense/sharded
-    # checks above; deltas upcast their whole small capacity by design)
-    rng = np.random.default_rng(3)
-    seg = SegmentedIndex.from_index(DenseIndex.build(Dh, quantize_int8=True),
-                                    delta_capacity=64)
-    seg = seg.append(rng.standard_normal((70, m)).astype(np.float32))
-    nd = len(seg.deltas)
-    entries.append(EntryPoint(
-        label=f"SegmentedIndex.search_projected[int8,{nd}d]",
-        fn=(lambda s: lambda q: s.search_projected(q, W, k=10,
-                                                   mean=mean))(seg),
-        args=(Q,), expected_dispatches=nd + 3, corpus_shape=(n, m),
-        family="segmented", backend="jnp", batch=B))
-
     # -- paged: a device-resident paged index is ONE fused dispatch — the
     # projection, page-table walk, per-page in-register dequant and top-k
     # all trace into ``_paged_search_projected``. Appends land in the tail
@@ -365,7 +350,7 @@ def serving_entry_points() -> tuple[EntryPoint, ...]:
 
     # -- paged cascade: projection + paged coarse walk + shortlist +
     # paged rescore + select = 5 dispatches, independent of page or
-    # extent count (the segmented cascade pays 2 more per delta) -----------
+    # extent count ---------------------------------------------------------
     rng_pc = np.random.default_rng(13)
     pcas = CascadeIndex.build(Dh, m_coarse=max(2, m // 2), n_factor=2,
                               quantize_int8=True
@@ -378,27 +363,13 @@ def serving_entry_points() -> tuple[EntryPoint, ...]:
         args=(Q,), expected_dispatches=5, corpus_shape=(n, m),
         family="cascade-paged", backend="jnp", batch=B))
 
-    # -- segmented cascade: projection + per-segment coarse scans + coarse
-    # merge + shortlist + per-segment rescores + select = 2*nd + 6 ---------
-    rng_c = np.random.default_rng(7)
-    cseg = CascadeIndex.build(Dh, m_coarse=max(2, m // 2), n_factor=2,
-                              quantize_int8=True
-                              ).segmented(delta_capacity=64)
-    cseg = cseg.append(rng_c.standard_normal((70, m)).astype(np.float32))
-    cnd = len(cseg.full.deltas)
-    entries.append(EntryPoint(
-        label=f"CascadeIndex.search_projected[seg,int8,{cnd}d]",
-        fn=(lambda c: lambda q: c.search_projected(q, W, k=10,
-                                                   mean=mean))(cseg),
-        args=(Q,), expected_dispatches=2 * cnd + 6, corpus_shape=(n, m),
-        family="cascade-seg", backend="jnp", batch=B))
     return tuple(entries)
 
 
 def run() -> list[Finding]:
     """Lint every serving entry point; returns the combined findings."""
-    from repro.core import DenseIndex, StaticPruner
-    from repro.core.index import SegmentedIndex, segment_jit_cache_sizes
+    from repro.core import CascadeIndex, DenseIndex, StaticPruner
+    from repro.core import cascade, paged
     from repro.core.pca import transform
 
     findings: list[Finding] = []
@@ -425,56 +396,29 @@ def run() -> list[Finding]:
     entry = lambda b: transform(b, pruner.state, pruner.kept_dims)  # noqa: E731
     findings += check_no_callbacks(label, entry, (block,))
 
-    # -- recompile stability across live-counts/offsets --------------------
-    from repro.core import CascadeIndex
-    seg = SegmentedIndex.from_index(DenseIndex.build(Dh, quantize_int8=True),
-                                    delta_capacity=64)
-    seg = seg.append(rng.standard_normal((70, m)).astype(np.float32))
-    rng_c = np.random.default_rng(7)
-    cseg = CascadeIndex.build(Dh, m_coarse=max(2, m // 2), n_factor=2,
-                              quantize_int8=True
-                              ).segmented(delta_capacity=64)
-    cseg = cseg.append(rng_c.standard_normal((70, m)).astype(np.float32))
-    state = {"seg": seg}
-
-    def dispatch(live_rows: int, _offset: int) -> None:
-        state["seg"] = state["seg"].append(
-            rng.standard_normal((live_rows, m)).astype(np.float32))
-        state["seg"].search_projected(Q, W, k=5, mean=mean)
-
-    # stays within the open delta's capacity: every step changes the live
-    # count and the next segment's id offset but must reuse every jit.
-    # One compile per distinct append-block SHAPE is the documented
-    # ``_delta_update`` contract, and whether a given append exercises it
-    # (vs the scale-widening requant path) depends on the data — so every
-    # sweep shape is warmed deterministically for both the full and the
-    # cascade's coarse width before anything is measured.
-    from repro.core.index import _delta_update
+    # -- paged cascade recompile stability: appends grow BOTH resolutions;
+    # the coarse walk and the rescore take page-table slot bounds as traced
+    # operands and nk = n_factor*k stays fixed, so no jit may recompile
+    # (appends themselves are host-side writes re-pushed at fixed shapes).
     sweep = [(1, 0), (2, 0), (3, 0), (5, 0), (1, 0)]
-    store_dt = seg.deltas[-1].vectors.dtype
-    for r in sorted({lr for lr, _ in sweep}):
-        for mm in (m, max(2, m // 2)):
-            _delta_update(jnp.zeros((64, mm), store_dt),
-                          jnp.zeros((r, mm), store_dt), jnp.int32(0))
-    findings += check_recompile_stability(
-        dispatch, segment_jit_cache_sizes, sweep,
-        "SegmentedIndex.append+search_projected")
-
-    # -- cascade recompile stability: appends grow BOTH resolutions; every
-    # per-segment rescore takes live count/offset as traced operands and
-    # nk = n_factor*k stays fixed, so no cascade jit may recompile. The
-    # sweep stays inside the open delta's capacity (the part count — a
-    # legitimate static shape — is unchanged throughout).
-    cstate = {"cas": cseg}
+    rng_c = np.random.default_rng(7)
+    cas = CascadeIndex.build(Dh, m_coarse=max(2, m // 2), n_factor=2,
+                             quantize_int8=True
+                             ).paged(page_rows=64, seal_rows=128)
+    cstate = {"cas": cas.append(rng_c.standard_normal((70, m))
+                                .astype(np.float32))}
 
     def cdispatch(live_rows: int, _offset: int) -> None:
         cstate["cas"] = cstate["cas"].append(
             rng_c.standard_normal((live_rows, m)).astype(np.float32))
         cstate["cas"].search_projected(Q, W, k=5, mean=mean)
 
+    def cascade_cache_sizes() -> dict:
+        return {**paged._jit_cache_sizes(), **cascade._jit_cache_sizes()}
+
     findings += check_recompile_stability(
-        cdispatch, segment_jit_cache_sizes, sweep,
-        "CascadeIndex.append+search_projected")
+        cdispatch, cascade_cache_sizes, sweep,
+        "CascadeIndex.append+search_projected[paged]")
 
     # -- paged lifecycle recompile stability: the FULL page lifecycle —
     # append -> search -> promote -> compact -> search — at varying live
@@ -483,9 +427,8 @@ def run() -> list[Finding]:
     # shapes; [lo, hi) slot bounds are traced operands; compaction is the
     # one fused ``_pool_drain`` gather. Any cache growth here means a page
     # count or extent boundary leaked into a static key.
-    from repro.core.paged import PagedIndex
     rng_p = np.random.default_rng(11)
-    pstate = {"pg": PagedIndex.from_index(
+    pstate = {"pg": paged.PagedIndex.from_index(
         DenseIndex.build(Dh, quantize_int8=True), page_rows=64,
         seal_rows=128)}
 
@@ -499,6 +442,5 @@ def run() -> list[Finding]:
         pstate["pg"] = pg
 
     findings += check_recompile_stability(
-        pdispatch, segment_jit_cache_sizes, sweep,
-        "PagedIndex.lifecycle")
+        pdispatch, paged._jit_cache_sizes, sweep, "PagedIndex.lifecycle")
     return findings

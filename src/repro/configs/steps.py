@@ -648,13 +648,7 @@ def _two_tower_bundle(spec_, cell, mesh, cfg, params_sds, pspec, meta):
                                  + 2 * cfg.param_count() // 1000)
 
     hier = bool(cell.dims.get("hier_merge", 0))
-    delta_rows = int(cell.dims.get("delta_rows", 0))
-    if delta_rows:
-        delta_rows = round_up(delta_rows, 128)
-        meta["delta_rows"] = delta_rows
-        meta["model_flops"] += 2 * delta_rows * m
-        meta["analytic_bytes"] += delta_rows * m * (1 if int8 else 4)
-    if m == d_full and not int8 and not delta_rows:
+    if m == d_full and not int8:
         def fn(params, item_index, user_ids):
             u = R.user_embedding(params, user_ids)           # (1, d)
             return _sharded_index_topk(item_index, u, TOPK_SERVE, mesh,
@@ -662,35 +656,6 @@ def _two_tower_bundle(spec_, cell, mesh, cfg, params_sds, pspec, meta):
 
         args = (params_sds, index_sds, sds((1,), jnp.int32))
         in_specs = (pspec, P(all_axes, None), P())
-    elif delta_rows:
-        # live segmented serving (SegmentedIndex at pod scale): sharded
-        # immutable base + one replicated open delta at fixed padded
-        # capacity with its OWN scale and a traced live-row count — the
-        # query projects once unfolded, folds each segment's scale
-        # separately, and the two candidate lists merge with global id
-        # offsets (delta ids start at C) via the same merge_segment_topk
-        # the serving index uses
-        W_sds = sds((d_full, m), jnp.float32)
-        scale_sds = sds((m,), jnp.float32)
-        delta_sds = sds((delta_rows, m), jnp.int8 if int8 else jnp.float32)
-
-        def fn(params, item_index, W_m, scale, delta_seg, delta_scale,
-               delta_n, user_ids):
-            from repro.core.index import (_delta_topk, merge_segment_topk,
-                                          project_queries)
-            u = R.user_embedding(params, user_ids)           # (1, d)
-            q = project_queries(u, W_m)                      # unfolded
-            base = _sharded_index_topk(item_index, q * scale[None, :],
-                                       TOPK_SERVE, mesh, hierarchical=hier)
-            delta = _delta_topk(delta_seg, delta_scale, q, delta_n,
-                                jnp.int32(C), TOPK_SERVE)
-            return merge_segment_topk([base, delta], TOPK_SERVE)
-
-        args = (params_sds, index_sds, W_sds, scale_sds, delta_sds,
-                sds((m,), jnp.float32), sds((), jnp.int32),
-                sds((1,), jnp.int32))
-        in_specs = (pspec, P(all_axes, None), P(), P(), P(None, None),
-                    P(), P(), P())
     else:
         # PCA-pruned (optionally int8) index: q̂ = (q @ W_m) ⊙ scale — the
         # same fused projection+fold the serving hot path traces

@@ -7,8 +7,7 @@ from repro.core.pca import (
     save_pca, load_pca,
 )
 from repro.core.pruning import StaticPruner
-from repro.core.index import (DeltaSegment, DenseIndex, SegmentedIndex,
-                              ShardedDenseIndex, merge_segment_topk)
+from repro.core.index import DenseIndex, ShardedDenseIndex
 from repro.core.cascade import CascadeIndex
 from repro.core.store import IndexStore, IndexStoreError, save_index
 from repro.core import metrics
@@ -21,7 +20,7 @@ __all__ = [
     "transform", "transform_query", "inverse_transform",
     "m_from_cutoff", "cutoff_from_m", "m_for_variance", "explained_variance_ratio",
     "save_pca", "load_pca", "StaticPruner", "DenseIndex", "ShardedDenseIndex",
-    "SegmentedIndex", "DeltaSegment", "CascadeIndex", "merge_segment_topk",
+    "CascadeIndex",
     "IndexStore", "IndexStoreError", "save_index",
     "metrics", "quantization",
 ]

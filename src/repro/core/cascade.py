@@ -25,10 +25,11 @@ captures that win query-independently, offline:
 
 Both stages trace into ONE jit for a dense×dense cascade (projection +
 coarse scan + shortlist + gather + rescore + select — the serving hot path
-stays one dispatch per batch). A segmented cascade mirrors the segmented
-search contract instead: one shared projection, one dispatch per segment
-per stage with live counts and id offsets as *traced* operands, so
-steady-state appends never recompile.
+stays one dispatch per batch). A paged cascade (``CascadeIndex.paged``,
+the form that takes appends) runs one shared projection, the coarse page
+walk, the shortlist, the paged rescore and the select: five dispatches,
+whatever the page or extent count, with page-table slot bounds as *traced*
+operands, so steady-state appends never recompile.
 
 Tie-breaks: the shortlist is sorted ascending, so ``lax.top_k``'s
 first-occurrence rule reproduces the monolithic scan's lowest-doc-id
@@ -48,12 +49,12 @@ import numpy as np
 from repro.core.index import (
     Backend,
     DenseIndex,
-    SegmentedIndex,
     _project_nofold,
     _scan_topk,
     _topk_merge,
     project_queries,
 )
+from repro.core.paged import PagedIndex
 from repro.kernels.topk_score import query_terms, score_rows
 
 
@@ -106,39 +107,19 @@ def _cascade_dense_projected(Dc, scale_c, Df, scale_f, W, mean, Q, k: int,
     return _topk_merge(s, jnp.broadcast_to(uids[None, :], s.shape), k)
 
 
-@jax.jit
-def _segment_rescore(D, scale, qf, uids, offset, n_valid):
-    """(B, U) exact scores ONE full-resolution segment contributes to the
-    shared shortlist; slots outside this segment's live id range are -inf.
-
-    ``offset`` (the segment's global doc-id base) and ``n_valid`` (live
-    rows) are traced operands — appends reuse the compiled shape, the same
-    zero-recompile contract as ``_delta_topk``.
-    """
-    q = qf if scale is None else qf * scale[None, :]
-    local = uids - offset
-    valid = (uids >= 0) & (local >= 0) & (local < n_valid)
-    rows = jnp.take(D, jnp.clip(local, 0, D.shape[0] - 1), axis=0)
-    s = score_rows(query_terms(q, rows.dtype), rows)
-    return jnp.where(valid[None, :], s, -jnp.inf)
-
-
 @partial(jax.jit, static_argnames=("k",))
-def _cascade_select(parts_s, uids, k: int):
-    """Combine per-segment rescore parts (each uid live in exactly one
-    segment, so elementwise max is exact) and select the final top-k."""
-    s = parts_s[0]
-    for p in parts_s[1:]:
-        s = jnp.maximum(s, p)
+def _cascade_select(s, uids, k: int):
+    """Final top-k of the (B, U) shortlist scores."""
     return _topk_merge(s, jnp.broadcast_to(uids[None, :], s.shape), k)
 
 
 def _jit_cache_sizes() -> dict:
-    """Compiled-variant counts of every cascade jit, merged into
-    ``repro.core.index.segment_jit_cache_sizes`` for recompile soaks."""
+    """Compiled-variant counts of every cascade jit, for recompile soaks
+    (merge with ``repro.core.paged._jit_cache_sizes`` for a paged
+    cascade)."""
     return {fn.__wrapped__.__name__: fn._cache_size()
             for fn in (_cascade_dense_projected, _cascade_shortlist,
-                       _segment_rescore, _cascade_select)}
+                       _cascade_select)}
 
 
 def _coarse_rows(full) -> np.ndarray:
@@ -164,8 +145,8 @@ class CascadeIndex:
     serving swap installs a consistent pair atomically.
     """
 
-    coarse: DenseIndex | SegmentedIndex
-    full: DenseIndex | SegmentedIndex
+    coarse: DenseIndex | PagedIndex
+    full: DenseIndex | PagedIndex
     n_factor: int = 8
 
     def __post_init__(self):
@@ -213,41 +194,27 @@ class CascadeIndex:
 
     @classmethod
     def load(cls, store, *, m_coarse: int | None = None, n_factor: int = 8,
-             backend: Backend = "jnp", segmented: bool = False,
-             paged: bool = False, page_rows: int | None = None,
-             pool_pages: int | None = None,
-             delta_capacity: int = 4096) -> "CascadeIndex":
+             backend: Backend = "jnp", page_rows: int | None = None,
+             pool_pages: int | None = None) -> "CascadeIndex":
         """Load a multi-resolution artifact: the main segments become the
         full resolution, the ``m_coarse`` resolution entry the coarse one
         (``m_coarse=None`` picks the widest stored resolution).
 
-        On a segmented load the coarse deltas rehydrate from the
-        resolution's PERSISTED delta segments when the store carries them
-        (``save_index`` on a segmented cascade writes the exact quantised
-        bytes + per-delta scales, so the reload is bit-identical to what
-        was serving). A store without them — or one whose main deltas have
-        grown past the persisted coarse view — falls back to re-deriving
-        (requantising) the coarse deltas from the full deltas' dequantised
-        rows, so the pair stays row-aligned however far the store has
-        grown.
+        A store that has grown (a ``paged`` block or delta segments) loads
+        both resolutions paged (``PagedIndex.load`` for the full side):
+        the coarse extents adopt the resolution's PERSISTED delta segments
+        when the store carries them (``save_index`` on a paged cascade
+        writes the exact quantised bytes + per-extent scales, so the
+        reload is bit-identical to what was serving). A store without
+        them — or one whose main segments have grown past the persisted
+        coarse view — re-derives (requantises) the coarse deltas from the
+        full deltas' dequantised rows, so the pair stays row-aligned
+        however far the store has grown. A flat store loads dense; call
+        ``paged()`` on it to take appends.
         """
         from repro.core.store import IndexStore, IndexStoreError
         if isinstance(store, (str, os.PathLike)):
             store = IndexStore.open(store)
-        if paged:
-            # the segmented load path rehydrates both resolutions
-            # byte-for-byte; the page tables then adopt those bytes —
-            # the paged block (when present) supplies the geometry
-            pb = store.manifest.get("paged") or {}
-            inner = cls.load(store, m_coarse=m_coarse, n_factor=n_factor,
-                             backend=backend, segmented=True,
-                             delta_capacity=int(pb.get("seal_rows",
-                                                       delta_capacity)))
-            return inner.paged(
-                page_rows=int(pb.get("page_rows", 256))
-                if page_rows is None else page_rows,
-                pool_pages=pool_pages,
-                seal_rows=int(pb.get("seal_rows", delta_capacity)))
         views = store.resolutions()
         if not views:
             raise IndexStoreError(
@@ -263,57 +230,50 @@ class CascadeIndex:
                     f"{store.path}: no m={m_coarse} resolution (stored: "
                     f"{sorted(by_m)})")
             view = by_m[m_coarse]
-        coarse = DenseIndex.load(view, backend=backend)
-        if segmented:
-            from repro.core.index import rehydrate_delta
-            full = SegmentedIndex.load(store, backend=backend,
-                                       delta_capacity=delta_capacity)
-            coarse = SegmentedIndex.from_index(
-                coarse, delta_capacity=delta_capacity)
-            dviews = store.resolution_deltas(view.name)
-            if dviews and ([v.n for v in dviews]
-                           == [d.n_real for d in full.deltas]):
-                # persisted coarse deltas mirror the main ones row-for-row:
-                # rehydrate the exact quantised bytes (no requantisation)
-                coarse = dataclasses.replace(
-                    coarse, deltas=tuple(rehydrate_delta(v, delta_capacity)
-                                         for v in dviews))
-            else:
-                # legacy artifact (or the main store grew past the persisted
-                # view): re-derive coarse deltas from the full deltas
-                for d in full.deltas:
-                    if d.n_real:
-                        coarse = coarse.append(d.raw[:, :coarse.dim])
+        if "paged" not in store.manifest and len(store.segments()) == 1:
+            return cls(coarse=DenseIndex.load(view, backend=backend),
+                       full=DenseIndex.load(store, backend=backend),
+                       n_factor=n_factor)
+        full = PagedIndex.load(store, page_rows=page_rows,
+                               pool_pages=pool_pages, backend=backend)
+        fst = full.storage
+        coarse = PagedIndex.from_index(
+            DenseIndex.load(view, backend=backend), page_rows=fst.page_rows,
+            tail_pages=fst.tail_pages, seal_rows=fst.seal_rows)
+        dviews = store.resolution_deltas(view.name)
+        if dviews and ([v.n for v in dviews]
+                       == [e.n_rows for e in fst.extents[1:]]):
+            # persisted coarse deltas mirror the main ones row-for-row:
+            # adopt the exact quantised bytes (no requantisation), with
+            # each main extent's lifecycle state
+            st = coarse.storage
+            for v in dviews:
+                vs = v.scale()
+                st = st._adopt_extent(
+                    v.read_rows(0, v.n),
+                    None if vs is None else np.asarray(vs, np.float32))
+            coarse = dataclasses.replace(coarse, storage=st.with_lifecycle(
+                [(e.kind, e.sealed) for e in fst.extents]))
         else:
-            full = DenseIndex.load(store, backend=backend)
+            # an artifact without persisted coarse deltas (or the main store
+            # grew past the persisted view): re-derive them from the full
+            # extents' dequantised rows
+            for i in range(1, len(fst.extents)):
+                rows = fst.extent_f32(i)
+                if rows.shape[0]:
+                    coarse = coarse.append(rows[:, :coarse.dim])
         return cls(coarse=coarse, full=full, n_factor=n_factor)
-
-    def segmented(self, *, delta_capacity: int = 4096) -> "CascadeIndex":
-        """Wrap both resolutions as single-base segmented indexes (the
-        live-append serving form; appends grow the pair in lockstep)."""
-        return dataclasses.replace(
-            self,
-            coarse=SegmentedIndex.from_index(self.coarse,
-                                             delta_capacity=delta_capacity),
-            full=SegmentedIndex.from_index(self.full,
-                                           delta_capacity=delta_capacity))
 
     def paged(self, *, page_rows: int = 256, pool_pages: int | None = None,
               coarse_pool_pages: int | None = None, seal_rows: int = 4096,
               depth: int = 2, wave_pages: int = 8) -> "CascadeIndex":
-        """Re-home both resolutions on paged storage (byte-for-byte): the
-        coarse scan and the exact rescore then both stream through the
+        """Re-home both dense resolutions on paged storage (byte-for-byte):
+        the coarse scan and the exact rescore then both stream through the
         page tables — appends, promotion, compaction and eviction become
         pointer swaps on BOTH sides of the cascade, and either side may
         oversubscribe device memory independently (``pool_pages`` /
         ``coarse_pool_pages``)."""
-        from repro.core.paged import PagedIndex
-
         def conv(ix, pool):
-            if isinstance(ix, SegmentedIndex):
-                return PagedIndex.from_segmented(
-                    ix, page_rows=page_rows, pool_pages=pool, depth=depth,
-                    wave_pages=wave_pages)
             return PagedIndex.from_index(
                 ix, page_rows=page_rows, pool_pages=pool,
                 seal_rows=seal_rows, depth=depth, wave_pages=wave_pages)
@@ -343,12 +303,10 @@ class CascadeIndex:
     # -- growth (copy-on-write) --------------------------------------------
     def append(self, rows) -> "CascadeIndex":
         """Append pruned f32 rows (full m) to BOTH resolutions — the coarse
-        side takes the leading columns. Requires segmented resolutions."""
-        if not (isinstance(self.full, SegmentedIndex)
-                or hasattr(self.full, "storage")):
-            raise TypeError("append needs segmented or paged resolutions — "
-                            "wrap with CascadeIndex.segmented()/.paged() "
-                            "first")
+        side takes the leading columns. Requires paged resolutions."""
+        if not isinstance(self.full, PagedIndex):
+            raise TypeError("append needs paged resolutions — wrap with "
+                            "CascadeIndex.paged() first")
         rows = np.atleast_2d(np.asarray(rows, np.float32))
         return dataclasses.replace(
             self, full=self.full.append(rows),
@@ -366,40 +324,18 @@ class CascadeIndex:
         nk = min(self.n_factor * k, max(self.n, 1))
         Q = jnp.atleast_2d(queries)
         W = jnp.asarray(components)
-        if isinstance(self.full, SegmentedIndex) or hasattr(self.full,
-                                                            "storage"):
-            return self._segmented_search(Q, W, mean, k, nk)
+        if isinstance(self.full, PagedIndex):
+            return self._paged_search(Q, W, mean, k, nk)
         return _cascade_dense_projected(
             self.coarse.vectors, self.coarse.scale, self.full.vectors,
             self.full.scale, W, mean, Q, k, nk, block, self.full.backend)
 
-    def _segmented_search(self, Q, W, mean, k: int, nk: int):
-        """Segmented/paged cascade: shared projection, coarse scan over
-        live segments or the coarse page table, then an exact rescore of
-        the shared shortlist — per-segment dispatches combined by max, or
-        the paged page-table walk (``PagedIndex.rescore``, bitwise the
-        same parts-combine). Live counts/offsets (or page-table slot
-        bounds) are traced operands throughout — zero recompiles."""
+    def _paged_search(self, Q, W, mean, k: int, nk: int):
+        """Paged cascade: shared projection, the coarse page walk, then an
+        exact rescore of the shared shortlist by the full side's page
+        walk (``PagedIndex.rescore``). Page-table slot bounds are traced
+        operands throughout — zero recompiles as the index grows."""
         qf = _project_nofold(Q, W, mean)
-        qc = qf[:, :self.m_coarse]
-        if hasattr(self.coarse, "storage"):       # paged coarse scan
-            _, cids = self.coarse._search_qf(qc, nk)
-        else:
-            _, cids = self.coarse._merged_topk(qc, nk)
+        _, cids = self.coarse._search_qf(qf[:, :self.m_coarse], nk)
         uids = _cascade_shortlist(cids)
-        if hasattr(self.full, "storage"):         # paged exact rescore
-            acc = self.full.rescore(qf, uids)
-            return _cascade_select((acc,), uids, k)
-        base = self.full.base
-        if not isinstance(base, DenseIndex):
-            raise TypeError("segmented cascade rescore supports a dense "
-                            "base only (sharded bases: see ROADMAP)")
-        parts = [_segment_rescore(base.vectors, base.scale, qf, uids,
-                                  jnp.int32(0), jnp.int32(base.n))]
-        off = base.n
-        for d in self.full.deltas:
-            parts.append(_segment_rescore(d.vectors, d.scale, qf, uids,
-                                          jnp.int32(off),
-                                          jnp.int32(d.n_real)))
-            off += d.n_real
-        return _cascade_select(tuple(parts), uids, k)
+        return _cascade_select(self.full.rescore(qf, uids), uids, k)

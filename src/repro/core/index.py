@@ -8,14 +8,7 @@ This is the FAISS-flat role in the paper's pipeline, built TPU-native:
   * ``ShardedDenseIndex`` — rows sharded over every mesh device; each shard
                             scans locally, then a tiny global merge over the
                             per-shard top-k (k·chips candidates).
-  * ``SegmentedIndex``    — an immutable base segment (dense or sharded)
-                            plus growable fixed-capacity ``DeltaSegment``s,
-                            each delta with its OWN int8 scale; searched by
-                            a cross-segment top-k merge with global doc-id
-                            offsets. Appends are copy-on-write and dispatch
-                            at the delta's fixed padded capacity (live row
-                            count and id offset are traced operands), so a
-                            growing index never recompiles in steady state.
+  * The mutable (appendable) index is ``repro.core.paged.PagedIndex``.
   * int8 symmetric quantisation (beyond-paper) composes with PCA pruning:
     index bytes drop by 4x on top of the m/d PCA reduction.
 
@@ -91,7 +84,7 @@ def _check_flat_loadable(store) -> None:
     from repro.core.store import IndexStoreError
     raise IndexStoreError(
         f"{store.path}: store has delta segments with per-segment scales — "
-        f"load it with SegmentedIndex.load, not a flat index loader")
+        f"load it with PagedIndex.load, not a flat index loader")
 
 
 def _topk_merge(scores: jax.Array, ids: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
@@ -299,6 +292,14 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
     starts = jnp.arange(nblocks, dtype=jnp.int32) * block
     (scores, ids), _ = jax.lax.scan(body, init, starts)
     return scores, ids
+
+
+@partial(jax.jit, donate_argnums=0)
+def _fill_rows(D, block, start):
+    """Write ``block`` into ``D`` at row ``start``. ``D`` is donated (a
+    load's buffer no one else holds), so the rows land in place instead
+    of in a copy; ``start`` is traced, so one chunk shape compiles once."""
+    return jax.lax.dynamic_update_slice(D, block, (start, 0))
 
 
 @dataclasses.dataclass
@@ -652,383 +653,9 @@ class ShardedDenseIndex:
                                 check_vma=False)
 
 
-# ---------------------------------------------------------------------------
-# Segmented live index: immutable base + growable delta segments
-# ---------------------------------------------------------------------------
-
-
 @jax.jit
 def _project_nofold(Q, W, mean):
-    """Shared raw-query projection for segmented search: center + project,
-    WITHOUT any scale fold — per-segment scales fold inside each segment's
-    own dispatch (the segments no longer agree on one scale)."""
+    """Raw-query projection WITHOUT the scale fold, shared by searches whose
+    rows do not agree on one scale (``PagedIndex`` extents, the cascade's
+    two resolutions): each folds its own scale inside its own dispatch."""
     return project_queries(Q, W, scale=None, mean=mean)
-
-
-@partial(jax.jit, static_argnames=("k",))
-def _delta_topk(D, scale, Q, n_valid, offset, k: int):
-    """Top-k over one fixed-capacity delta segment, in one compiled shape.
-
-    ``D`` is the (capacity, m) segment in its storage dtype — rows at and
-    beyond the live count are zero padding. ``n_valid`` (live rows) and
-    ``offset`` (this segment's global doc-id base) are *traced* operands,
-    so appends that grow the live count never trigger a recompile: the
-    serving hot path dispatches the same compiled computation whether the
-    delta holds 1 row or its full capacity. Padding rows are masked to
-    (-inf, -1) before selection, exactly like the scan's init sentinels.
-    """
-    q = jnp.atleast_2d(Q).astype(jnp.float32)
-    if scale is not None:
-        q = q * scale[None, :]
-    cap = D.shape[0]
-    s = score_rows(query_terms(q, D.dtype), D)               # (B, cap) f32
-    ids = jnp.arange(cap, dtype=jnp.int32)[None, :]
-    live = ids < n_valid
-    s = jnp.where(live, s, -jnp.inf)
-    gids = jnp.broadcast_to(jnp.where(live, ids + offset, -1), s.shape)
-    ss, si = jax.lax.top_k(s, min(k, cap))
-    return ss, jnp.take_along_axis(gids, si, axis=-1)
-
-
-@jax.jit
-def _delta_update(D, block, start):
-    """Patch appended rows into a delta's fixed-capacity buffer — O(rows)
-    per append instead of re-uploading the whole capacity. ``start`` is
-    traced, so steady-state appends of one block size compile once."""
-    return jax.lax.dynamic_update_slice(D, block, (start, 0))
-
-
-@partial(jax.jit, donate_argnums=0)
-def _fill_rows(D, block, start):
-    """``_delta_update`` into a buffer no one else holds (a load's): ``D``
-    is donated, so the rows land in place instead of in a copy."""
-    return jax.lax.dynamic_update_slice(D, block, (start, 0))
-
-
-@partial(jax.jit, static_argnames=("k",))
-def _concat_topk(parts_s, parts_i, k: int):
-    s = jnp.concatenate(parts_s, axis=1)
-    ids = jnp.concatenate(parts_i, axis=1)
-    return _topk_merge(s, ids, k)
-
-
-def merge_segment_topk(candidates, k: int):
-    """Merge per-segment (B, k_i) top-k candidate lists (global ids already
-    applied) into the global (B, k) top-k.
-
-    Segments must be passed in ascending id-offset order (base first, then
-    deltas): ``lax.top_k`` keeps the *first* occurrence among equal scores,
-    so concatenation order reproduces the monolithic index's lowest-id
-    tie-break — the same invariant ``_staged_topk_merge`` relies on for its
-    row-major shard gather, which makes the segmented search bit-identical
-    to a monolithic scan over the concatenated corpus.
-    """
-    parts_s = tuple(s for s, _ in candidates)
-    parts_i = tuple(i for _, i in candidates)
-    if len(parts_s) == 1:
-        return parts_s[0], parts_i[0]
-    return _concat_topk(parts_s, parts_i, k)
-
-
-def segment_jit_cache_sizes() -> dict:
-    """Per-jit compiled-variant counts for every jit the segmented search
-    path can touch — the diagnosable form of ``segment_jit_cache_size``
-    (a failure names the function that recompiled)."""
-    from repro.core import cascade, paged  # lazy: both import this module
-    sizes = {fn.__wrapped__.__name__: fn._cache_size()
-             for fn in (_delta_topk, _concat_topk, _project_nofold,
-                        _scan_topk, _dense_search_projected, _delta_update)}
-    sizes.update(cascade._jit_cache_sizes())
-    sizes.update(paged._jit_cache_sizes())
-    return sizes
-
-
-def segment_jit_cache_size() -> int:
-    """Total compiled-variant count across every jit the segmented search
-    path can touch — the soak tests pin this to ZERO growth during
-    steady-state appends (the whole point of fixed-capacity deltas)."""
-    return sum(segment_jit_cache_sizes().values())
-
-
-@dataclasses.dataclass(frozen=True)
-class DeltaSegment:
-    """One growable segment: fixed-capacity storage + its own scale.
-
-    ``vectors`` always has ``capacity`` rows (zeros beyond ``n_real``) so
-    every search dispatches one compiled shape. ``raw`` keeps the exact f32
-    rows appended so far — the requantisation source when an append widens
-    the scale (re-quantising from f32 is exact; from int8 it would drift by
-    up to half an old LSB). After a cold start from disk ``raw`` is the
-    dequantised reconstruction — the best source that survives a restart.
-    """
-
-    vectors: jax.Array                 # (capacity, m), storage dtype
-    n_real: int
-    scale: jax.Array | None            # per-dim dequant scale (int8 deltas)
-    raw: np.ndarray                    # (n_real, m) f32 requant source
-
-    @property
-    def capacity(self) -> int:
-        return self.vectors.shape[0]
-
-    @property
-    def dim(self) -> int:
-        return self.vectors.shape[1]
-
-    @property
-    def nbytes(self) -> int:
-        b = self.vectors.size * self.vectors.dtype.itemsize
-        if self.scale is not None:
-            b += self.scale.size * self.scale.dtype.itemsize
-        return b
-
-    @staticmethod
-    def quantise(raw: np.ndarray, scale: np.ndarray) -> np.ndarray:
-        from repro.core.quantization import quantize_with_scale
-        return quantize_with_scale(raw, scale)
-
-    @classmethod
-    def build(cls, rows: np.ndarray, capacity: int, *, quantize: bool,
-              dtype) -> "DeltaSegment":
-        """Open a delta from its first f32 rows; int8 deltas get a FRESH
-        per-dim scale fitted to these rows — never the base's frozen one."""
-        from repro.core.quantization import scale_for
-        raw = np.ascontiguousarray(np.asarray(rows, np.float32))
-        if raw.shape[0] > capacity:
-            raise ValueError(f"{raw.shape[0]} rows exceed delta capacity "
-                             f"{capacity}")
-        if quantize:
-            scale = scale_for(raw)
-            stored = cls.quantise(raw, scale)
-        else:
-            scale = None
-            stored = raw.astype(np.dtype(dtype))
-        pad = capacity - stored.shape[0]
-        if pad:
-            stored = np.concatenate(
-                [stored, np.zeros((pad, stored.shape[1]), stored.dtype)])
-        return cls(vectors=jnp.asarray(stored), n_real=raw.shape[0],
-                   scale=None if scale is None else jnp.asarray(scale),
-                   raw=raw)
-
-    def extend(self, rows: np.ndarray
-               ) -> tuple["DeltaSegment", bool, np.ndarray]:
-        """Copy-on-write append of f32 rows.
-
-        Returns ``(new segment, widened, stored)`` where ``stored`` is the
-        host copy of what changed in storage dtype — just the new rows in
-        the common case, the whole requantised segment when the scale
-        widened (the durable mirror appends/rewrites exactly those bytes).
-
-        int8 deltas widen their per-dim scale whenever a new row's absmax
-        exceeds the representable range — the whole segment requantises
-        from its exact f32 staging, so nothing ever clips. That rewrite is
-        bounded by the segment's capacity (the reason the scale problem is
-        tractable per segment and was not on the monolithic index). The
-        common non-widened append touches only O(rows): the new rows
-        quantise under the unchanged scale and patch into the existing
-        device buffer with a ``dynamic_update_slice`` (a new immutable
-        array — in-flight searches keep the old one).
-        """
-        rows = np.ascontiguousarray(np.asarray(rows, np.float32))
-        if self.n_real + rows.shape[0] > self.capacity:
-            raise ValueError("extend beyond delta capacity — seal and open "
-                             "a new delta instead")
-        from repro.core.quantization import scale_for
-        raw = np.concatenate([self.raw, rows])
-        if self.scale is not None:
-            old = np.asarray(self.scale)
-            need = scale_for(rows)
-            scale = np.maximum(old, need).astype(np.float32)
-            if bool((scale > old).any()):          # widen: bounded rewrite
-                stored = self.quantise(raw, scale)
-                full = np.concatenate(
-                    [stored, np.zeros((self.capacity - stored.shape[0],
-                                       stored.shape[1]), stored.dtype)]) \
-                    if stored.shape[0] < self.capacity else stored
-                return dataclasses.replace(
-                    self, vectors=jnp.asarray(full), n_real=raw.shape[0],
-                    scale=jnp.asarray(scale), raw=raw), True, stored
-            new_rows = self.quantise(rows, old)
-        else:
-            new_rows = rows.astype(self.vectors.dtype)
-        vectors = _delta_update(self.vectors, jnp.asarray(new_rows),
-                                jnp.int32(self.n_real))
-        return dataclasses.replace(
-            self, vectors=vectors, n_real=raw.shape[0],
-            raw=raw), False, new_rows
-
-
-def rehydrate_delta(view, delta_capacity: int) -> DeltaSegment:
-    """Rebuild one ``DeltaSegment`` from a store view (a main delta OR a
-    persisted coarse-resolution delta): the stored quantised bytes become
-    the served bytes bit-for-bit, padded to the stored capacity; ``raw``
-    is the dequantised reconstruction — the best requant source that
-    survives a restart."""
-    rows = view.read_rows(0, view.n)
-    s = view.scale()
-    if s is not None:
-        raw = rows.astype(np.float32) * s[None, :].astype(np.float32)
-    else:
-        raw = rows.astype(np.float32)
-    cap = int(view.capacity) if view.capacity else max(delta_capacity,
-                                                       view.n)
-    stored = np.zeros((cap, view.dim), rows.dtype)
-    stored[:view.n] = rows
-    return DeltaSegment(vectors=jnp.asarray(stored), n_real=view.n,
-                        scale=None if s is None else jnp.asarray(s),
-                        raw=np.ascontiguousarray(raw))
-
-
-@dataclasses.dataclass(frozen=True)
-class SegmentedIndex:
-    """Immutable segment set: [base] + deltas, searched as one index.
-
-    The base is a committed ``DenseIndex`` or ``ShardedDenseIndex`` (the
-    offline PCA-pruned artifact); deltas absorb live corpus growth. Every
-    mutation (``append``) returns a NEW ``SegmentedIndex`` sharing the
-    untouched segments — the running ``RetrievalServer`` swaps whole
-    segment sets atomically between batches, and in-flight batches keep
-    the old set alive until their replies post.
-
-    Search = per-segment top-k (each segment folds its OWN scale) merged by
-    ``merge_segment_topk`` with global id offsets (base rows first, deltas
-    in open order). When every segment shares one scale the result is
-    bit-identical to a monolithic index over the concatenated corpus; with
-    mixed scales, ids/ordering are exactly the top-k of the per-segment
-    dequantised scores.
-    """
-
-    base: DenseIndex | ShardedDenseIndex
-    deltas: tuple[DeltaSegment, ...] = ()
-    delta_capacity: int = 4096
-
-    # -- construction -------------------------------------------------------
-    @classmethod
-    def from_index(cls, base, *, delta_capacity: int = 4096
-                   ) -> "SegmentedIndex":
-        return cls(base=base, deltas=(), delta_capacity=delta_capacity)
-
-    @classmethod
-    def load(cls, store, *, mesh: Mesh | None = None,
-             backend: Backend = "jnp", merge: Merge = "flat",
-             delta_capacity: int = 4096) -> "SegmentedIndex":
-        """Load a (possibly segmented) artifact: segment 0 becomes the base
-        (sharded over ``mesh`` when given), every delta segment is
-        rehydrated at its stored capacity with its own scale. A pre-segment
-        artifact loads as a single base — full backward compatibility."""
-        from repro.core.store import IndexStore
-        if isinstance(store, (str, os.PathLike)):
-            store = IndexStore.open(store)
-        views = store.segments()
-        base_view = views[0]
-        if mesh is not None:
-            base = ShardedDenseIndex.load(base_view, mesh, backend=backend,
-                                          merge=merge)
-        else:
-            base = DenseIndex.load(base_view, backend=backend)
-        deltas = [rehydrate_delta(v, delta_capacity) for v in views[1:]]
-        return cls(base=base, deltas=tuple(deltas),
-                   delta_capacity=delta_capacity)
-
-    # -- shape --------------------------------------------------------------
-    @property
-    def n(self) -> int:
-        return self.base.n + sum(d.n_real for d in self.deltas)
-
-    @property
-    def dim(self) -> int:
-        return self.base.dim
-
-    @property
-    def nbytes(self) -> int:
-        return self.base.nbytes + sum(d.nbytes for d in self.deltas)
-
-    @property
-    def quantized(self) -> bool:
-        return self.base.scale is not None
-
-    @property
-    def delta_rows(self) -> int:
-        return sum(d.n_real for d in self.deltas)
-
-    @property
-    def storage_dtype(self):
-        return self.base.vectors.dtype
-
-    # -- growth (copy-on-write) --------------------------------------------
-    def append(self, rows) -> "SegmentedIndex":
-        new, _ = self.append_with_ops(rows)
-        return new
-
-    def append_with_ops(self, rows) -> tuple["SegmentedIndex", list]:
-        """Append f32 rows (already PCA-pruned to this index's dim).
-
-        Returns ``(new_index, ops)`` where ``ops`` records what changed for
-        a durable mirror (``IndexStore``), in order:
-          ("open",   di, stored_rows, scale)  — new delta with first rows
-          ("extend", di, stored_rows)         — rows appended, scale kept
-          ("widen",  di, stored_all,  scale)  — scale widened: the delta's
-                                                full requantised contents
-        ``stored_*`` are in storage dtype (int8 already quantised), exactly
-        the bytes the in-memory index serves — disk and memory stay
-        bit-identical.
-        """
-        rows = np.atleast_2d(np.asarray(rows, np.float32))
-        if rows.shape[1] != self.dim:
-            raise ValueError(f"append expects (rows, {self.dim}), got "
-                             f"{tuple(rows.shape)}")
-        quantize = self.quantized
-        deltas = list(self.deltas)
-        ops: list = []
-        pos = 0
-        while pos < rows.shape[0]:
-            if deltas and deltas[-1].n_real < deltas[-1].capacity:
-                di = len(deltas) - 1
-                seg = deltas[di]
-                take = min(rows.shape[0] - pos, seg.capacity - seg.n_real)
-                block = rows[pos:pos + take]
-                seg, widened, stored = seg.extend(block)
-                deltas[di] = seg
-                if widened:
-                    ops.append(("widen", di, stored, np.asarray(seg.scale)))
-                else:
-                    ops.append(("extend", di, stored))
-            else:
-                di = len(deltas)
-                take = min(rows.shape[0] - pos, self.delta_capacity)
-                block = rows[pos:pos + take]
-                seg = DeltaSegment.build(block, self.delta_capacity,
-                                         quantize=quantize,
-                                         dtype=self.storage_dtype)
-                deltas.append(seg)
-                ops.append(("open", di, np.asarray(seg.vectors[:seg.n_real]),
-                            None if seg.scale is None
-                            else np.asarray(seg.scale)))
-            pos += take
-        return dataclasses.replace(self, deltas=tuple(deltas)), ops
-
-    # -- search -------------------------------------------------------------
-    def _merged_topk(self, q: jax.Array, k: int):
-        k = min(k, max(self.n, 1))
-        parts = [self.base.search(q, k=k)]
-        off = self.base.n
-        for d in self.deltas:
-            parts.append(_delta_topk(d.vectors, d.scale, q,
-                                     jnp.int32(d.n_real), jnp.int32(off), k))
-            off += d.n_real
-        return merge_segment_topk(parts, k)
-
-    def search(self, queries: jax.Array, k: int = 10
-               ) -> tuple[jax.Array, jax.Array]:
-        q = jnp.atleast_2d(queries).astype(jnp.float32)
-        return self._merged_topk(q, k)
-
-    def search_projected(self, queries: jax.Array, components: jax.Array,
-                         k: int = 10, *, mean: jax.Array | None = None
-                         ) -> tuple[jax.Array, jax.Array]:
-        """Raw-query search: one shared projection dispatch (no scale fold —
-        the segments don't share one), then per-segment fold+scan+merge."""
-        q = _project_nofold(jnp.atleast_2d(queries),
-                            jnp.asarray(components), mean)
-        return self._merged_topk(q, k)

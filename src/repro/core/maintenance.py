@@ -1,35 +1,33 @@
-"""Index maintenance: segmented live growth + drift-triggered compaction.
+"""Index maintenance: live growth, compaction and drift-triggered refit.
 
 Beyond-paper production plumbing for the pruned index. The paper shows the
 transform is robust out-of-domain (RQ2) and to small fit samples (RQ3) —
-this module turns those findings into operational policy over a
-**segmented** index (``repro.core.index.SegmentedIndex``):
+this module turns those findings into operational policy over the mutable
+index, ``repro.core.paged.PagedIndex``:
 
   * ``IndexUpdater.add_documents`` — new documents are rotated with the
-    EXISTING ``W_m`` and appended to the open *delta segment* (no refit, no
-    reindex of old docs). Each delta carries its OWN int8 scale, widened
+    EXISTING ``W_m`` and appended to the open delta extent (no refit, no
+    reindex of old docs). Each extent carries its OWN int8 scale, widened
     per append block when needed, so nothing ever clips against the base's
-    frozen scale — the clip problem the monolithic updater could only
-    *measure* is killed at the root. With a ``store`` attached, every
-    append mirrors durably to disk (the quantised bytes on disk are the
-    bytes being served); with a ``server`` attached, every append installs
-    the new segment set atomically between in-flight batches
-    (``RetrievalServer.swap_index``).
+    frozen scale. With a ``store`` attached, every append mirrors durably
+    to disk (the quantised bytes on disk are the bytes being served); with
+    a ``server`` attached, every append installs the new index atomically
+    between in-flight batches (``RetrievalServer.swap_index``).
   * ``drift_score`` — fraction of new-batch embedding energy captured by
     the kept subspace, ``||X W_m||² / ||X||²``, compared to the energy the
     subspace captured at fit time. A ratio near 1 ⇒ the rotation still
     fits (paper RQ2 regime); a falling ratio quantifies when the corpus
     distribution has moved enough to warrant an offline refit.
   * ``scale_divergence`` / ``delta_fraction`` — how far the delta scales
-    have widened past the base's, and how much of the corpus lives outside
-    the base. Either climbing is the compaction signal.
+    have widened past the base's, and how many pages live outside the
+    base. Either climbing is the compaction signal.
   * ``needs_refit`` — thresholded policy over all three signals.
-  * ``compact()`` — streaming re-build of base+deltas into ONE fresh base
-    segment (same rotation, fresh corpus-wide scale) through
-    ``StaticPruner.build_index_to(already_projected=True)``; commits
-    atomically at the store path, swaps into the server, retires the old
-    segments. ``compact_async()`` runs it off-thread — appends that land
-    mid-compaction are reconciled onto the new base before the swap.
+  * ``compact()`` — seal + promote every delta extent and drain its tail
+    pages into the pool: page-pointer swaps plus one fused gather
+    dispatch, and on disk one lifecycle-block manifest swap. It keeps
+    every extent's scale; ``refit`` is what requantises.
+  * ``refit()`` — a fresh PCA fit and a fresh index (fresh corpus-wide
+    scale) on the current corpus, replacing the store atomically.
 """
 from __future__ import annotations
 
@@ -40,7 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.index import DenseIndex, SegmentedIndex, ShardedDenseIndex
+from repro.core.index import DenseIndex
+from repro.core.paged import PagedIndex
 from repro.core.pruning import StaticPruner
 
 
@@ -86,10 +85,11 @@ def captured_energy(X: jax.Array, pruner: StaticPruner) -> float:
 
 @dataclasses.dataclass
 class IndexUpdater:
-    """Segmented pruned index + transform with live growth and compaction.
+    """Paged pruned index + transform with live growth and compaction.
 
-    ``index`` may be handed in as a bare ``DenseIndex``/``ShardedDenseIndex``
-    (it is wrapped as a single-base ``SegmentedIndex``) or as a segment set.
+    ``index`` may be handed in as a bare ``DenseIndex`` (it is paged as
+    one base extent, sealing deltas at ``delta_capacity`` rows) or as a
+    ``PagedIndex``.
 
     ``fit_energy`` may be left unset (a directly-constructed updater): the
     reference energy is then derived lazily from the fitted state — for an
@@ -100,13 +100,13 @@ class IndexUpdater:
 
     ``store``: an optional ``IndexStore`` (or path) the updater appends
     through — every delta mutation lands durably so the on-disk artifact
-    tracks the in-memory segments bit-for-bit. ``server``: an optional
-    ``RetrievalServer`` that receives the new segment set via
-    ``swap_index`` after every mutation.
+    tracks the in-memory extents bit-for-bit. ``server``: an optional
+    ``RetrievalServer`` that receives the new index via ``swap_index``
+    after every mutation.
     """
 
     pruner: StaticPruner
-    index: SegmentedIndex
+    index: PagedIndex
     fit_energy: float | None = None  # energy on the fit corpus (reference)
     store: object | None = None      # IndexStore | str | None
     server: object | None = None     # RetrievalServer | None
@@ -114,10 +114,9 @@ class IndexUpdater:
     # telemetry
     appended_rows: int = 0
     compactions: int = 0
-    # last compaction's cost receipt. Paged: {"pages_moved", "pages_freed",
+    # last compaction's cost receipt: {"pages_moved", "pages_freed",
     # "pages_host"} — pointer swaps, the true cost unit (a rows-copied
-    # number would claim O(corpus) work the paged path never does).
-    # Segmented streaming rebuild: {"rows_rebuilt"}.
+    # number would claim O(corpus) work compaction never does)
     last_compaction: dict | None = None
     # background-thread failures (compact_async and any future maintenance
     # thread): a swallowed exception is an operational lie — the fleet
@@ -132,50 +131,45 @@ class IndexUpdater:
         if isinstance(self.store, (str, bytes)) or hasattr(self.store,
                                                            "__fspath__"):
             self.store = IndexStore.open(self.store)
-        if isinstance(self.index, (DenseIndex, ShardedDenseIndex)):
-            self.index = SegmentedIndex.from_index(
-                self.index, delta_capacity=self.delta_capacity)
+        if isinstance(self.index, DenseIndex):
+            self.index = PagedIndex.from_index(
+                self.index, seal_rows=self.delta_capacity)
+        elif not isinstance(self.index, PagedIndex):
+            raise TypeError(
+                f"IndexUpdater needs a DenseIndex or PagedIndex, got "
+                f"{type(self.index).__name__}; appends over a sharded base "
+                f"need paged x sharded storage (ROADMAP queue 2 item 5)")
 
     @classmethod
     def build(cls, corpus: jax.Array, *, cutoff: float = 0.5,
               quantize_int8: bool = False,
               store_path: str | None = None,
-              delta_capacity: int = 4096, paged: bool = False,
-              page_rows: int = 256,
+              delta_capacity: int = 4096, page_rows: int = 256,
               pool_pages: int | None = None) -> "IndexUpdater":
         """Fit + build in memory; with ``store_path``, also persist the
         artifact and attach the committed store for durable appends.
-        ``paged=True`` serves through ``PagedIndex`` (pointer-swap
-        lifecycle; ``pool_pages`` below the corpus page count
-        oversubscribes device memory)."""
-        from repro.core.paged import PagedIndex
+        ``pool_pages`` below the corpus page count oversubscribes device
+        memory."""
         pruner = StaticPruner(cutoff=cutoff).fit(corpus)
         base = pruner.build_index(corpus, quantize_int8=quantize_int8)
-        if paged:
-            index = PagedIndex.from_index(base, page_rows=page_rows,
-                                          pool_pages=pool_pages,
-                                          seal_rows=delta_capacity)
-        else:
-            index = SegmentedIndex.from_index(base,
-                                              delta_capacity=delta_capacity)
+        index = PagedIndex.from_index(base, page_rows=page_rows,
+                                      pool_pages=pool_pages,
+                                      seal_rows=delta_capacity)
         store = None
         if store_path is not None:
             from repro.core.store import save_index
-            store = save_index(store_path, index if paged else base,
-                               pruner=pruner)
+            store = save_index(store_path, index, pruner=pruner)
         return cls(pruner=pruner, index=index,
                    fit_energy=captured_energy(corpus, pruner), store=store,
                    delta_capacity=delta_capacity)
 
     @classmethod
     def from_store(cls, store, *, backend: str = "jnp",
-                   mesh=None, delta_capacity: int = 4096,
-                   paged: bool | None = None,
+                   delta_capacity: int = 4096,
                    pool_pages: int | None = None) -> "IndexUpdater":
         """Rehydrate updater state from a committed artifact (cold start) —
-        base AND delta segments, each with its own scale. ``paged=None``
-        auto-detects: a store carrying the ``paged`` manifest block reloads
-        as a ``PagedIndex``.
+        base AND delta extents, each with its own scale
+        (``PagedIndex.load``).
 
         ``fit_energy`` stays lazy — the fit corpus is not in the store, and
         the eigenvalue identity gives the same reference.
@@ -183,15 +177,9 @@ class IndexUpdater:
         from repro.core.store import IndexStore
         if not isinstance(store, IndexStore):
             store = IndexStore.open(store)
-        if paged is None:
-            paged = "paged" in store.manifest
-        if paged:
-            from repro.core.paged import PagedIndex
-            index = PagedIndex.load(store, backend=backend,
-                                    pool_pages=pool_pages)
-        else:
-            index = SegmentedIndex.load(store, mesh=mesh, backend=backend,
-                                        delta_capacity=delta_capacity)
+        index = PagedIndex.load(store, backend=backend,
+                                pool_pages=pool_pages,
+                                seal_rows=delta_capacity)
         return cls(pruner=store.load_pruner(), index=index,
                    store=store, delta_capacity=delta_capacity)
 
@@ -199,12 +187,12 @@ class IndexUpdater:
     def add_documents(self, new_embs: jax.Array) -> int:
         """Rotate with the existing W_m and append to the open delta.
 
-        Copy-on-write: a NEW segment set is built, mirrored to the store
+        Copy-on-write: a NEW index is built, mirrored to the store
         (open/extend/widen ops with the exact quantised bytes), then
         installed into the attached server atomically. Nothing ever clips:
         an int8 delta's scale widens per-dim to fit every appended block
         (requantised from the exact f32 staging — the rewrite is bounded by
-        the open delta's capacity). Returns the number of rows appended.
+        ``seal_rows``). Returns the number of rows appended.
         """
         with self._lock:
             pruner = self.pruner
@@ -216,51 +204,41 @@ class IndexUpdater:
             self._mirror_ops(ops, new_index)
             self.index = new_index
             self.appended_rows += pruned.shape[0]
-            # swap INSIDE the lock: a preempted thread must not install a
-            # segment set an already-completed append/compaction superseded
+            # swap INSIDE the lock: a preempted thread must not install an
+            # index an already-completed append/compaction superseded
             if self.server is not None:
                 self.server.swap_index(new_index)
         return int(pruned.shape[0])
 
     def _mirror_ops(self, ops, new_index) -> None:
-        """Replay append ops durably. The op stream is identical for
-        segmented and paged indexes; only the delta-ordinal -> store-segment
-        mapping differs (paged: extents are segments positionally, with
-        base extents a prefix — delta ordinal di is extent/segment
-        ``n_base + di``). A paged mirror finishes with the lifecycle-block
-        swap, which may lag the segment ops across a crash (the loader
-        reconstructs; ``IndexStore._validate_paged``)."""
+        """Replay append ops durably. Extents are store segments
+        positionally, with base extents a prefix — delta ordinal di is
+        extent/segment ``n_base + di``. The mirror finishes with the
+        lifecycle-block swap, which may lag the segment ops across a crash
+        (the loader reconstructs; ``IndexStore._validate_paged``)."""
         if self.store is None:
             return
-        paged = hasattr(new_index, "storage")
-        if paged:
-            base_idx = sum(1 for e in new_index.storage.extents
-                           if e.kind == "base")
-            capacity = new_index.storage.seal_rows
-        else:
-            base_idx = 1
+        from repro.core.store import paged_manifest_block
+        st = new_index.storage
+        base_idx = sum(1 for e in st.extents if e.kind == "base")
         names = [v.name for v in self.store.segments()]
         for op in ops:
             kind, di = op[0], op[1]
-            seg_idx = base_idx + di                # store segment position
             if kind == "open":
                 _, _, stored, scale = op
-                cap = capacity if paged else new_index.deltas[di].capacity
-                name = self.store.add_delta(scale=scale, capacity=cap)
+                name = self.store.add_delta(scale=scale,
+                                            capacity=st.seal_rows)
                 names.append(name)
                 if stored.shape[0]:
                     self.store.append(stored, segment=name)
             elif kind == "extend":
                 _, _, stored = op
-                self.store.append(stored, segment=names[seg_idx])
+                self.store.append(stored, segment=names[base_idx + di])
             else:                                   # widen: bounded rewrite
                 _, _, stored, scale = op
-                self.store.replace_segment(names[seg_idx], [stored],
+                self.store.replace_segment(names[base_idx + di], [stored],
                                            scale=scale)
-        if paged:
-            from repro.core.store import paged_manifest_block
-            self.store.set_paged_state(
-                paged_manifest_block(new_index.storage))
+        self.store.set_paged_state(paged_manifest_block(st))
 
     # -- telemetry ---------------------------------------------------------
     @property
@@ -272,21 +250,16 @@ class IndexUpdater:
 
     @property
     def delta_fraction(self) -> float:
-        """Fraction of the corpus living outside the compacted base.
-
-        Paged index: counted in PAGES (``delta_pages / total_pages``), the
-        unit compaction actually pays in — pointer swaps per page. The old
-        rows-over-corpus ratio undercounted a delta of many part-filled
-        pages (the fleet auto-compaction controller then waited too long),
-        and a capacity-based ratio would overcount sealed-but-short
-        extents that cost nothing to promote."""
+        """Fraction of the index living outside the compacted base, counted
+        in PAGES (``delta_pages / total_pages``): the unit compaction pays
+        in — pointer swaps per page. A rows-over-corpus ratio undercounts
+        a delta of many part-filled pages (the fleet auto-compaction
+        controller then waits too long), and a capacity-based ratio would
+        overcount sealed-but-short extents that cost nothing to promote."""
         with self._lock:
             index = self.index
-        pages = getattr(index, "total_pages", None)
-        if pages is not None:
-            return index.delta_pages / pages if pages else 0.0
-        n = index.n
-        return index.delta_rows / n if n else 0.0
+        pages = index.total_pages
+        return index.delta_pages / pages if pages else 0.0
 
     def scale_divergence(self) -> float:
         """max over deltas of max-dim ratio (delta scale / base scale) —
@@ -294,13 +267,9 @@ class IndexUpdater:
         1.0 when unquantised or no deltas have widened past the base."""
         with self._lock:
             index = self.index
-        if hasattr(index, "storage"):             # paged: extents carry it
-            exts = index.storage.extents
-            base_scale = exts[0].scale if exts else None
-            dscales = [e.scale for e in exts if e.kind == "delta"]
-        else:
-            base_scale = index.base.scale
-            dscales = [d.scale for d in index.deltas]
+        exts = index.storage.extents
+        base_scale = exts[0].scale if exts else None
+        dscales = [e.scale for e in exts if e.kind == "delta"]
         if base_scale is None or not dscales:
             return 1.0
         b = np.asarray(base_scale, np.float64)
@@ -349,52 +318,19 @@ class IndexUpdater:
         return self.drift_score(new_embs) < threshold
 
     # -- compaction --------------------------------------------------------
-    def _iter_dequant_rows(self, index: SegmentedIndex, block_rows: int,
-                           store) -> "object":
-        """Stream base+delta rows as f32 blocks in global id order.
-
-        ``store`` is the caller's locked snapshot of ``self.store`` (or
-        None): the generator runs unlocked while appends mirror to the
-        live store, so it must never re-read the field mid-stream. With a
-        store the base streams from DISK (host O(block)); otherwise from
-        the device copy. Deltas stream from their exact f32 staging either
-        way.
-        """
-        if store is not None:
-            base_view = store.segments()[0]
-            scale = base_view.scale()
-            for lo in range(0, base_view.n, block_rows):
-                rows = base_view.read_rows(lo, min(lo + block_rows,
-                                                   base_view.n))
-                rows = rows.astype(np.float32)
-                if scale is not None:
-                    rows = rows * scale[None, :].astype(np.float32)
-                yield rows
-        else:
-            base = index.base
-            scale = (None if base.scale is None
-                     else np.asarray(base.scale, np.float32))
-            v = np.asarray(base.vectors[:base.n])
-            for lo in range(0, base.n, block_rows):
-                rows = v[lo:lo + block_rows].astype(np.float32)
-                if scale is not None:
-                    rows = rows * scale[None, :]
-                yield rows
-        for d in index.deltas:
-            for lo in range(0, d.n_real, block_rows):
-                yield d.raw[lo:lo + block_rows]
-
-    def _compact_paged(self) -> None:
-        """Paged compaction: seal + promote every delta extent and drain
-        tail pages into free pool slots — pointer swaps plus ONE fused
-        gather dispatch, never a corpus rebuild. Cheap enough to run
-        entirely under the lock (no racing-append reconcile needed); on
-        disk it is a single lifecycle-block manifest swap (the page bytes
-        already mirrored at append time)."""
+    def compact(self) -> None:
+        """Seal + promote every delta extent and drain tail pages into free
+        pool slots — pointer swaps plus ONE fused gather dispatch, never a
+        corpus rebuild, and every extent keeps its scale (``refit``
+        requantises). Cheap enough to run entirely under the lock, so no
+        racing append can interleave; on disk it is a single
+        lifecycle-block manifest swap (the page bytes already mirrored at
+        append time). The attached server receives the new index between
+        batches."""
+        from repro.core.store import paged_manifest_block
         with self._lock:
             new_index, stats = self.index.compact_pages()
             if self.store is not None:
-                from repro.core.store import paged_manifest_block
                 self.store.set_paged_state(
                     paged_manifest_block(new_index.storage))
             self.index = new_index
@@ -403,87 +339,9 @@ class IndexUpdater:
             if self.server is not None:
                 self.server.swap_index(new_index)
 
-    def compact(self, *, block_rows: int = 65536) -> None:
-        """Merge base + deltas into ONE fresh base segment and swap it in.
-
-        The rotation (``W_m``) is unchanged — compaction re-homogenises the
-        quantisation: a single fresh corpus-wide scale replaces the base's
-        frozen scale and every widened delta scale. Rows stream through
-        ``StaticPruner.build_index_to(already_projected=True)`` (O(block)
-        host memory, int8 spill). With a store attached the new artifact
-        builds UNLOCKED at a sidecar path (``<path>.compact`` — appends
-        keep mirroring to the live store meanwhile) and only the final
-        directory swap into the live path (``commit_dir`` rename-aside — a
-        crash leaves the old or the new artifact, never neither) happens
-        under the updater lock, so no append mirror can interleave with the
-        replacement and scribble a stale manifest over the fresh artifact.
-        The attached server receives the new segment set between batches.
-        Appends racing a background compaction are reconciled: rows landed
-        after the snapshot re-append onto the fresh base before the swap.
-        """
-        with self._lock:
-            snapshot, pruner = self.index, self.pruner
-            store, n_compactions = self.store, self.compactions
-        if hasattr(snapshot, "compact_pages"):
-            self._compact_paged()
-            return
-        quant = snapshot.quantized
-        mesh = getattr(snapshot.base, "mesh", None)
-        backend = snapshot.base.backend
-        if store is not None:
-            from repro.checkpoint.manager import commit_dir
-            from repro.core.store import IndexStore
-            side_path = store.path + ".compact"
-            side = pruner.build_index_to(
-                side_path,
-                lambda: self._iter_dequant_rows(snapshot, block_rows, store),
-                quantize_int8=quant, already_projected=True,
-                meta={"compactions": n_compactions + 1})
-            # the base's device arrays materialise from the sidecar BEFORE
-            # the lock: the expensive load never blocks appends
-            if mesh is not None:
-                base = ShardedDenseIndex.load(side, mesh, backend=backend,
-                                              merge=snapshot.base.merge)
-            else:
-                base = DenseIndex.load(side, backend=backend)
-        else:
-            side_path = None
-            rows = np.concatenate(
-                list(self._iter_dequant_rows(snapshot, block_rows, None)))
-            if mesh is not None:
-                base = ShardedDenseIndex.build(jnp.asarray(rows), mesh,
-                                               quantize_int8=quant,
-                                               backend=backend,
-                                               merge=snapshot.base.merge)
-            else:
-                base = DenseIndex.build(jnp.asarray(rows),
-                                        quantize_int8=quant, backend=backend)
-        fresh = SegmentedIndex.from_index(base,
-                                          delta_capacity=self.delta_capacity)
-        with self._lock:
-            if side_path is not None:
-                commit_dir(side_path, self.store.path)   # atomic retire
-                self.store = IndexStore.open(self.store.path)
-            # reconcile rows appended while the compaction streamed: the
-            # current segment set extends the snapshot row-for-row, so the
-            # tail [snapshot.n:) is exactly the racing appends
-            tail = []
-            for d in self.index.deltas:
-                tail.append(d.raw)
-            tail_rows = (np.concatenate(tail)[snapshot.delta_rows:]
-                         if tail else np.zeros((0, snapshot.dim), np.float32))
-            if tail_rows.shape[0]:
-                fresh, ops = fresh.append_with_ops(tail_rows)
-                self._mirror_ops(ops, fresh)
-            self.index = fresh
-            self.compactions += 1
-            self.last_compaction = {"rows_rebuilt": int(fresh.n)}
-            if self.server is not None:
-                self.server.swap_index(fresh)
-
-    def compact_async(self, **kw) -> threading.Thread:
+    def compact_async(self) -> threading.Thread:
         """Run ``compact`` off-thread: the serving path keeps dispatching
-        against the old segment set until the finished base swaps in.
+        against the old index until the compacted one swaps in.
 
         A crash in the background thread is RECORDED, not swallowed: the
         exception lands in ``background_errors`` (read by ``health()`` and
@@ -493,7 +351,7 @@ class IndexUpdater:
         def _run():
             import time as _time
             try:
-                self.compact(**kw)
+                self.compact()
             except BaseException as e:   # noqa: BLE001 — recorded, re-raised
                 with self._lock:
                     self.background_errors.append(
@@ -519,35 +377,19 @@ class IndexUpdater:
 
     def refit(self, corpus: jax.Array) -> None:
         """Full offline refit (new rotation) on the current corpus
-        distribution — unlike ``compact``, this re-fits ``W_m`` itself.
-        The base keeps its layout: a sharded base refits onto the same
-        mesh/merge/backend instead of collapsing onto one device."""
+        distribution — unlike ``compact``, this re-fits ``W_m`` itself and
+        requantises under one fresh corpus-wide scale. The new index keeps
+        the old one's page geometry, backend and pipeline depth."""
         with self._lock:
             old_index, old_pruner = self.index, self.pruner
-        cutoff = old_pruner.effective_cutoff
-        quant = old_index.quantized
-        paged = hasattr(old_index, "storage")
-        old_base = getattr(old_index, "base", None)
-        mesh = getattr(old_base, "mesh", None)
-        backend = old_index.backend if paged else old_base.backend
-        pruner = StaticPruner(cutoff=cutoff).fit(corpus)
-        if mesh is not None:
-            base = ShardedDenseIndex.build(
-                pruner.prune_index(corpus), mesh, quantize_int8=quant,
-                backend=backend, merge=old_base.merge)
-        else:
-            base = pruner.build_index(corpus, quantize_int8=quant,
-                                      backend=backend)
-        if paged:
-            from repro.core.paged import PagedIndex
-            new_index = PagedIndex.from_index(
-                base, page_rows=old_index.storage.page_rows,
-                seal_rows=old_index.storage.seal_rows,
-                backend=backend, depth=old_index.depth,
-                wave_pages=old_index.wave_pages)
-        else:
-            new_index = SegmentedIndex.from_index(
-                base, delta_capacity=self.delta_capacity)
+        pruner = StaticPruner(cutoff=old_pruner.effective_cutoff).fit(corpus)
+        base = pruner.build_index(corpus, quantize_int8=old_index.quantized,
+                                  backend=old_index.backend)
+        new_index = PagedIndex.from_index(
+            base, page_rows=old_index.storage.page_rows,
+            seal_rows=old_index.storage.seal_rows,
+            backend=old_index.backend, depth=old_index.depth,
+            wave_pages=old_index.wave_pages)
         energy = captured_energy(corpus, pruner)
         with self._lock:
             self.pruner, self.index, self.fit_energy = (pruner, new_index,
@@ -557,10 +399,8 @@ class IndexUpdater:
                 # the old artifact is invalid under the new rotation —
                 # replace it atomically at the same path
                 from repro.core.store import save_index
-                self.store = save_index(
-                    self.store.path,
-                    self.index if paged else self.index.base,
-                    pruner=self.pruner)
+                self.store = save_index(self.store.path, self.index,
+                                        pruner=self.pruner)
             if self.server is not None:
                 self.server.swap_index(self.index, pruner=self.pruner)
 

@@ -1,45 +1,45 @@
-"""Paged index memory: fixed-size pages behind an int32 indirection table.
+"""Paged index memory: the mutable index, in fixed-size pages behind an
+int32 indirection table.
 
-The segmented live index (``core/index.py``) rebuilds whole arrays on delta
-promotion/compaction and dispatches one computation per segment, so the
-segment COUNT leaks into compiled shapes. ``PagedIndexStorage`` stores the
-same rows as fixed ``page_rows``-row pages addressed through a page table:
+``PagedIndexStorage`` stores the rows as fixed ``page_rows``-row pages
+addressed through a page table, in three tiers:
 
   * ``pool``  — the stable device tier (promoted/compacted pages). Written
                 only by the one-dispatch compaction drain; searches stream
                 straight out of it.
-  * ``tail``  — a small device write arena absorbing live appends (the
-                delta role). O(tail) copy-on-write per append, like a
-                delta's ``dynamic_update_slice`` — never O(index).
+  * ``tail``  — a small device write arena absorbing live appends. O(tail)
+                copy-on-write per append — never O(index).
   * host tier — pages whose table entry is -1 live as host ``np`` arrays
                 and stream on demand in bounded waves, so the index may
                 exceed device memory (oversubscription).
 
 Logical slots are contiguous per extent (base extents first, then deltas,
-ascending global-id order). Every lifecycle step is a page-pointer swap:
+ascending global-id order). Each extent carries its own int8 scale: an
+extent opens with a fresh per-dim scale fitted to its first rows, and an
+append that would clip widens it (``max(old, need)``) and requantises the
+extent from its exact f32 staging — bounded by ``seal_rows``, so nothing
+ever clips. Every lifecycle step is a page-pointer swap:
 
   append   — write rows into tail pages, grow the open delta extent;
   seal     — the open extent freezes at ``seal_rows`` rows (metadata);
   promote  — sealed delta extents become base extents (metadata only);
   compact  — promoted tail pages drain into free pool slots in ONE fused
              gather dispatch (``_pool_drain``) + a pointer swap — no
-             requantisation, no index rebuild;
+             requantisation, no index rebuild (``IndexUpdater.refit``
+             requantises);
   evict    — device pages move to the host tier (pointer swap + host copy).
 
 Search walks slots ``[lo, hi)`` with *traced* bounds over fixed-shape
 arrays, so appends/seals/promotions/compactions/evictions never recompile.
 An oversubscribed index splits into device/host runs chained through a
-top-k carry; visit order stays ascending-slot, preserving the exact
-lowest-id tie-break (and the skip-on-equality guard) of the segmented
-path. Backends: 'jnp' (``lax.scan`` page walk) or 'pallas'
-(``topk_score_paged_pallas`` — double-buffered ``make_async_copy`` DMA
-pipeline prefetching page i+depth-1 while scoring page i).
-
-Bit-parity: quantised bytes, per-extent scale evolution (fresh scale per
-delta, widen = requantise from exact f32 staging), projection and fold
-order, merge structure, and tie-breaks all mirror ``SegmentedIndex`` —
-searches over equal contents are bit-identical across dense × f32/int8 ×
-jnp/pallas, including the cascade rescore (pinned by tests/test_paged.py).
+top-k carry; visit order stays ascending-slot, so ties resolve to the
+lowest global id and the skip-on-equality guard stays exact. Backends:
+'jnp' (``lax.scan`` page walk) or 'pallas' (``topk_score_paged_pallas`` —
+double-buffered ``make_async_copy`` DMA pipeline prefetching page
+i+depth-1 while scoring page i). Both fold each page's extent scale into
+the query and score it with ``score_rows``, so they agree bit for bit, and
+promotion, compaction, eviction and a store round trip never move a
+result (pinned by tests/test_paged.py).
 """
 from __future__ import annotations
 
@@ -52,8 +52,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.index import (Backend, DenseIndex, SegmentedIndex,
-                              _project_nofold, _topk_merge, project_queries)
+from repro.core.index import (Backend, DenseIndex, _project_nofold,
+                              _topk_merge, project_queries)
 from repro.kernels.topk_score import query_terms, score_rows
 
 
@@ -63,7 +63,8 @@ class PageExtent(NamedTuple):
     row_offset + n_rows)``. ``scale`` is the extent's int8 dequant scale
     (replicated into the per-page scale rows); ``raw`` is the exact f32
     staging kept only while a delta extent is open (the requant source
-    when an append widens the scale — same contract as ``DeltaSegment``).
+    when an append widens the scale; after a load, the dequantised
+    reconstruction — the best source that survives a restart).
     """
 
     kind: str                    # "base" | "delta"
@@ -177,8 +178,7 @@ def _paged_search_projected(pool, tail, pt, scale, nv, off, lo, hi, W, mean,
                             guard: str = "row"):
     """The serving hot path: projection + page walk in ONE dispatch (the
     paged analogue of ``_dense_search_projected``). No scale fold at
-    projection — per-page scales fold inside the walk, exactly like the
-    segmented per-segment fold, so results stay bit-identical."""
+    projection — each page's extent scale folds inside the walk."""
     q = project_queries(jnp.atleast_2d(Q), W, scale=None, mean=mean)
     return _dispatch_topk(pool, tail, pt, scale, nv, off, lo, hi, q, k,
                           backend, depth, guard, None, True)
@@ -189,11 +189,10 @@ def _paged_rescore(pool, tail, pt, scale, nv, off, lo, hi, qf, uids, acc):
     """Cascade rescore over pages: max-combine each page's contribution to
     the shared shortlist into ``acc`` (B, U).
 
-    Per page this is exactly ``_segment_rescore`` — fold the extent scale
-    into q, gather shortlist rows in storage dtype, one (B,m)×(m,U)
-    matmul, -inf outside the page — and each live uid falls in exactly one
-    page, so the elementwise max equals the segmented parts-combine
-    bitwise (``_cascade_select`` invariant).
+    Per page: fold the extent scale into q, gather shortlist rows in
+    storage dtype, one (B,m)×(m,U) matmul, -inf outside the page. Each
+    live uid falls in exactly one page, so the elementwise max is that
+    uid's one exact score.
     """
     pool_pages, R, m = pool.shape
     table_cap = pt.shape[0]
@@ -221,12 +220,13 @@ def _paged_rescore(pool, tail, pt, scale, nv, off, lo, hi, qf, uids, acc):
 
 
 def _jit_cache_sizes() -> dict:
-    """Compiled-variant counts of every paged-search jit, merged into
-    ``repro.core.index.segment_jit_cache_sizes`` for recompile soaks."""
+    """Compiled-variant counts of every jit a paged search or lifecycle
+    step can touch — the recompile soaks pin these to zero growth (a
+    failure names the function that recompiled)."""
     from repro.kernels.topk_score import topk_score_paged_pallas
     sizes = {fn.__wrapped__.__name__: fn._cache_size()
              for fn in (_paged_topk, _paged_search_projected, _paged_rescore,
-                        _pool_drain)}
+                        _pool_drain, _project_nofold)}
     sizes["topk_score_paged_pallas"] = topk_score_paged_pallas._cache_size()
     return sizes
 
@@ -422,18 +422,23 @@ class PagedIndexStorage:
 
     # -- construction --------------------------------------------------------
     @classmethod
-    def from_index(cls, base: DenseIndex, *, page_rows: int = 256,
-                   pool_pages: int | None = None,
-                   tail_pages: int | None = None,
-                   table_cap: int | None = None,
-                   seal_rows: int = 4096) -> "PagedIndexStorage":
-        """Page an immutable base index. ``pool_pages`` below the base's
+    def from_index(cls, base: DenseIndex, **kw) -> "PagedIndexStorage":
+        """Page an immutable base index (see ``from_rows``)."""
+        return cls.from_rows(np.asarray(base.vectors),
+                             None if base.scale is None
+                             else np.asarray(base.scale, np.float32), **kw)
+
+    @classmethod
+    def from_rows(cls, vec: np.ndarray, scale: np.ndarray | None, *,
+                  page_rows: int = 256, pool_pages: int | None = None,
+                  tail_pages: int | None = None,
+                  table_cap: int | None = None,
+                  seal_rows: int = 4096) -> "PagedIndexStorage":
+        """Page stored rows (storage dtype, ``scale`` their int8 dequant
+        scale or None) as one base extent. ``pool_pages`` below the rows'
         page count oversubscribes at construction: the overflow suffix
         lives on the host tier and streams at search time."""
         R = page_rows
-        vec = np.asarray(base.vectors)
-        scale = (None if base.scale is None
-                 else np.asarray(base.scale, np.float32))
         n, m = vec.shape
         npages = -(-n // R) if n else 0
         if tail_pages is None:
@@ -480,42 +485,11 @@ class PagedIndexStorage:
             free_tail=tuple(range(tail_pages)), page_rows=R,
             seal_rows=seal_rows)
 
-    @classmethod
-    def from_segmented(cls, seg: SegmentedIndex, *, page_rows: int = 256,
-                       pool_pages: int | None = None,
-                       tail_pages: int | None = None,
-                       table_cap: int | None = None) -> "PagedIndexStorage":
-        """Convert a live segmented index byte-for-byte: the base pages
-        into the pool, each delta becomes a delta extent in the tail with
-        its own scale (and its exact f32 staging when still open), and
-        ``seal_rows`` adopts the delta capacity — continued appends evolve
-        scales exactly like the segmented path would have."""
-        if not isinstance(seg.base, DenseIndex):
-            raise TypeError("PagedIndexStorage.from_segmented needs a "
-                            "DenseIndex base — page the sharded artifact "
-                            "per shard instead")
-        R = page_rows
-        need_tail = sum(-(-d.capacity // R) for d in seg.deltas)
-        if tail_pages is None:
-            tail_pages = max(2 * (-(-seg.delta_capacity // R)),
-                             need_tail + (-(-seg.delta_capacity // R)), 2)
-        st = cls.from_index(seg.base, page_rows=R, pool_pages=pool_pages,
-                            tail_pages=tail_pages, table_cap=table_cap,
-                            seal_rows=seg.delta_capacity)
-        for di, d in enumerate(seg.deltas):
-            stored = np.asarray(d.vectors[:d.n_real])
-            dscale = None if d.scale is None else np.asarray(d.scale,
-                                                             np.float32)
-            sealed = d.n_real >= d.capacity
-            st = st._adopt_extent(stored, dscale,
-                                  raw=None if sealed else d.raw,
-                                  sealed=sealed)
-        return st
-
-    def _adopt_extent(self, stored: np.ndarray, scale: np.ndarray | None,
-                      *, raw: np.ndarray | None,
-                      sealed: bool) -> "PagedIndexStorage":
-        """Append a whole pre-quantised extent (segmented-delta adoption)."""
+    def _adopt_extent(self, stored: np.ndarray, scale: np.ndarray | None
+                      ) -> "PagedIndexStorage":
+        """Append a whole pre-quantised extent (a stored segment, on load)
+        into the writable tiers, with its bytes and scale as they are, as
+        a sealed delta (``with_lifecycle`` sets what the store recorded)."""
         mut = _Mut(self)
         R = self.page_rows
         start_slot = self.n_slots
@@ -528,9 +502,27 @@ class PagedIndexStorage:
             mut.write_rows(slot, 0, stored[pi * R:(pi + 1) * R])
             if scale is not None:
                 mut.set_scale(slot, scale)
-        mut.extents.append(PageExtent("delta", sealed, start_slot, npages,
-                                      n, row_offset, scale, raw))
+        mut.extents.append(PageExtent("delta", True, start_slot, npages,
+                                      n, row_offset, scale, None))
         return mut.freeze()
+
+    def with_lifecycle(self, states) -> "PagedIndexStorage":
+        """Set each extent's ``(kind, sealed)``; an unsealed extent gets its
+        dequantised bytes as requant staging ``raw`` — the best source
+        that survives a restart."""
+        exts = tuple(
+            e._replace(kind=kind, sealed=sealed,
+                       raw=None if sealed else self.extent_f32(i))
+            for i, (e, (kind, sealed)) in enumerate(zip(self.extents,
+                                                        states)))
+        return dataclasses.replace(self, extents=exts)
+
+    def extent_f32(self, ei: int) -> np.ndarray:
+        """One extent's rows dequantised to f32 (its bytes times its
+        scale)."""
+        rows = self.extent_rows(ei).astype(np.float32)
+        sc = self.extents[ei].scale
+        return rows if sc is None else rows * sc[None, :].astype(np.float32)
 
     def extent_rows(self, ei: int) -> np.ndarray:
         """One extent's stored bytes in global-id order, gathered off
@@ -562,13 +554,18 @@ class PagedIndexStorage:
         """Append f32 rows; page-pointer swaps only — no array rebuilds.
 
         Rows land in the open delta extent (tail-tier pages; host-tier
-        once the arena is full) which seals at ``seal_rows``. Scale
-        evolution is ``DeltaSegment``'s exactly: a fresh per-dim scale per
-        extent, widen = ``max(old, need)`` + requantise the extent from
-        its exact f32 staging. Emits the same op stream as
-        ``SegmentedIndex.append_with_ops`` (("open"|"extend"|"widen"),
-        delta-ordinal, stored bytes[, scale]) so durable mirrors carry
-        over unchanged — disk and memory stay bit-identical.
+        once the arena is full) which seals at ``seal_rows``. Scales: a
+        fresh per-dim scale per extent, widen = ``max(old, need)`` +
+        requantise the extent from its exact f32 staging. Returns the op
+        stream a durable mirror replays (``IndexUpdater._mirror_ops``), in
+        order:
+          ("open",   di, stored_rows, scale)  — new delta with first rows
+          ("extend", di, stored_rows)         — rows appended, scale kept
+          ("widen",  di, stored_all,  scale)  — scale widened: the delta's
+                                                full requantised contents
+        ``di`` is the delta's ordinal among delta extents; ``stored_*``
+        are in storage dtype, exactly the bytes served — disk and memory
+        stay bit-identical.
         """
         from repro.core.quantization import quantize_with_scale, scale_for
         rows = np.atleast_2d(np.asarray(rows, np.float32))
@@ -756,10 +753,12 @@ class PagedIndexStorage:
 
 @dataclasses.dataclass(frozen=True, eq=False)
 class PagedIndex:
-    """Search facade over ``PagedIndexStorage`` — the drop-in paged
-    replacement for ``SegmentedIndex`` in serving (same ``search`` /
-    ``search_projected`` / ``append`` surface, same copy-on-write swap
-    discipline, bit-identical results at equal contents).
+    """Search facade over ``PagedIndexStorage`` — the mutable index the
+    server swaps. Every mutation (``append``, ``promote``,
+    ``compact_pages``, ``evict``) returns a NEW index sharing untouched
+    arrays: the running ``RetrievalServer`` swaps whole indexes between
+    batches, and in-flight batches keep the old one alive until their
+    replies post.
 
     ``depth`` is the DMA pipeline depth (pallas: page i+depth-1 prefetches
     while page i scores; jnp: host-wave staging lookahead). ``wave_pages``
@@ -795,10 +794,6 @@ class PagedIndex:
         return self.storage.pool.dtype
 
     @property
-    def delta_rows(self) -> int:
-        return self.storage.delta_rows
-
-    @property
     def delta_pages(self) -> int:
         return self.storage.delta_pages
 
@@ -821,96 +816,85 @@ class PagedIndex:
                    backend=base.backend if backend is None else backend,
                    depth=depth, wave_pages=wave_pages)
 
-    @classmethod
-    def from_segmented(cls, seg: SegmentedIndex, *, page_rows: int = 256,
-                       pool_pages: int | None = None,
-                       tail_pages: int | None = None,
-                       table_cap: int | None = None,
-                       backend: Backend | None = None, depth: int = 2,
-                       wave_pages: int = 8) -> "PagedIndex":
-        st = PagedIndexStorage.from_segmented(
-            seg, page_rows=page_rows, pool_pages=pool_pages,
-            tail_pages=tail_pages, table_cap=table_cap)
-        if backend is None:
-            backend = getattr(seg.base, "backend", "jnp")
-        return cls(storage=st, backend=backend, depth=depth,
-                   wave_pages=wave_pages)
-
     # -- persistence ---------------------------------------------------------
     @classmethod
     def load(cls, store, *, page_rows: int | None = None,
              pool_pages: int | None = None, tail_pages: int | None = None,
-             table_cap: int | None = None, seal_rows: int | None = None,
+             table_cap: int | None = None, seal_rows: int = 4096,
              backend: Backend = "jnp", depth: int = 2,
              wave_pages: int = 8) -> "PagedIndex":
         """Rehydrate from an on-disk artifact bit-for-bit.
 
-        A store written by ``save_paged_index`` carries the ``paged``
-        manifest block (page geometry + extent lifecycle); extent i's
-        bytes are segment i's bytes, so the load reuses the segmented
-        rehydration, then re-applies the recorded extent kinds. The block
-        may LAG the segments (crash between the append mirror's two
-        manifest swaps): missing trailing extents reload as deltas, and
-        sealed-ness is reconstructed conservatively (every non-last extent
-        is sealed; the last one by row count or the fresh block entry).
-        A plain segmented store (no block) pages directly — the migration
-        path. ``pool_pages`` below the resident page count oversubscribes:
-        the overflow streams from the host tier at search time.
+        Extent i is store segment i, adopted with its stored bytes and
+        scale as they are: segment 0 pages into the pool as the base
+        extent, every later segment into the writable tiers (tail, then
+        host). When the ``paged`` manifest block records extent 0 as a
+        delta (an index grown from empty), segment 0 is adopted like the
+        rest — pool pages reject writes, so an open extent must never
+        land there. The block (page geometry + extent lifecycle) may LAG
+        the segments (crash between the append mirror's two manifest
+        swaps): missing trailing extents reload as deltas, and sealed-ness
+        is reconstructed conservatively (every non-last extent is sealed;
+        the last one by row count or the fresh block entry). Without a
+        block (a flat artifact, or bare delta segments) a delta is sealed
+        once it holds its stored capacity. An unsealed extent's requant
+        staging ``raw`` is the dequantised reconstruction of its bytes.
+        ``pool_pages`` below the resident page count oversubscribes: the
+        overflow streams from the host tier at search time.
+
+        The seal size is the store's: the ``paged`` block's ``seal_rows``,
+        else the last delta segment's recorded capacity, so the open
+        extent never takes more rows than its segment may hold.
+        ``seal_rows`` applies only to a flat store.
         """
         import os
         from repro.core.store import IndexStore
         if isinstance(store, (str, os.PathLike)):
             store = IndexStore.open(store)
         pb = store.manifest.get("paged")
+        views = store.segments()
+        caps = [v.capacity for v in views if v.kind == "delta"]
         if pb is not None:
-            R = int(pb["page_rows"]) if page_rows is None else page_rows
-            S = int(pb["seal_rows"]) if seal_rows is None else seal_rows
+            S = int(pb["seal_rows"])
+        elif caps and caps[-1]:
+            S = int(caps[-1])
         else:
-            R = 256 if page_rows is None else page_rows
-            S = 4096 if seal_rows is None else seal_rows
-        if pb is not None and pb["extents"] \
-                and pb["extents"][0]["kind"] == "delta":
-            # extent 0 is itself a delta (an index grown from empty): adopt
-            # every segment through the writable tiers (tail/host) over a
-            # zero-row base — pool pages reject writes, so an open extent
-            # must never land there
-            import types
-            views = store.segments()
-            shim = types.SimpleNamespace(
-                vectors=np.zeros((0, store.dim), store.dtype),
-                scale=views[0].scale())
-            st = PagedIndexStorage.from_index(
-                shim, page_rows=R, pool_pages=pool_pages,
-                tail_pages=tail_pages, table_cap=table_cap, seal_rows=S)
-            for v in views:
-                st = st._adopt_extent(v.read_rows(0, v.n), v.scale(),
-                                      raw=None, sealed=True)
+            S = seal_rows
+        if page_rows is None:
+            R = 256 if pb is None else int(pb["page_rows"])
         else:
-            seg = SegmentedIndex.load(store, backend=backend,
-                                      delta_capacity=S)
-            st = PagedIndexStorage.from_segmented(
-                seg, page_rows=R, pool_pages=pool_pages,
-                tail_pages=tail_pages, table_cap=table_cap)
-            st = dataclasses.replace(st, seal_rows=S)
-        if pb is not None and st.extents:
-            pbe = pb["extents"]
-            exts = list(st.extents)
-            for i, ext in enumerate(exts):
-                kind = pbe[i]["kind"] if i < len(pbe) else "delta"
-                fresh = (i < len(pbe)
-                         and int(pbe[i]["n"]) == ext.n_rows)
-                sealed = (i < len(exts) - 1 or ext.n_rows >= S
-                          or (fresh and bool(pbe[i]["sealed"])))
-                raw = ext.raw
-                if not sealed and raw is None:
-                    stored = st.extent_rows(i).astype(np.float32)
-                    raw = (stored if ext.scale is None else
-                           stored * ext.scale[None, :].astype(np.float32))
-                exts[i] = ext._replace(kind=kind, sealed=sealed,
-                                       raw=None if sealed else raw)
-            st = dataclasses.replace(st, extents=tuple(exts))
-        return cls(storage=st, backend=backend, depth=depth,
-                   wave_pages=wave_pages)
+            R = page_rows
+        pbe = None if pb is None else pb["extents"]
+        first = 1 if not pbe or pbe[0]["kind"] != "delta" else 0
+        adopt = views[first:]
+        if tail_pages is None:
+            seal_pages = -(-S // R)
+            need = sum(-(-(v.capacity or max(S, v.n)) // R) for v in adopt)
+            tail_pages = max(2 * seal_pages, need + seal_pages, 2)
+
+        def scale_of(v):
+            sc = v.scale()
+            return None if sc is None else np.asarray(sc, np.float32)
+
+        base = views[0].read_rows(0, views[0].n) if first else \
+            np.zeros((0, store.dim), store.dtype)
+        st = PagedIndexStorage.from_rows(
+            base, scale_of(views[0]), page_rows=R, pool_pages=pool_pages,
+            tail_pages=tail_pages, table_cap=table_cap, seal_rows=S)
+        states = [("base", True)] * len(st.extents)
+        for v in adopt:
+            st = st._adopt_extent(v.read_rows(0, v.n), scale_of(v))
+            states.append(("delta", v.n >= (v.capacity or max(S, v.n))))
+        if pbe is not None:
+            last = len(states) - 1
+            states = [
+                (pbe[i]["kind"] if i < len(pbe) else "delta",
+                 i < last or e.n_rows >= S
+                 or (i < len(pbe) and int(pbe[i]["n"]) == e.n_rows
+                     and bool(pbe[i]["sealed"])))
+                for i, e in enumerate(st.extents)]
+        return cls(storage=st.with_lifecycle(states), backend=backend,
+                   depth=depth, wave_pages=wave_pages)
 
     def save(self, path: str, *, pruner=None, meta: dict | None = None
              ) -> "object":
@@ -1052,7 +1036,7 @@ class PagedIndex:
     def rescore(self, qf: jax.Array, uids: jax.Array) -> jax.Array:
         """(B, U) exact shortlist scores (cascade second stage): device
         runs rescore off the pools, host runs stream waves; max-combined
-        per page — bitwise the segmented parts-combine at equal bytes."""
+        per page."""
         acc = jnp.full((qf.shape[0], uids.shape[0]), -jnp.inf, jnp.float32)
         for lo, hi, dev in self._runs():
             if dev:

@@ -30,15 +30,16 @@ blob the manifest never names — still a valid store.
 segment 0 is the immutable base (the offline PCA-pruned artifact), later
 entries are growable *delta* segments, each with the chunked-blob format
 above plus its OWN ``scale_file`` (per-segment int8 scale — the fix for
-the frozen-scale clip problem) and a ``capacity`` (the fixed padded shape
-deltas dispatch at). The top-level ``n``/``chunks``/``scale_file`` fields
+the frozen-scale clip problem) and a ``capacity`` (the rows at which the
+delta seals). A ``PagedIndex`` store maps extent i to segment i and adds
+a ``paged`` block (page geometry + extent lifecycle). The top-level ``n``/``chunks``/``scale_file`` fields
 are always the derived global view (total rows, all chunks in id order,
 the base's scale), so a pre-segment manifest IS a valid single-base
 segmented store — ``IndexStore.open`` on an old artifact exposes exactly
 one base segment, and old artifacts round-trip untouched. Segment
 mutations (``add_delta`` / ``append`` / ``replace_segment``) all follow
-the blob-then-manifest-swap protocol; whole-store replacement (compaction
-building a fresh base) reuses ``checkpoint.manager.commit_dir``.
+the blob-then-manifest-swap protocol; whole-store replacement (a refit
+re-saving at the same path) reuses ``checkpoint.manager.commit_dir``.
 
 **Resolutions.** A manifest may also carry a ``resolutions`` list: extra
 *coarse* views of the SAME rows at a smaller width — the leading
@@ -46,7 +47,7 @@ building a fresh base) reuses ``checkpoint.manager.commit_dir``.
 usually re-quantised int8 with their own scale. Each entry reuses the
 chunked-blob layout (``chunks`` + optional ``scale_file`` + its own
 ``dtype``) and covers exactly the immutable BASE segment's rows. A
-segmented cascade's coarse DELTA segments may ride along in the entry's
+paged cascade's coarse DELTA extents may ride along in the entry's
 ``deltas`` list (same per-delta layout as the main segments: exact
 quantised rows + own scale + capacity), so a reload serves the very bytes
 that were serving before instead of requantising from the full deltas;
@@ -105,19 +106,17 @@ def save_index(path: str, index, *, pruner=None, meta: dict | None = None,
     """
     import numpy as _np
     from repro.core.cascade import CascadeIndex
-    from repro.core.index import SegmentedIndex
     from repro.core.paged import PagedIndex
     if isinstance(index, PagedIndex):
         return save_paged_index(path, index, pruner=pruner, meta=meta,
                                 chunk_rows=chunk_rows)
     if isinstance(index, CascadeIndex):
-        # full resolution commits through the normal (possibly segmented)
+        # full resolution commits through the normal (possibly paged)
         # path; the coarse base rides along as a `resolutions` entry, so
         # one artifact round-trips the whole cascade via CascadeIndex.load.
-        # A segmented coarse side persists its delta segments too (exact
-        # quantised bytes + per-delta scales), so a segmented load
-        # rehydrates them bit-for-bit instead of requantising from the
-        # full deltas.
+        # A paged coarse side persists its delta extents too (exact
+        # quantised bytes + per-extent scales), so a load adopts them
+        # bit-for-bit instead of requantising from the full deltas.
         store = save_index(path, index.full, pruner=pruner, meta=meta,
                            chunk_rows=chunk_rows)
         coarse = index.coarse
@@ -136,31 +135,13 @@ def save_index(path: str, index, *, pruner=None, meta: dict | None = None,
                  "capacity": cst.seal_rows}
                 for i, e in enumerate(exts) if i > 0]
         else:
-            coarse_base = getattr(coarse, "base", coarse)
-            base_rows = _np.asarray(coarse_base.vectors[:coarse_base.n])
-            base_scale = coarse_base.scale
-            coarse_deltas = [
-                {"rows": _np.asarray(d.vectors[:d.n_real]),
-                 "scale": None if d.scale is None else _np.asarray(d.scale),
-                 "capacity": d.capacity}
-                for d in getattr(coarse, "deltas", ())]
+            base_rows = _np.asarray(coarse.vectors[:coarse.n])
+            base_scale = coarse.scale
+            coarse_deltas = []
         store.add_resolution(
             base_rows,
             scale=None if base_scale is None else _np.asarray(base_scale),
             chunk_rows=chunk_rows, deltas=coarse_deltas)
-        return store
-    if isinstance(index, SegmentedIndex):
-        # base commits through the normal path, then each delta is replayed
-        # as a durable segment mutation — the artifact round-trips through
-        # SegmentedIndex.load with every per-segment scale intact
-        store = save_index(path, index.base, pruner=pruner, meta=meta,
-                           chunk_rows=chunk_rows)
-        for d in index.deltas:
-            name = store.add_delta(
-                scale=None if d.scale is None else _np.asarray(d.scale),
-                capacity=d.capacity)
-            if d.n_real:
-                store.append(_np.asarray(d.vectors[:d.n_real]), segment=name)
         return store
     writer = IndexStoreWriter(path)
     with writer:
@@ -307,8 +288,8 @@ class SegmentView:
     Duck-types the slice of the ``IndexStore`` read API the index loaders
     use (``n``/``dim``/``dtype``/``iter_chunks``/``read_rows``/``scale``),
     so ``DenseIndex.load`` / ``ShardedDenseIndex.load`` work unchanged on a
-    single segment — that is how ``SegmentedIndex.load`` assembles its
-    base. Row indices are segment-local; ``offset`` is the segment's
+    single segment (a cascade's coarse resolution loads this way). Row
+    indices are segment-local; ``offset`` is the segment's
     global doc-id base.
     """
 
@@ -758,12 +739,11 @@ class IndexStore:
         every other segment mutation; refuses a duplicate m, a non-nested
         m, or a row count that disagrees with the base segment.
 
-        ``deltas`` persists a segmented cascade's COARSE delta segments so
-        a segmented load rehydrates them bit-for-bit instead of re-deriving
-        (requantising) from the full deltas: each dict carries ``rows``
-        (the n_real live rows in storage dtype — exactly the bytes served),
-        ``scale`` (per-dim dequant scale or None) and ``capacity`` (the
-        fixed padded dispatch shape). Their row counts must mirror the main
+        ``deltas`` persists a paged cascade's COARSE delta extents so a
+        load adopts them bit-for-bit instead of re-deriving (requantising)
+        from the full deltas: each dict carries ``rows`` (the live rows in
+        storage dtype — exactly the bytes served), ``scale`` (per-dim
+        dequant scale or None) and ``capacity`` (the seal rows). Their row counts must mirror the main
         delta segments one-for-one — the two views describe the same docs.
         """
         vectors = np.asarray(vectors)
@@ -874,7 +854,7 @@ class IndexStore:
     def flat_loadable(self) -> bool:
         """Whether the global chunk list is a coherent single index: one
         segment, no scales at all, or every segment sharing one scale —
-        mixed per-segment scales need ``SegmentedIndex.load``."""
+        mixed per-segment scales need ``PagedIndex.load``."""
         segs = self._segment_entries()
         if len(segs) == 1:
             return True

@@ -75,7 +75,7 @@ started before page ``i`` is scored, so the HBM (or host-tier) stream
 overlaps the MXU. The pool stays in its storage dtype end-to-end (int8
 pages dequantise in-register); each page's per-page dequant scale row is
 DMA'd alongside and folded into the *query* (``q * scale``, the same
-fold order as the segmented path, so scores are bit-identical). Dead
+fold order as the jnp page walk, so scores are bit-identical). Dead
 table slots (``slot >= n_slots``) are masked, never DMA'd. The page
 count is a *traced* scalar — growing or shrinking the index never
 recompiles. A ``(table_cap, 1, page_rows)`` ``ids_pool`` switches to the
@@ -588,7 +588,7 @@ def _make_paged_kernel(k: int, table_cap: int, page_rows: int,
                 q = q_ref[...]
                 if with_scale:
                     # per-page dequant scale folds into the QUERY — the same
-                    # fold order as the segmented path, so bitwise-equal
+                    # fold order as the jnp page walk, so bitwise-equal
                     q = q * sbuf[slot]                  # (bb, m) * (1, m)
                 q = query_terms(q, pbuf.dtype)
                 if q.dtype == jnp.bfloat16:

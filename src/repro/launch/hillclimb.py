@@ -93,14 +93,6 @@ def tt_retrieval_variants():
                 "compose 75% PCA + int8 + hierarchical merge: all three "
                 "terms now within ~2x of each other (balanced design)",
                 index_dim=64, int8=1, hier_merge=1),
-        variant("pca50_int8_live_delta",
-                "LIVE INDEX: sharded immutable base + one replicated open "
-                "delta (8k rows, own scale, traced live count) merged via "
-                "merge_segment_topk — the delta scan is 8k*m extra streamed "
-                "bytes (<1% of the base) and the merge adds one tiny "
-                "replicated top-k: live appends should be ~free at serve "
-                "time",
-                index_dim=128, int8=1, delta_rows=8192),
     ]
 
 

@@ -36,28 +36,29 @@ serves from it — no PCA refit, no index rebuild, and the index is
 host-streamed onto the device(s) (per-shard when ``--sharded``). The
 cold-start time (open store -> first answered query) is printed.
 
-``--live-append R`` wraps the index in a ``SegmentedIndex`` and appends
-synthetic documents at R rows/s WHILE serving: each append builds a new
-segment set (open delta with its own int8 scale) and installs it into the
-running server atomically between batches (``swap_index``), then a final
-compaction rebuilds base+deltas into one fresh base mid-serve — the full
-live-index lifecycle under traffic, zero steady-state recompiles.
+``--live-append R`` serves through a ``PagedIndex`` and appends synthetic
+documents at R rows/s WHILE serving: each append builds a new index (rows
+in the open delta extent, with its own int8 scale) and installs it into
+the running server atomically between batches (``swap_index``), then a
+final compaction promotes every delta and drains its pages into the pool
+mid-serve — the live-index lifecycle under traffic, zero steady-state
+recompiles.
 ``--bucket-batches`` pads partial batches to the next bucket in
 {8, 16, …, max_batch} instead of always max_batch (less pad compute at
 low load for a handful of extra compiles).
 
-``--paged`` serves through a ``PagedIndex``: the index lives in
+The index is paged (``PagedIndex``) when ``--live-append``, ``--page-pool``
+or a loaded artifact's ``paged`` manifest block asks for it: it lives in
 fixed-size pages behind an int32 indirection table, so live appends,
 delta promotion, compaction and eviction are page-pointer swaps — no
-array rebuilds, no per-segment-count recompiles. ``--page-rows R`` sets
-the page geometry (rows per page) and ``--page-pool P`` caps the
-device-resident pool at P pages: an index larger than the pool keeps its
-overflow pages host-side and streams them through the double-buffered
-DMA pipeline on demand (oversubscription). Composes with
-``--live-append`` (the updater mirrors page lifecycle ops to the store),
-``--cascade`` (both resolutions page), and ``--save-index`` /
-``--load-index`` (the page map rides the manifest; a paged artifact is
-auto-detected). ``--sharded`` is not supported.
+array rebuilds, no recompiles. ``--page-rows R`` sets the page geometry
+(rows per page) and ``--page-pool P`` caps the device-resident pool at P
+pages: an index larger than the pool keeps its overflow pages host-side
+and streams them through the double-buffered DMA pipeline on demand
+(oversubscription). Composes with ``--cascade`` (both resolutions page)
+and ``--save-index`` / ``--load-index`` (the page map rides the
+manifest). ``--sharded`` is refused with ``--live-append`` and
+``--page-pool`` (paged per-shard pools do not exist yet).
 
 ``--cascade M:N`` serves through a two-resolution ``CascadeIndex``:
 a coarse scan over the first M PCA dims (int8) keeps N·k candidates per
@@ -81,7 +82,7 @@ Examples:
       --quantize-int8 --save-index /tmp/idx
   PYTHONPATH=src python -m repro.launch.serve --load-index /tmp/idx --sharded
   PYTHONPATH=src python -m repro.launch.serve --live-append 300 \
-      --open-loop 200            # segmented index: append while serving,
+      --open-loop 200            # paged index: append while serving,
                                  # atomic swaps, final mid-serve compaction
   PYTHONPATH=src python -m repro.launch.serve --bucket-batches \
       --open-loop 50             # low load: pad to {8,16,32}, not max_batch
@@ -90,11 +91,8 @@ Examples:
   PYTHONPATH=src python -m repro.launch.serve --cascade 64:8 \
       --live-append 300          # cascade + live appends: both resolutions
                                  # grow and swap atomically as one object
-  PYTHONPATH=src python -m repro.launch.serve --paged --page-rows 256 \
-      --live-append 300          # paged index: appends/compaction are
-                                 # page-pointer swaps, zero recompiles
   PYTHONPATH=src python -m repro.launch.serve --load-index /tmp/idx \
-      --paged --page-pool 96     # oversubscribed: pool capped at 96
+      --page-pool 96             # oversubscribed: pool capped at 96
                                  # pages, the rest stream from host
 """
 from __future__ import annotations
@@ -112,6 +110,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.core import CascadeIndex, DenseIndex, IndexStore, ShardedDenseIndex, StaticPruner
+from repro.core.paged import PagedIndex
 from repro.core.store import save_index
 from repro.data.synthetic import make_dataset
 from repro.util import compile_cache_dir, force_host_device_count
@@ -370,12 +369,12 @@ class RetrievalServer:
     of compiled shapes traded for up to 4x less pad compute at low load
     (call ``warmup()`` to pre-compile every bucket).
 
-    ``swap_index`` installs a NEW index (a fresh ``SegmentedIndex`` after a
+    ``swap_index`` installs a NEW index (a fresh ``PagedIndex`` after a
     live append or compaction) atomically *between* batches: the worker
     snapshots (index, projection) under a lock per dispatch, so every batch
-    runs entirely against one segment set, and in-flight batches keep the
-    old set's arrays alive until their replies post — no reply is dropped
-    or computed against a half-swapped state.
+    runs entirely against one index, and in-flight batches keep the old
+    one's arrays alive until their replies post — no reply is dropped or
+    computed against a half-swapped state.
     """
 
     _KEEP = object()   # swap_index sentinel: leave the projection alone
@@ -489,7 +488,7 @@ class RetrievalServer:
         unpadded dispatch.
 
         The (index, projection) pair is snapshotted under the swap lock:
-        the whole batch runs against one consistent segment set even if
+        the whole batch runs against one consistent index even if
         ``swap_index`` lands mid-flight.
 
         The padded batch goes from the host straight to where the index
@@ -576,7 +575,7 @@ class RetrievalServer:
                 f"— batch never posted"), now)
 
     def swap_index(self, index, pruner=_KEEP) -> None:
-        """Atomically install a new index (segment set) for future batches.
+        """Atomically install a new index for future batches.
 
         Runs between batches by construction: ``_dispatch`` snapshots
         (index, projection) under the same lock, in-flight batches hold
@@ -1015,14 +1014,13 @@ def main() -> None:
                          "compiles)")
     ap.add_argument("--live-append", type=float, default=0.0,
                     metavar="ROWS_PER_S",
-                    help="serve through a SegmentedIndex and append "
+                    help="serve through a PagedIndex and append "
                          "synthetic documents at this rate during the "
-                         "drive — every append swaps a fresh segment set "
-                         "into the running server (then compacts at the "
-                         "end)")
+                         "drive — every append swaps a fresh index into "
+                         "the running server (then compacts at the end)")
     ap.add_argument("--delta-capacity", type=int, default=4096,
-                    help="fixed padded capacity of each delta segment "
-                         "(the compiled dispatch shape for live appends)")
+                    help="rows at which a paged delta extent seals (the "
+                         "bound on a scale-widening requantisation)")
     ap.add_argument("--open-loop", type=float, default=0.0, metavar="QPS",
                     help="additionally drive Poisson arrivals at QPS "
                          "(open loop: submissions never wait on replies) "
@@ -1041,18 +1039,12 @@ def main() -> None:
                     help="sharded candidate merge: one all-gather over "
                          "every device, or two stages over a factored mesh")
     ap.add_argument("--quantize-int8", action="store_true")
-    ap.add_argument("--paged", action="store_true",
-                    help="serve through a PagedIndex: fixed-size pages "
-                         "behind an indirection table — appends, "
-                         "promotion, compaction and eviction are "
-                         "page-pointer swaps (zero steady-state "
-                         "recompiles), and the index may exceed device "
-                         "memory (see --page-pool)")
     ap.add_argument("--page-rows", type=int, default=0, metavar="R",
                     help="rows per page (default: 256, or the artifact's "
                          "page geometry under --load-index)")
     ap.add_argument("--page-pool", type=int, default=0, metavar="P",
-                    help="cap the device-resident page pool at P pages; "
+                    help="serve through a PagedIndex whose "
+                         "device-resident page pool is capped at P pages; "
                          "overflow pages stay host-side and stream "
                          "through the DMA pipeline on demand "
                          "(default: everything resident)")
@@ -1082,12 +1074,12 @@ def main() -> None:
     args = ap.parse_args()
     if args.save_index and args.load_index:
         ap.error("--save-index and --load-index are mutually exclusive")
-    if args.paged and args.sharded:
-        ap.error("--paged does not compose with --sharded yet "
-                 "(paged per-shard pools: see ROADMAP)")
-    if args.paged and args.fleet > 0:
-        ap.error("--paged does not compose with --fleet yet "
-                 "(paged replicas load via the store auto-detect path)")
+    for flag, on in (("--live-append", args.live_append > 0),
+                     ("--page-pool", args.page_pool > 0)):
+        if on and args.sharded:
+            ap.error(f"{flag} does not compose with --sharded yet "
+                     f"(paged per-shard pools: see ROADMAP)")
+    paged = args.live_append > 0 or args.page_pool > 0
     page_rows = args.page_rows or 256
     pool_pages = args.page_pool or None
     cascade_mn = None
@@ -1107,7 +1099,7 @@ def main() -> None:
     jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
 
     if args.fleet > 0:
-        if args.sharded or args.cascade or args.live_append > 0:
+        if args.sharded or args.cascade or paged:
             ap.error("--fleet composes with the single-node flat index only "
                      "(sharded/cascade fleet replicas: see ROADMAP)")
         _serve_fleet(args)
@@ -1147,20 +1139,21 @@ def main() -> None:
             index = CascadeIndex.load(store, m_coarse=cascade_mn[0],
                                       n_factor=cascade_mn[1],
                                       backend=args.backend,
-                                      segmented=args.live_append > 0,
-                                      paged=args.paged,
                                       page_rows=args.page_rows or None,
-                                      pool_pages=pool_pages,
-                                      delta_capacity=args.delta_capacity)
+                                      pool_pages=pool_pages)
+            if paged and isinstance(index.full, DenseIndex):
+                index = index.paged(page_rows=page_rows,
+                                    pool_pages=pool_pages,
+                                    seal_rows=args.delta_capacity)
+            tag = ", paged" if isinstance(index.full, PagedIndex) else ""
             print(f"[serve] loaded cascade: {index.n} x {index.dim} "
                   f"(+ coarse m={index.m_coarse}, shortlist "
-                  f"{index.n_factor}*k, {index.nbytes/2**20:.1f} MiB"
-                  f"{', paged' if args.paged else ''})")
-        elif args.paged or "paged" in store.manifest:
-            from repro.core.paged import PagedIndex
+                  f"{index.n_factor}*k, {index.nbytes/2**20:.1f} MiB{tag})")
+        elif paged or "paged" in store.manifest:
             index = PagedIndex.load(store, backend=args.backend,
                                     page_rows=args.page_rows or None,
-                                    pool_pages=pool_pages)
+                                    pool_pages=pool_pages,
+                                    seal_rows=args.delta_capacity)
             stg = index.storage
             print(f"[serve] loaded paged index: {index.n} x {index.dim} "
                   f"({index.nbytes/2**20:.1f} MiB, {stg.n_slots} pages "
@@ -1204,16 +1197,15 @@ def main() -> None:
                                        n_factor=cascade_mn[1],
                                        quantize_int8=args.quantize_int8,
                                        backend=args.backend)
-            if args.paged:
+            if paged:
                 index = index.paged(page_rows=page_rows,
                                     pool_pages=pool_pages,
                                     seal_rows=args.delta_capacity)
             print(f"[serve] cascade index: {index.n} x {index.dim} "
                   f"(+ coarse m={index.m_coarse} int8, shortlist "
                   f"{index.n_factor}*k, {index.nbytes/2**20:.1f} MiB"
-                  f"{', paged' if args.paged else ''})")
-        elif args.paged:
-            from repro.core.paged import PagedIndex
+                  f"{', paged' if paged else ''})")
+        elif paged:
             base = DenseIndex.build(pruned, quantize_int8=args.quantize_int8)
             index = PagedIndex.from_index(base, page_rows=page_rows,
                                           pool_pages=pool_pages,
@@ -1244,14 +1236,10 @@ def main() -> None:
     if args.live_append > 0 and cascade_mn:
         # CascadeIndex is copy-on-write: append grows BOTH resolutions and
         # swap_index installs the consistent pair atomically. IndexUpdater
-        # is SegmentedIndex-specific, so the cascade drives the same
+        # holds one PagedIndex, so the cascade drives the same
         # swap-between-batches discipline directly; only this thread ever
-        # rebinds the local, so no extra lock is needed.
-        from repro.core import SegmentedIndex
-        if not (isinstance(index.full, SegmentedIndex)
-                or hasattr(index.full, "storage")):
-            index = index.segmented(delta_capacity=args.delta_capacity)
-        server.swap_index(index)
+        # rebinds the local, so no extra lock is needed. (The index is
+        # already paged: --live-append selects paged storage.)
         rng_app = np.random.default_rng(123)
         app_block = 64
         cascade_app = {"rows": 0, "index": index}
@@ -1274,21 +1262,12 @@ def main() -> None:
 
         appender = threading.Thread(target=_appender, daemon=True)
         print(f"[serve] live-append (cascade): {args.live_append:.0f} "
-              f"rows/s (blocks of {app_block}, delta capacity "
+              f"rows/s (blocks of {app_block}, seal rows "
               f"{args.delta_capacity})")
         appender.start()
     elif args.live_append > 0:
-        from repro.core import SegmentedIndex
         from repro.core.maintenance import IndexUpdater
-        if hasattr(index, "storage"):
-            # already paged: appends/compaction are page-pointer swaps on
-            # the same object — no segmented wrapper needed
-            seg = index
-        else:
-            seg = SegmentedIndex.from_index(
-                index, delta_capacity=args.delta_capacity)
-        server.swap_index(seg)
-        updater = IndexUpdater(pruner=pruner, index=seg, server=server,
+        updater = IndexUpdater(pruner=pruner, index=index, server=server,
                                delta_capacity=args.delta_capacity)
         rng_app = np.random.default_rng(123)
         app_block = 64
@@ -1306,7 +1285,7 @@ def main() -> None:
 
         appender = threading.Thread(target=_appender, daemon=True)
         print(f"[serve] live-append: {args.live_append:.0f} rows/s "
-              f"(blocks of {app_block}, delta capacity "
+              f"(blocks of {app_block}, seal rows "
               f"{args.delta_capacity})")
         appender.start()
 
@@ -1342,10 +1321,8 @@ def main() -> None:
         print(_worker_times(ostats))
 
     def _delta_units(idx) -> str:
-        if hasattr(idx, "storage"):
-            n_ext = sum(1 for e in idx.storage.extents if e.kind == "delta")
-            return f"{idx.storage.delta_pages} delta page(s), {n_ext} extent(s)"
-        return f"{len(idx.deltas)} delta segment(s)"
+        n_ext = sum(1 for e in idx.storage.extents if e.kind == "delta")
+        return f"{idx.storage.delta_pages} delta page(s), {n_ext} extent(s)"
 
     if cascade_app is not None:
         append_stop.set()
@@ -1365,18 +1342,11 @@ def main() -> None:
         t0 = time.perf_counter()
         updater.compact()
         dt_ms = (time.perf_counter() - t0) * 1e3
-        lc = updater.last_compaction or {}
-        if "pages_moved" in lc:
-            print(f"[serve] compaction (paged): {lc['pages_moved']} pages "
-                  f"moved, {lc['pages_freed']} freed, {lc['pages_host']} "
-                  f"host-tier, in {dt_ms:.0f}ms — pointer swaps, no "
-                  f"rebuild; server swapped mid-serve "
-                  f"(swap #{server.swap_count})")
-        else:
-            print(f"[serve] compaction: base+deltas -> one fresh base "
-                  f"({updater.index.n} rows, fresh scale) in "
-                  f"{dt_ms:.0f}ms; server swapped "
-                  f"mid-serve (swap #{server.swap_count})")
+        lc = updater.last_compaction
+        print(f"[serve] compaction: {lc['pages_moved']} pages moved, "
+              f"{lc['pages_freed']} freed, {lc['pages_host']} host-tier, "
+              f"in {dt_ms:.0f}ms — pointer swaps, no rebuild; server "
+              f"swapped mid-serve (swap #{server.swap_count})")
     server.close()
 
     if args.compare_full and args.load_index:

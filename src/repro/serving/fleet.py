@@ -56,8 +56,8 @@ from typing import Sequence
 
 import numpy as np
 
-from repro.core import SegmentedIndex
 from repro.core.maintenance import IndexUpdater
+from repro.core.paged import PagedIndex
 from repro.core.store import IndexStore
 from repro.launch.serve import Reply, RetrievalServer, TimedOut
 
@@ -439,9 +439,9 @@ class HealthPolicy:
 @dataclasses.dataclass(frozen=True)
 class AutoCompactPolicy:
     """Compaction controller thresholds (closes the PR 5 follow-up): when
-    the delta tier outgrows the base or per-segment scales diverge, the
-    actor compacts and swaps the fresh base into the least-loaded replica
-    first."""
+    the delta pages outgrow the base or delta extent scales diverge, the
+    actor compacts (every delta extent joins the base) and swaps the
+    result into the least-loaded replica first."""
 
     max_delta_fraction: float = 0.25
     max_scale_divergence: float = 1.5    # scale ratio; floor is 1.0
@@ -482,8 +482,8 @@ class ReplicaSet:
         self.autocompact = autocompact
         self.probe_queries = probe_queries
         self.pruner = store.load_pruner()
-        self.index = SegmentedIndex.load(store, backend=backend,
-                                         delta_capacity=delta_capacity)
+        self.index = PagedIndex.load(store, backend=backend,
+                                     seal_rows=delta_capacity)
         self.version = str(store.path)
         self.events: list = []       # actor-appended; snapshot via health()
         self.replicas = []
@@ -756,9 +756,8 @@ class ReplicaSet:
             # corrupt artifact aborts here with the fleet untouched
             store_new = IndexStore.open(path)
             pruner_new = store_new.load_pruner()
-            index_new = SegmentedIndex.load(
-                store_new, backend=self.backend,
-                delta_capacity=self.delta_capacity)
+            index_new = PagedIndex.load(store_new, backend=self.backend,
+                                        seal_rows=self.delta_capacity)
         except Exception as e:
             result["reason"] = f"artifact rejected: {e!r}"
             self.events.append(result)

@@ -419,20 +419,6 @@ def test_invariant_fixtures_trip_exactly_their_finding(fn_name, expect):
     assert all(f.severity == "error" for f in fs)
 
 
-def test_segment_offset_fixture_flagged():
-    from repro.analysis import invariants
-    from repro.analysis.fixtures import bad_invariants
-    D8a = jnp.asarray(RNG.integers(-127, 127, (64, 16)).astype(np.int8))
-    D8b = jnp.asarray(RNG.integers(-127, 127, (64, 16)).astype(np.int8))
-    sc = jnp.full((16,), 0.05, jnp.float32)
-    q = jnp.asarray(RNG.standard_normal((2, 16)).astype(np.float32))
-    fs = invariants.check_entry("fixture.overlap",
-                                bad_invariants.overlapping_segments,
-                                (D8a, D8b, sc, q))
-    assert [f.check for f in fs] == ["inv.segment-offsets"]
-    assert "100" in fs[0].message and "132" in fs[0].message
-
-
 # ---------------------------------------------------------------------------
 # lock sanitizer
 # ---------------------------------------------------------------------------

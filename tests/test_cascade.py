@@ -1,18 +1,19 @@
 """Cascade retrieval: oracle parity, monotonicity, multi-resolution store.
 
 Covers the acceptance surface of the two-stage cascade:
-  * with N·k >= n the cascade is BIT-identical (scores AND ids) to the
-    full-m exact ``search_projected`` — dense and segmented, f32 and int8
-    full resolution, jnp and pallas backends;
+  * with N·k >= n the dense cascade is BIT-identical (scores AND ids) to
+    the full-m exact ``search_projected`` — f32 and int8 full resolution,
+    jnp and pallas backends — and a paged cascade (base + live deltas in
+    both resolutions) returns the float64 oracle's exact top-k;
   * recall@10 against the full-m oracle is non-decreasing in the
     shortlist depth N (a superset shortlist rescored exactly can only
     keep or add true top-k members) and reaches 1.0 at N·k >= n;
   * a stored coarse resolution round-trips bit-identically and corrupted
     multi-resolution manifests are rejected loudly (row mismatch,
     non-nested m, duplicate m, missing blobs);
-  * ``CascadeIndex`` validates row alignment and nesting, and a
-    segmented cascade grows BOTH resolutions in lockstep with zero
-    steady-state recompiles.
+  * ``CascadeIndex`` validates row alignment and nesting, and a paged
+    cascade grows BOTH resolutions in lockstep with zero steady-state
+    recompiles.
 """
 import json
 import os
@@ -21,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paged_oracle import assert_matches_oracle
 from repro.core import (
     CascadeIndex,
     DenseIndex,
@@ -76,21 +78,24 @@ def test_cascade_bitwise_oracle_parity_dense(quant, backend, n):
 
 @pytest.mark.parametrize("quant", [False, True])
 def test_cascade_bitwise_oracle_parity_segmented(quant):
-    """Segmented cascade (base + live deltas in both resolutions) against
-    the segmented full-m search on the same segment set."""
+    """Paged cascade (base + live deltas in both resolutions) at covering
+    depth: its answer, like the paged full-m search's, is the float64
+    oracle's exact top-k over the full resolution."""
     k, n = 8, 400
     pruned, W, mean, Q = _fixture(n=n)
     extra = RNG.standard_normal((90, pruned.shape[1])).astype(np.float32)
     cas = CascadeIndex.build(pruned, m_coarse=max(2, pruned.shape[1] // 2),
                              n_factor=_full_nf(n + 90, k),
                              quantize_int8=quant
-                             ).segmented(delta_capacity=64)
+                             ).paged(page_rows=64, seal_rows=64)
     cas = cas.append(extra)
     assert cas.n == n + 90 and cas.coarse.n == cas.full.n
-    s0, i0 = cas.full.search_projected(Q, W, k=k, mean=mean)
-    s1, i1 = cas.search_projected(Q, W, k=k, mean=mean)
-    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
-    np.testing.assert_array_equal(np.asarray(s0), np.asarray(s1))
+    assert cas.full.delta_pages > 0
+    assert_matches_oracle(cas.full, Q, cas.search_projected(Q, W, k=k,
+                                                            mean=mean),
+                          k, W=W, mean=mean, msg="cascade")
+    assert_matches_oracle(cas.full, Q, cas.full.search_projected(
+        Q, W, k=k, mean=mean), k, W=W, mean=mean, msg="full")
 
 
 def test_cascade_recall_monotone_in_shortlist_depth():
@@ -139,29 +144,34 @@ def test_cascade_rejects_non_nested_m():
 def test_cascade_append_requires_segmented_resolutions():
     pruned, _, _, _ = _fixture(n=120)
     cas = CascadeIndex.build(pruned, m_coarse=4)
-    with pytest.raises(TypeError, match="segmented"):
+    with pytest.raises(TypeError, match="paged resolutions"):
         cas.append(np.zeros((3, pruned.shape[1]), np.float32))
 
 
 def test_cascade_append_zero_steady_state_recompiles():
     """Fixed-shape appends + searches after warmup must not grow any jit
-    cache — nk is fixed and every per-segment dispatch takes live count
-    and offset as traced operands."""
-    from repro.core.index import segment_jit_cache_size
+    cache — nk is fixed and both page walks take their slot bounds as
+    traced operands."""
+    from repro.core import cascade, paged
+
+    def cache_size():
+        return (sum(paged._jit_cache_sizes().values())
+                + sum(cascade._jit_cache_sizes().values()))
+
     k, n = 5, 300
     pruned, W, mean, Q = _fixture(n=n)
     cas = CascadeIndex.build(pruned, m_coarse=pruned.shape[1] // 2,
                              n_factor=2, quantize_int8=True
-                             ).segmented(delta_capacity=128)
+                             ).paged(page_rows=64, seal_rows=128)
     block = RNG.standard_normal((8, pruned.shape[1])).astype(np.float32)
     cas = cas.append(block)            # opens both deltas, widest scale
-    cas = cas.append(0.5 * block)      # non-widening extend compiles once
+    cas = cas.append(0.5 * block)      # non-widening extend
     cas.search_projected(Q, W, k=k, mean=mean)
-    before = segment_jit_cache_size()
+    before = cache_size()
     for frac in (0.4, 0.3, 0.2):       # shrinking rows: never re-widen
         cas = cas.append(frac * block)
         cas.search_projected(Q, W, k=k, mean=mean)
-    assert segment_jit_cache_size() == before
+    assert cache_size() == before
     assert cas.coarse.n == cas.full.n == n + 5 * 8
 
 
@@ -190,26 +200,27 @@ def test_cascade_store_roundtrip_dense(tmp_path, quant):
 def test_cascade_store_roundtrip_segmented(tmp_path):
     """A grown cascade persists through the store: the stored resolution
     covers the base rows, coarse deltas rehydrate from their PERSISTED
-    segments (exact quantised bytes — no requantisation on load), and
-    the pair stays row-aligned."""
+    segments (exact quantised bytes — no requantisation on load), the
+    pair stays row-aligned, and the reload serves the same bits."""
     k, n = 8, 300
     pruned, W, mean, Q = _fixture(n=n)
     cas = CascadeIndex.build(pruned, m_coarse=pruned.shape[1] // 2,
                              n_factor=_full_nf(n + 40, k),
-                             quantize_int8=True).segmented(delta_capacity=64)
+                             quantize_int8=True
+                             ).paged(page_rows=64, seal_rows=64)
     cas = cas.append(RNG.standard_normal((40, pruned.shape[1]))
                      .astype(np.float32))
     store = save_index(str(tmp_path / "st"), cas)
     loaded = CascadeIndex.load(store, m_coarse=cas.m_coarse,
-                               n_factor=cas.n_factor, segmented=True,
-                               delta_capacity=64)
+                               n_factor=cas.n_factor)
     assert loaded.n == cas.n and loaded.coarse.n == loaded.full.n
-    # at covering depth the shortlist spans every row, so ids/scores
-    # match the full-resolution search exactly
-    s0, i0 = loaded.full.search_projected(Q, W, k=k, mean=mean)
+    assert loaded.full.storage.seal_rows == 64
+    s0, i0 = cas.search_projected(Q, W, k=k, mean=mean)
     s1, i1 = loaded.search_projected(Q, W, k=k, mean=mean)
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
     np.testing.assert_array_equal(np.asarray(s0), np.asarray(s1))
+    # at covering depth the shortlist spans every row: the exact top-k
+    assert_matches_oracle(loaded.full, Q, (s1, i1), k, W=W, mean=mean)
 
 
 def _cascade_store(tmp_path, n=200):
@@ -285,15 +296,16 @@ def test_cascade_load_requires_matching_resolution(tmp_path):
 
 
 def test_cascade_store_persists_coarse_deltas_bit_parity(tmp_path):
-    """Satellite regression: a segmented cascade's coarse deltas persist
-    in the store as exact quantised bytes + per-delta scales, and a
-    segmented load rehydrates them BIT-identically — no requantisation
-    from the full deltas on the load path."""
+    """Satellite regression: a paged cascade's coarse delta extents
+    persist in the store as exact quantised bytes + per-extent scales,
+    and a load adopts them BIT-identically — no requantisation from the
+    full deltas on the load path."""
     k, n = 8, 300
     pruned, W, mean, Q = _fixture(n=n)
     cas = CascadeIndex.build(pruned, m_coarse=pruned.shape[1] // 2,
                              n_factor=_full_nf(n + 48, k),
-                             quantize_int8=True).segmented(delta_capacity=64)
+                             quantize_int8=True
+                             ).paged(page_rows=64, seal_rows=64)
     for seed in (1, 2):
         cas = cas.append(np.random.default_rng(seed)
                          .standard_normal((24, pruned.shape[1]))
@@ -301,19 +313,20 @@ def test_cascade_store_persists_coarse_deltas_bit_parity(tmp_path):
     store = save_index(str(tmp_path / "st"), cas)
     name = store.manifest["resolutions"][0]["name"]
     dviews = store.resolution_deltas(name)
-    assert dviews, "segmented save must persist the coarse delta segments"
-    assert [v.n for v in dviews] == [d.n_real for d in cas.coarse.deltas]
+    assert dviews, "a paged save must persist the coarse delta extents"
+    mem_st = cas.coarse.storage
+    assert [v.n for v in dviews] == [e.n_rows for e in mem_st.extents[1:]]
     loaded = CascadeIndex.load(store, m_coarse=cas.m_coarse,
-                               n_factor=cas.n_factor, segmented=True,
-                               delta_capacity=64)
-    for mem, got in zip(cas.coarse.deltas, loaded.coarse.deltas):
-        np.testing.assert_array_equal(
-            np.asarray(mem.vectors[:mem.n_real]),
-            np.asarray(got.vectors[:got.n_real]))
+                               n_factor=cas.n_factor)
+    got_st = loaded.coarse.storage
+    assert len(got_st.extents) == len(mem_st.extents)
+    for i in range(1, len(mem_st.extents)):
+        np.testing.assert_array_equal(mem_st.extent_rows(i),
+                                      got_st.extent_rows(i))
+        mem, got = mem_st.extents[i], got_st.extents[i]
         assert (mem.scale is None) == (got.scale is None)
         if mem.scale is not None:
-            np.testing.assert_array_equal(np.asarray(mem.scale),
-                                          np.asarray(got.scale))
+            np.testing.assert_array_equal(mem.scale, got.scale)
     s0, i0 = cas.search_projected(Q, W, k=k, mean=mean)
     s1, i1 = loaded.search_projected(Q, W, k=k, mean=mean)
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
@@ -325,14 +338,14 @@ def test_store_rejects_misaligned_resolution_deltas(tmp_path):
     — otherwise the two views would describe different docs."""
     pruned, W, mean, Q = _fixture(n=200)
     cas = CascadeIndex.build(pruned, m_coarse=pruned.shape[1] // 2,
-                             n_factor=2,
-                             quantize_int8=True).segmented(delta_capacity=64)
+                             n_factor=2, quantize_int8=True
+                             ).paged(page_rows=64, seal_rows=64)
     cas = cas.append(RNG.standard_normal((16, pruned.shape[1]))
                      .astype(np.float32))
     store = save_index(str(tmp_path / "full-only"), cas.full)
     mc = cas.m_coarse
-    base = np.asarray(cas.coarse.base.vectors[:cas.coarse.base.n])
-    scale = np.asarray(cas.coarse.base.scale)
+    base = cas.coarse.storage.extent_rows(0)
+    scale = cas.coarse.storage.extents[0].scale
     with pytest.raises(IndexStoreError, match="mirror"):
         store.add_resolution(base, scale=scale, deltas=[
             {"rows": np.zeros((3, mc), np.int8), "scale": None,

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.paged import PagedIndex
 from repro.core.pruning import StaticPruner
 from repro.core.store import IndexStore, save_index
 from repro.launch.serve import TimedOut, _drive_open
@@ -302,6 +303,45 @@ def test_append_visible_on_every_replica(make_fleet):
         assert int(np.asarray(ids)[0]) == n0 + 5
 
 
+@pytest.mark.parametrize("writer", ["updater", "bare_delta"])
+def test_fleet_keeps_store_seal_size(tmp_path, writer):
+    """A fleet opened at a larger ``delta_capacity`` than the store's open
+    delta segment seals at the store's capacity: appends past it open a
+    new segment, and the store still opens (``IndexStore.open`` refuses a
+    segment holding more rows than its capacity)."""
+    from repro.core.maintenance import IndexUpdater
+    D = _unit_corpus()
+    pruner = StaticPruner(cutoff=0.5).fit(jnp.asarray(D))
+    p = str(tmp_path / "v")
+    save_index(p, pruner.build_index(jnp.asarray(D)), pruner=pruner)
+    first = _unit_corpus(n=40, d=D_DIM, seed=3)
+    if writer == "updater":          # a paged block records seal_rows=96
+        up = IndexUpdater.from_store(p, delta_capacity=96)
+        up.add_documents(first)
+        assert "paged" in up.store.manifest
+    else:                            # a delta segment with no paged block
+        st = IndexStore.open(p)
+        name = st.add_delta(capacity=96)
+        st.append(np.asarray(st.load_pruner().prune_index(
+            jnp.asarray(first)), np.float32), segment=name)
+        assert "paged" not in st.manifest
+    fleet = ReplicaSet(p, replicas=1, delta_capacity=4096,
+                       probe_queries=D[:8])
+    try:
+        assert fleet.index.storage.seal_rows == 96
+        extra = _unit_corpus(n=160, d=D_DIM, seed=4)
+        fleet.append(extra)
+        assert fleet.index.n == N + 200
+    finally:
+        fleet.close()
+    reopened = IndexStore.open(p)
+    deltas = [v for v in reopened.segments() if v.kind == "delta"]
+    assert [v.n for v in deltas] == [96, 96, 8]
+    assert all(v.n <= v.capacity == 96 for v in deltas)
+    cold = PagedIndex.load(reopened)
+    assert cold.n == N + 200 and cold.storage.seal_rows == 96
+
+
 def test_autocompact_controller_triggers_and_serves(make_fleet):
     fleet, D = make_fleet(
         autocompact=AutoCompactPolicy(max_delta_fraction=0.10,
@@ -311,13 +351,14 @@ def test_autocompact_controller_triggers_and_serves(make_fleet):
     while time.monotonic() < deadline and fleet.updater.compactions == 0:
         time.sleep(0.05)
     assert fleet.updater.compactions == 1
-    assert len(fleet.index.deltas) == 0
+    assert fleet.index.delta_pages == 0
     kinds = [e["kind"] for e in fleet.events]
     assert "autocompact" in kinds
     _assert_self_topk(fleet, D, 21)
     # durably compacted too: a cold reload of the store sees no deltas
-    cold = IndexStore.open(fleet.store.path)
-    assert all(s.kind == "base" for s in cold.segments())
+    cold = PagedIndex.load(IndexStore.open(fleet.store.path))
+    assert cold.delta_pages == 0
+    assert all(e.kind == "base" for e in cold.storage.extents)
 
 
 @pytest.mark.filterwarnings(

@@ -26,13 +26,29 @@ def test_add_documents_searchable():
     assert n0 + 3 in np.asarray(ids)[0].tolist()
 
 
+def test_updater_refuses_sharded_index_at_construction():
+    """Appends over a sharded base are not supported: the updater says so
+    when it is built, not at the first append."""
+    import jax
+    from repro.core.index import ShardedDenseIndex
+    D = _corpus(n=256)
+    pruner = StaticPruner(cutoff=0.5).fit(D)
+    mesh = jax.make_mesh((1,), ("data",))
+    sidx = ShardedDenseIndex.build(pruner.prune_index(D), mesh)
+    with pytest.raises(TypeError, match="queue 2 item 5"):
+        IndexUpdater(pruner=pruner, index=sidx)
+
+
 def test_add_documents_int8_path():
     D = _corpus()
     up = IndexUpdater.build(D, cutoff=0.5, quantize_int8=True)
     up.add_documents(_corpus(seed=0, n=120, domain_seed=2)[:50])
-    assert up.index.base.vectors.dtype == jnp.int8
-    assert up.index.deltas[0].vectors.dtype == jnp.int8
-    assert up.index.deltas[0].scale is not None     # its OWN scale
+    st = up.index.storage
+    assert up.index.storage_dtype == jnp.int8
+    assert st.extent_rows(1).dtype == np.int8
+    assert st.extents[1].kind == "delta"
+    assert st.extents[1].scale is not None          # its OWN scale
+    assert not np.array_equal(st.extents[1].scale, st.extents[0].scale)
     s, ids = up.search(D[:2], k=5)
     assert np.isfinite(np.asarray(s)).all()
 
@@ -119,10 +135,10 @@ def test_clip_fraction_zero_on_float_index():
 
 
 def test_delta_fraction_trips_refit():
-    """Compaction pressure: once the deltas hold most of the corpus, the
-    policy asks for a compaction even with zero drift."""
+    """Compaction pressure: once the delta pages are most of the index,
+    the policy asks for a compaction even with zero drift."""
     D = _corpus(n=400)
-    up = IndexUpdater.build(D, cutoff=0.5)
+    up = IndexUpdater.build(D, cutoff=0.5, page_rows=64)
     in_dom = _corpus(seed=0, n=900, domain_seed=7)[400:]
     up.add_documents(in_dom)
     assert up.delta_fraction > 0.5
@@ -133,15 +149,32 @@ def test_delta_fraction_trips_refit():
     assert not up.needs_refit(in_dom[:100], threshold=0.0)
 
 
+def test_delta_fraction_counts_pages_at_default_page_size():
+    """At the default 256-row pages the threshold is on pages, not rows:
+    500 delta rows over a 400-row base are 2 of 4 pages, exactly 0.5, so
+    the policy holds (5/9 of the rows would have tripped it); 13 more
+    rows open a third delta page, 3 of 5, and it trips."""
+    D = _corpus(n=400)
+    up = IndexUpdater.build(D, cutoff=0.5)
+    assert up.index.storage.page_rows == 256
+    in_dom = _corpus(seed=0, n=913, domain_seed=7)[400:]
+    up.add_documents(in_dom[:500])
+    assert up.delta_fraction == 0.5
+    assert not up.needs_refit(in_dom[:100], threshold=0.0)
+    up.add_documents(in_dom[500:])
+    assert up.delta_fraction == 0.6
+    assert up.needs_refit(in_dom[:100], threshold=0.0)
+
+
 def test_refit_resets_segments_and_telemetry():
     D = _corpus()
     up = IndexUpdater.build(D, cutoff=0.5, quantize_int8=True)
     up.add_documents(50.0 * _corpus(seed=0, n=120, domain_seed=6)[:40])
     assert up.scale_divergence() > 1.0
-    assert len(up.index.deltas) == 1
+    assert up.index.delta_pages > 0
     up.refit(D)
     assert up.scale_divergence() == 1.0
-    assert len(up.index.deltas) == 0
+    assert up.index.delta_pages == 0
     assert up.appended_rows == 0
 
 
@@ -198,7 +231,9 @@ def test_telemetry_safe_under_concurrent_appends():
         th.join(timeout=60.0)
     assert not errs
     assert up.appended_rows == 30 * 37
-    assert abs(up.delta_fraction - 30 * 37 / up.index.n) < 1e-9
+    st = up.index.storage
+    assert abs(up.delta_fraction - st.delta_pages / st.n_slots) < 1e-9
+    assert st.delta_rows == 30 * 37
 
 
 def test_reference_energy_cached_once_and_refit_coherent():

@@ -6,13 +6,14 @@ The acceptance surface of the paged architecture:
     and int8 (per-page scales folded into the query), partial last page
     masked by ``n_valid``, run-split carry chaining, ``ids_pool`` rescore
     mode, any pipeline depth;
-  * ``PagedIndex`` search is BIT-IDENTICAL to ``SegmentedIndex`` at equal
-    contents — dense x {f32, int8} x {jnp, pallas}, through appends,
-    promotion, compaction, eviction (host-tier streaming), and the
-    cascade rescore path;
+  * ``PagedIndex`` search matches a plain float64 oracle over its
+    dequantised extents (``tests/paged_oracle.py``: ids exact, scores
+    within an argued f32 bound) — {f32, int8} x {jnp, pallas}, fresh,
+    after appends (a scale-widening block among them), after promotion +
+    compaction, oversubscribed, and through the cascade rescore path;
   * promotion / compaction / eviction are page-pointer swaps: results
-    never change, and a full lifecycle never grows the jit cache once
-    every variant is warm;
+    never move by a bit, jnp and pallas agree bit for bit, and a full
+    lifecycle never grows the jit cache once every variant is warm;
   * paged artifacts round-trip through ``IndexStore`` page-granularly
     (chunk boundaries page-aligned, host-tier pages included, bytes
     identical from either residency), reject corruption/truncation and a
@@ -35,10 +36,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import DenseIndex, SegmentedIndex, StaticPruner
-from repro.core.index import segment_jit_cache_size
+from paged_oracle import (assert_matches_oracle, dequantised_rows,
+                          exact_topk, score_bound)
+from repro.core import DenseIndex, StaticPruner
 from repro.core.maintenance import IndexUpdater
-from repro.core.paged import PagedIndex, PagedIndexStorage
+from repro.core.paged import PagedIndex, PagedIndexStorage, _jit_cache_sizes
 from repro.core.store import (
     IndexStore,
     IndexStoreError,
@@ -54,6 +56,10 @@ RNG = np.random.default_rng(170)
 def _assert_same(a, b, msg=""):
     assert jnp.array_equal(a[0], b[0]), f"scores diverged {msg}"
     assert jnp.array_equal(a[1], b[1]), f"ids diverged {msg}"
+
+
+def _jit_cache_size() -> int:
+    return sum(_jit_cache_sizes().values())
 
 
 # ---------------------------------------------------------------------------
@@ -194,8 +200,54 @@ def test_paged_kernel_ids_pool_rescore_mode():
 
 
 # ---------------------------------------------------------------------------
-# PagedIndex vs SegmentedIndex: bit parity through the full lifecycle
+# PagedIndex vs the float64 oracle; bit parity across lifecycle steps
 # ---------------------------------------------------------------------------
+
+
+def _oracle_case(quant, backend, state):
+    rng = np.random.default_rng(7)
+    n, d, m = 300, 48, 24
+    X = rng.standard_normal((n, m)).astype(np.float32)
+    base = DenseIndex.build(jnp.asarray(X), quantize_int8=quant)
+    pool = 5 if state == "oversubscribed" else None
+    pg = PagedIndex.from_index(base, page_rows=32, pool_pages=pool,
+                               seal_rows=64, backend=backend)
+    if state in ("appended", "compacted"):
+        # the second block's magnitude widens the open extent's int8 scale
+        for bl in (rng.standard_normal((37, m)),
+                   rng.standard_normal((20, m)) * 9.0,
+                   rng.standard_normal((50, m))):
+            pg = pg.append(bl.astype(np.float32))
+    if state == "compacted":
+        pg, _ = pg.promote()
+        pg, _ = pg.compact_pages()
+    W = rng.standard_normal((d, m)).astype(np.float32) * 0.2
+    mean = rng.standard_normal(d).astype(np.float32) * 0.1
+    Qraw = rng.standard_normal((6, d)).astype(np.float32)
+    Qm = rng.standard_normal((6, m)).astype(np.float32)
+    return pg, W, mean, Qraw, Qm
+
+
+@pytest.mark.parametrize("state", ["fresh", "appended", "compacted",
+                                   "oversubscribed"])
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+@pytest.mark.parametrize("quant", [False, True], ids=["f32", "int8"])
+def test_paged_matches_oracle(quant, backend, state):
+    pg, W, mean, Qraw, Qm = _oracle_case(quant, backend, state)
+    if state == "appended":
+        assert pg.delta_pages > 0
+        if quant:
+            scales = [e.scale for e in pg.storage.extents]
+            assert not np.array_equal(scales[0], scales[1])
+    if state == "oversubscribed":
+        assert pg.storage.n_host_pages > 0
+    k = 9
+    assert_matches_oracle(pg, Qm, pg.search(jnp.asarray(Qm), k), k,
+                          msg="search")
+    assert_matches_oracle(
+        pg, Qraw, pg.search_projected(jnp.asarray(Qraw), jnp.asarray(W), k,
+                                      mean=jnp.asarray(mean)),
+        k, W=W, mean=mean, msg="search_projected")
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["f32", "int8"])
@@ -210,26 +262,26 @@ def test_paged_parity_lifecycle(quant, backend):
     Qm = jnp.asarray(rng.standard_normal((B, m)).astype(np.float32))
     base = DenseIndex.build(jnp.asarray(X), quantize_int8=quant,
                             backend=backend)
-    seg = SegmentedIndex.from_index(base, delta_capacity=96)
     pg = PagedIndex.from_index(base, page_rows=32, seal_rows=96,
                                backend=backend)
-    _assert_same(pg.search(Qm, k), seg.search(Qm, k), "base")
-    _assert_same(pg.search_projected(Qraw, W, k, mean=mean),
-                 seg.search_projected(Qraw, W, k, mean=mean),
-                 "base projected")
+
+    def oracle(ix, msg):
+        assert_matches_oracle(ix, Qm, ix.search(Qm, k), k, msg=msg)
+        assert_matches_oracle(ix, Qraw, ix.search_projected(Qraw, W, k,
+                                                            mean=mean),
+                              k, W=W, mean=mean, msg=msg + " projected")
+
+    oracle(pg, "base")
     # appends, including a big-magnitude block that widens the int8 scale
     blocks = [rng.standard_normal((37, m)).astype(np.float32),
               (rng.standard_normal((20, m)) * 9.0).astype(np.float32),
               rng.standard_normal((150, m)).astype(np.float32)]
     for bl in blocks:
-        seg = seg.append(bl)
         pg = pg.append(bl)
-    _assert_same(pg.search(Qm, k), seg.search(Qm, k), "after appends")
-    _assert_same(pg.search_projected(Qraw, W, k, mean=mean),
-                 seg.search_projected(Qraw, W, k, mean=mean),
-                 "appends projected")
+    oracle(pg, "after appends")
     # promotion and compaction are pointer swaps: results must not move
     ref = pg.search(Qm, k)
+    ref_p = pg.search_projected(Qraw, W, k, mean=mean)
     pg, _ = pg.promote()
     _assert_same(pg.search(Qm, k), ref, "after promote")
     pg, stats = pg.compact_pages()
@@ -239,22 +291,17 @@ def test_paged_parity_lifecycle(quant, backend):
     pg, nev = pg.evict(7)
     assert pg.storage.n_host_pages >= 7, nev
     _assert_same(pg.search(Qm, k), ref, "oversubscribed")
-    _assert_same(pg.search_projected(Qraw, W, k, mean=mean),
-                 seg.search_projected(Qraw, W, k, mean=mean),
+    _assert_same(pg.search_projected(Qraw, W, k, mean=mean), ref_p,
                  "oversubscribed projected")
     # append while oversubscribed. Compaction SEALED the open delta, so a
-    # post-compact int8 append opens a fresh extent with a fresh scale —
-    # compare against the OTHER backend (cross-backend self-parity), not
-    # the never-compacted segmented index.
+    # post-compact int8 append opens a fresh extent with a fresh scale;
+    # the other backend must agree bit for bit
     pg = pg.append(blocks[0])
-    if quant:
-        other = dataclasses.replace(
-            pg, backend="pallas" if backend == "jnp" else "jnp")
-        _assert_same(pg.search(Qm, k), other.search(Qm, k),
-                     "oversub append xbackend")
-    else:
-        _assert_same(pg.search(Qm, k), seg.append(blocks[0]).search(Qm, k),
-                     "oversub append")
+    oracle(pg, "oversub append")
+    other = dataclasses.replace(
+        pg, backend="pallas" if backend == "jnp" else "jnp")
+    _assert_same(pg.search(Qm, k), other.search(Qm, k),
+                 "oversub append xbackend")
 
 
 def test_paged_construction_oversubscription_parity():
@@ -263,65 +310,83 @@ def test_paged_construction_oversubscription_parity():
     Qm = jnp.asarray(rng.standard_normal((5, 24)).astype(np.float32))
     base = DenseIndex.build(jnp.asarray(X), quantize_int8=True,
                             backend="pallas")
-    seg = SegmentedIndex.from_index(base, delta_capacity=96)
     pg = PagedIndex.from_index(base, page_rows=32, pool_pages=6,
                                seal_rows=96, backend="pallas")
     assert pg.storage.n_host_pages > 0
-    _assert_same(pg.search(Qm, 8), seg.search(Qm, 8), "construction oversub")
+    assert_matches_oracle(pg, Qm, pg.search(Qm, 8), 8,
+                          msg="construction oversub")
+    resident = PagedIndex.from_index(base, page_rows=32, seal_rows=96,
+                                     backend="pallas")
+    _assert_same(pg.search(Qm, 8), resident.search(Qm, 8),
+                 "oversubscribed vs resident")
 
 
-def test_paged_from_segmented_adopts_bytes():
+def test_paged_load_adopts_segment_bytes(tmp_path):
+    """A store load adopts every segment's stored bytes and scale as they
+    are, the open delta's requant staging included: continued appends on
+    the loaded index stay bit for bit with the index that was saved,
+    through a scale-widening block."""
     rng = np.random.default_rng(3)
     X = rng.standard_normal((400, 24)).astype(np.float32)
     Qm = jnp.asarray(rng.standard_normal((5, 24)).astype(np.float32))
-    seg = SegmentedIndex.from_index(
+    pg = PagedIndex.from_index(
         DenseIndex.build(jnp.asarray(X), quantize_int8=True),
-        delta_capacity=96)
-    seg = seg.append(rng.standard_normal((130, 24)).astype(np.float32))
-    pg = PagedIndex.from_segmented(seg, page_rows=32)
-    _assert_same(pg.search(Qm, 8), seg.search(Qm, 8), "from_segmented")
+        page_rows=32, seal_rows=96)
+    pg = pg.append(rng.standard_normal((130, 24)).astype(np.float32))
+    save_paged_index(str(tmp_path / "idx"), pg)
+    got = PagedIndex.load(str(tmp_path / "idx"))
+    st, st2 = pg.storage, got.storage
+    assert len(st2.extents) == len(st.extents) == 3
+    for i, (e, e2) in enumerate(zip(st.extents, st2.extents)):
+        np.testing.assert_array_equal(st.extent_rows(i), st2.extent_rows(i))
+        np.testing.assert_array_equal(e.scale, e2.scale)
+        assert (e.kind, e.sealed) == (e2.kind, e2.sealed)
+    _assert_same(got.search(Qm, 8), pg.search(Qm, 8), "loaded")
     # continued appends stay in lockstep, including a widening block
     bl = (rng.standard_normal((25, 24)) * 8.0).astype(np.float32)
-    _assert_same(pg.append(bl).search(Qm, 8), seg.append(bl).search(Qm, 8),
+    _assert_same(got.append(bl).search(Qm, 8), pg.append(bl).search(Qm, 8),
                  "continued append + widen")
 
 
 def test_paged_cascade_rescore_parity():
-    from repro.core.cascade import _cascade_select, _segment_rescore
+    from repro.core.cascade import _cascade_select
     rng = np.random.default_rng(4)
     X = rng.standard_normal((400, 24)).astype(np.float32)
     qf = jnp.asarray(rng.standard_normal((5, 24)).astype(np.float32))
-    seg = SegmentedIndex.from_index(
+    pg = PagedIndex.from_index(
         DenseIndex.build(jnp.asarray(X), quantize_int8=True),
-        delta_capacity=96)
-    seg = seg.append(rng.standard_normal((130, 24)).astype(np.float32))
-    pg = PagedIndex.from_segmented(seg, page_rows=32)
+        page_rows=32, seal_rows=96)
+    pg = pg.append(rng.standard_normal((130, 24)).astype(np.float32))
     uids = jnp.sort(jnp.asarray(
-        rng.choice(seg.n, size=40, replace=False).astype(np.int32)))
-    parts, off = [], seg.base.n
-    segs = [(seg.base.vectors, seg.base.scale, 0, seg.base.n)]
-    for dd in seg.deltas:
-        segs.append((dd.vectors, dd.scale, off, dd.n_real))
-        off += dd.n_real
-    for D, sc, o, nvalid in segs:
-        parts.append(_segment_rescore(D, sc, qf, uids, jnp.int32(o),
-                                      jnp.int32(nvalid)))
-    ref = _cascade_select(tuple(parts), uids, 8)
-    _assert_same(_cascade_select((pg.rescore(qf, uids),), uids, 8), ref,
-                 "paged rescore")
+        rng.choice(pg.n, size=40, replace=False).astype(np.int32)))
+    acc = pg.rescore(qf, uids)
+    # every shortlist uid scores its own exact row within the f32 bound
+    rows = dequantised_rows(pg)
+    q = np.asarray(qf, np.float64)
+    u = np.asarray(uids)
+    ids = np.broadcast_to(u[None, :], (q.shape[0], u.shape[0]))
+    exact = q @ rows[u].T
+    err = np.abs(np.asarray(acc, np.float64) - exact)
+    assert (err <= score_bound(rows, q, np.zeros_like(q), ids)).all()
+    # the selected top-k is the oracle's top-k over the shortlist
+    ref = _cascade_select(acc, uids, 8)
+    ref_s, ref_i = exact_topk(rows[u], q, 8)
+    np.testing.assert_array_equal(np.asarray(ref[1]), u[ref_i])
     # rescore with host-tier pages streams waves, same bits
     pgo, _ = pg.evict(9)
-    _assert_same(_cascade_select((pgo.rescore(qf, uids),), uids, 8), ref,
+    _assert_same(_cascade_select(pgo.rescore(qf, uids), uids, 8), ref,
                  "paged rescore oversubscribed")
 
 
-def test_paged_k_exceeding_n_clamps_like_segmented():
+def test_paged_k_exceeding_n_clamps_to_n():
     rng = np.random.default_rng(5)
     X = rng.standard_normal((5, 24)).astype(np.float32)
     Qm = jnp.asarray(rng.standard_normal((3, 24)).astype(np.float32))
-    small = DenseIndex.build(jnp.asarray(X))
-    _assert_same(PagedIndex.from_index(small, page_rows=32).search(Qm, 50),
-                 SegmentedIndex.from_index(small).search(Qm, 50), "k>n")
+    pg = PagedIndex.from_index(DenseIndex.build(jnp.asarray(X)),
+                               page_rows=32)
+    out = pg.search(Qm, 50)
+    assert out[1].shape == (3, 5)
+    assert_matches_oracle(pg, Qm, out, 50, msg="k>n")
 
 
 def test_paged_lifecycle_zero_recompiles_across_page_counts():
@@ -345,13 +410,13 @@ def test_paged_lifecycle_zero_recompiles_across_page_counts():
         return pg
 
     pg = lifecycle(pg, 48)           # warmup: compile every resident path
-    j0 = segment_jit_cache_size()
+    j0 = _jit_cache_size()
     counts = set()
     for rows in (32, 48, 80, 96):
         pg = lifecycle(pg, rows)
         counts.add(pg.total_pages)
     assert len(counts) > 1, "page count never changed — sweep is vacuous"
-    assert segment_jit_cache_size() == j0, \
+    assert _jit_cache_size() == j0, \
         "page-count growth leaked into a static jit key"
 
 
@@ -475,14 +540,12 @@ def test_paged_store_append_reload_bit_parity(tmp_path):
 def test_paged_store_empty_grown_index_roundtrip(tmp_path):
     """An index grown purely from appends (0-row base) round-trips with
     its open delta intact and keeps accepting appends."""
-    import types
     rng = np.random.default_rng(35)
     m = 24
     Qm = jnp.asarray(rng.standard_normal((5, m)).astype(np.float32))
-    st0 = PagedIndexStorage.from_index(
-        types.SimpleNamespace(vectors=np.zeros((0, m), np.int8),
-                              scale=np.ones(m, np.float32)),
-        page_rows=32, seal_rows=96)
+    st0 = PagedIndexStorage.from_rows(np.zeros((0, m), np.int8),
+                                      np.ones(m, np.float32),
+                                      page_rows=32, seal_rows=96)
     pg = PagedIndex(storage=st0)
     pg = pg.append(rng.standard_normal((40, m)).astype(np.float32))
     p = str(tmp_path / "idx")
@@ -507,8 +570,7 @@ def test_updater_paged_telemetry_and_mirror(tmp_path, quant):
     Qd = jnp.asarray(rng.standard_normal((4, d)).astype(np.float32))
     sp = str(tmp_path / "store")
     u = IndexUpdater.build(corpus, cutoff=0.5, quantize_int8=quant,
-                           store_path=sp, delta_capacity=96,
-                           paged=True, page_rows=32)
+                           store_path=sp, delta_capacity=96, page_rows=32)
     assert isinstance(u.index, PagedIndex)
     assert u.delta_fraction == 0.0
 
@@ -525,7 +587,7 @@ def test_updater_paged_telemetry_and_mirror(tmp_path, quant):
     # delta_fraction counts PAGES on a paged index, not rows
     st = u.index.storage
     assert u.delta_fraction == pytest.approx(st.delta_pages / st.n_slots)
-    # durable mirror auto-detects paged and reloads to the same bits
+    # the durable mirror reloads to the same bits
     u2 = IndexUpdater.from_store(sp)
     assert isinstance(u2.index, PagedIndex)
     _assert_same(srch(u2), srch(u), "mirror reload")
@@ -572,7 +634,7 @@ def test_paged_swap_soak_append_promote_compact():
     server = RetrievalServer(pg, pruner, k=1, max_batch=8, pipeline_depth=3)
     up = IndexUpdater(pruner=pruner, index=pg, server=server)
     try:
-        assert isinstance(up.index, PagedIndex)   # no segmented rewrap
+        assert isinstance(up.index, PagedIndex)
         up.add_documents(jnp.asarray(extra[:8]))
         up.add_documents(jnp.asarray(0.5 * extra[:8]))
         server.query(D[0])

@@ -1,17 +1,18 @@
-"""Segmented live index: parity, mixed scales, swap-under-load, layout.
+"""Live growth on the paged index: extents, scales, store segments, swaps.
 
-The acceptance surface of the segment architecture:
-  * ``SegmentedIndex.search`` is BIT-IDENTICAL to a monolithic index built
-    from the concatenated corpus when every segment shares one scale —
-    dense and sharded base, f32 and int8, jnp and pallas backends;
-  * with mixed per-segment scales, ids/ordering exactly match an f32
-    oracle over the per-segment dequantised vectors;
-  * appends never clip (per-delta scales widen) and never recompile in
-    steady state (fixed-capacity dispatch, jit-cache-size pinned);
-  * a pre-segment artifact opens as a single base segment (backward
-    compat) and a segmented artifact round-trips losslessly;
-  * ``RetrievalServer.swap_index`` under live append+query load drops no
-    reply and never serves from a half-swapped segment set.
+The acceptance surface of live growth (``PagedIndex`` extents mirrored to
+``IndexStore`` segments):
+  * with mixed per-extent scales, ids/ordering match a float64 oracle over
+    the per-extent dequantised rows;
+  * appends roll over into fresh extents at ``seal_rows`` with global ids
+    in order, never clip (per-extent scales widen) and never recompile in
+    steady state (jit-cache-size pinned);
+  * a pre-segment artifact opens as a single base extent holding the
+    stored bytes, and a grown artifact round-trips losslessly;
+  * ``IndexUpdater`` mirrors every append to the store bit for bit,
+    compacts by pointer swaps (racing appends included), and
+    ``RetrievalServer.swap_index`` under live append+query load drops no
+    reply and never serves from a half-swapped index.
 """
 import os
 import threading
@@ -21,18 +22,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paged_oracle import assert_matches_oracle
 from repro.core import (
-    DeltaSegment,
     DenseIndex,
     IndexStore,
     IndexStoreError,
-    SegmentedIndex,
     ShardedDenseIndex,
     StaticPruner,
     save_index,
 )
-from repro.core.index import segment_jit_cache_sizes
 from repro.core.maintenance import IndexUpdater
+from repro.core.paged import PagedIndex, _jit_cache_sizes
 from repro.core.quantization import quantize_int8_per_dim
 
 RNG = np.random.default_rng(17)
@@ -55,142 +55,31 @@ def _mesh(ndev):
     return jax.make_mesh((ndev,), ("data",))
 
 
-def _shared_scale_segmented(D, splits, *, quantize, backend="jnp",
-                            mesh=None, capacity=256):
-    """Segment a corpus at ``splits`` with ONE shared scale (the parity
-    construction: same quantised bytes as the monolithic index)."""
-    if quantize:
-        q8, scale = quantize_int8_per_dim(jnp.asarray(D))
-        stored = np.asarray(q8)
-        raw = stored.astype(np.float32) * np.asarray(scale)[None, :]
-    else:
-        stored, scale = np.asarray(D, np.float32), None
-        raw = stored
-    lo = splits[0]
-    if mesh is not None:
-        base = ShardedDenseIndex(
-            vectors=jax.device_put(
-                jnp.asarray(_pad_rows(stored[:lo], mesh)),
-                jax.sharding.NamedSharding(
-                    mesh, jax.sharding.PartitionSpec(("data",), None))),
-            mesh=mesh, scale=scale, backend=backend, n_real=lo)
-    else:
-        base = DenseIndex(vectors=jnp.asarray(stored[:lo]), scale=scale,
-                          backend=backend)
-    deltas = []
-    bounds = list(splits) + [len(D)]
-    for a, b in zip(bounds, bounds[1:]):
-        seg = np.zeros((capacity, D.shape[1]), stored.dtype)
-        seg[:b - a] = stored[a:b]
-        deltas.append(DeltaSegment(vectors=jnp.asarray(seg), n_real=b - a,
-                                   scale=scale, raw=raw[a:b]))
-    return SegmentedIndex(base=base, deltas=tuple(deltas),
-                          delta_capacity=capacity)
-
-
-def _pad_rows(v, mesh):
-    ndev = int(np.prod(mesh.devices.shape))
-    pad = (-v.shape[0]) % ndev
-    return np.concatenate([v, np.zeros((pad, v.shape[1]), v.dtype)]) \
-        if pad else v
+def _delta_extents(index):
+    return [e for e in index.storage.extents if e.kind == "delta"]
 
 
 # ---------------------------------------------------------------------------
-# parity: segmented == monolithic, bit for bit, when scales agree
+# mixed scales: the oracle over per-extent dequantised rows
 # ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
-@pytest.mark.parametrize("quantize", [False, True])
-def test_dense_parity_bit_identical(backend, quantize):
-    D = _corpus(703, 32)
-    Q = _queries(32)
-    seg = _shared_scale_segmented(D, (500, 650), quantize=quantize,
-                                  backend=backend)
-    if quantize:
-        mono = DenseIndex(vectors=jnp.asarray(
-            np.concatenate([np.asarray(seg.base.vectors)]
-                           + [np.asarray(d.vectors[:d.n_real])
-                              for d in seg.deltas])),
-            scale=seg.base.scale, backend=backend)
-    else:
-        mono = DenseIndex.build(jnp.asarray(D), backend=backend)
-    s0, i0 = mono.search(Q, k=10)
-    s1, i1 = seg.search(Q, k=10)
-    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
-    np.testing.assert_array_equal(np.asarray(s0), np.asarray(s1))
-
-
-@pytest.mark.parametrize("ndev", [1, 4])
-@pytest.mark.parametrize("quantize", [False, True])
-def test_sharded_base_parity_bit_identical(ndev, quantize):
-    """Sharded base + dense deltas vs a fully-sharded monolithic index —
-    uneven rows, so device padding and delta padding coexist."""
-    mesh = _mesh(ndev)
-    D = _corpus(1003, 32)
-    Q = _queries(32)
-    seg = _shared_scale_segmented(D, (801, 950), quantize=quantize, mesh=mesh)
-    if quantize:
-        q8, scale = quantize_int8_per_dim(jnp.asarray(D))
-        mono = ShardedDenseIndex(
-            vectors=jax.device_put(
-                jnp.asarray(_pad_rows(np.asarray(q8), mesh)),
-                jax.sharding.NamedSharding(
-                    mesh, jax.sharding.PartitionSpec(("data",), None))),
-            mesh=mesh, scale=scale, n_real=D.shape[0])
-    else:
-        mono = ShardedDenseIndex.build(jnp.asarray(D), mesh)
-    s0, i0 = mono.search(Q, k=10)
-    s1, i1 = seg.search(Q, k=10)
-    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
-    np.testing.assert_array_equal(np.asarray(s0), np.asarray(s1))
-
-
-def test_search_projected_parity_bit_identical():
-    """Raw-query path: shared projection + per-segment fold must equal the
-    monolithic fused search_projected dispatch bit-for-bit."""
-    D = _corpus(703, 32)
-    pruner = StaticPruner(cutoff=0.5).fit(jnp.asarray(D))
-    Dh = np.asarray(pruner.prune_index(jnp.asarray(D)), np.float32)
-    Q = _queries(32)
-    W, mean = pruner.projection()
-    seg = _shared_scale_segmented(Dh, (500, 650), quantize=True)
-    mono = DenseIndex(vectors=jnp.asarray(np.concatenate(
-        [np.asarray(seg.base.vectors)]
-        + [np.asarray(d.vectors[:d.n_real]) for d in seg.deltas])),
-        scale=seg.base.scale)
-    s0, i0 = mono.search_projected(Q, W, k=10, mean=mean)
-    s1, i1 = seg.search_projected(Q, W, k=10, mean=mean)
-    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
-    np.testing.assert_array_equal(np.asarray(s0), np.asarray(s1))
 
 
 def test_mixed_scale_ids_match_f32_oracle():
-    """Per-segment scales (an OOD append widened the delta's): ids and
-    ordering must exactly match exact f32 search over the per-segment
-    DEQUANTISED vectors — strict correctness, not best-effort."""
+    """Per-extent scales (an OOD append widened the delta's): ids and
+    ordering must exactly match the float64 oracle over the per-extent
+    DEQUANTISED rows — strict correctness, not best-effort."""
     D = _corpus(600, 32)
     base8, base_scale = quantize_int8_per_dim(jnp.asarray(D))
     base = DenseIndex(vectors=base8, scale=base_scale)
-    seg = SegmentedIndex.from_index(base, delta_capacity=128)
+    pg = PagedIndex.from_index(base, page_rows=64, seal_rows=128)
     ood = np.concatenate([_corpus(80, 32, seed=9) * 12.0,
                           _corpus(40, 32, seed=11)])
-    seg = seg.append(ood)
-    assert len(seg.deltas) == 1
-    assert not np.array_equal(np.asarray(seg.deltas[0].scale),
-                              np.asarray(base_scale))
-    # oracle: dequantise every segment with ITS scale, exact f32 search
-    dq = [np.asarray(base8, np.float32) * np.asarray(base_scale)[None, :]]
-    for d in seg.deltas:
-        dq.append(np.asarray(d.vectors[:d.n_real], np.float32)
-                  * np.asarray(d.scale)[None, :])
-    oracle = DenseIndex.build(jnp.asarray(np.concatenate(dq)))
+    pg = pg.append(ood)
+    deltas = _delta_extents(pg)
+    assert len(deltas) == 1
+    assert not np.array_equal(deltas[0].scale, np.asarray(base_scale))
     Q = _queries(32)
-    so, io = oracle.search(Q, k=10)
-    s1, i1 = seg.search(Q, k=10)
-    np.testing.assert_array_equal(np.asarray(io), np.asarray(i1))
-    np.testing.assert_allclose(np.asarray(so), np.asarray(s1),
-                               rtol=1e-5, atol=1e-5)
+    assert_matches_oracle(pg, Q, pg.search(Q, k=10), 10)
 
 
 # ---------------------------------------------------------------------------
@@ -200,15 +89,17 @@ def test_mixed_scale_ids_match_f32_oracle():
 
 def test_append_rollover_and_global_ids():
     D = _corpus(500, 24)
-    seg = SegmentedIndex.from_index(DenseIndex.build(jnp.asarray(D)),
-                                    delta_capacity=100)
+    pg = PagedIndex.from_index(DenseIndex.build(jnp.asarray(D)),
+                               page_rows=32, seal_rows=100)
     extra = _corpus(750, 24, seed=5)[500:]
-    seg = seg.append(extra)
-    assert seg.n == 750
-    assert len(seg.deltas) == 3                 # 100 + 100 + 50
-    assert [d.n_real for d in seg.deltas] == [100, 100, 50]
+    pg = pg.append(extra)
+    assert pg.n == 750
+    deltas = _delta_extents(pg)
+    assert [e.n_rows for e in deltas] == [100, 100, 50]   # seal, roll over
+    assert [e.row_offset for e in deltas] == [500, 600, 700]
+    assert [e.sealed for e in deltas] == [True, True, False]
     for gid in (500, 601, 749):
-        _, ids = seg.search(jnp.asarray(extra[gid - 500][None, :]), k=5)
+        _, ids = pg.search(jnp.asarray(extra[gid - 500][None, :]), k=5)
         assert gid in np.asarray(ids)[0].tolist()
 
 
@@ -221,12 +112,14 @@ def test_ood_append_widens_scale_never_clips():
                             delta_capacity=256)
     in_dom = _corpus(500, 24, domain_seed=5)[400:480]   # same encoder basis
     up.add_documents(jnp.asarray(in_dom))
-    scale0 = np.asarray(up.index.deltas[0].scale)
+    scale0 = _delta_extents(up.index)[0].scale
     up.add_documents(50.0 * jnp.asarray(in_dom[:40]))
-    d = up.index.deltas[0]
-    scale1 = np.asarray(d.scale)
+    st = up.index.storage
+    ei = st.extents.index(_delta_extents(up.index)[0])
+    d = st.extents[ei]
+    scale1 = d.scale
     assert (scale1 >= scale0).all() and (scale1 > scale0).any()
-    stored = np.asarray(d.vectors[:d.n_real], np.float32)
+    stored = st.extent_rows(ei).astype(np.float32)
     err = np.abs(stored * scale1[None, :] - d.raw)
     assert (err <= scale1[None, :] / 2 + 1e-7).all(), \
         "a stored value clipped instead of the scale widening"
@@ -238,33 +131,31 @@ def test_ood_append_widens_scale_never_clips():
 
 
 def test_steady_state_appends_do_not_recompile():
-    """Fixed-capacity dispatch contract: once the segment shapes are warm,
-    appends (any live count) add ZERO jit cache entries."""
+    """Fixed-shape dispatch contract: once the page walk is warm, appends
+    (any live count, widening or not) add ZERO jit cache entries."""
     D = _corpus(300, 24)
     pruner = StaticPruner(cutoff=0.5).fit(jnp.asarray(D))
-    seg = SegmentedIndex.from_index(
+    pg = PagedIndex.from_index(
         pruner.build_index(jnp.asarray(D), quantize_int8=True),
-        delta_capacity=512)
+        page_rows=64, seal_rows=512)
     W, mean = pruner.projection()
-    # warm: open the delta, then extend once at the steady-state block
-    # size with rows that provably cannot widen the scale (0.5x rows
-    # already in the delta) — both extend paths (widen = plain host
-    # requant+upload, non-widen = the update-slice jit) are then warm
+    # warm: open the delta, then extend once (appends themselves are
+    # host-side writes re-pushed at fixed shapes)
     warm = np.asarray(pruner.prune_index(
         jnp.asarray(_corpus(20, 24, seed=7))), np.float32)
-    seg = seg.append(warm)
-    seg = seg.append(0.5 * warm[:15])
+    pg = pg.append(warm)
+    pg = pg.append(0.5 * warm[:15])
     jax.block_until_ready(
-        seg.search_projected(jnp.asarray(_queries(24, 4)), W, k=5,
-                             mean=mean))
-    j0 = segment_jit_cache_sizes()
+        pg.search_projected(jnp.asarray(_queries(24, 4)), W, k=5,
+                            mean=mean))
+    j0 = _jit_cache_sizes()
     for i in range(6):
-        seg = seg.append(np.asarray(pruner.prune_index(
+        pg = pg.append(np.asarray(pruner.prune_index(
             jnp.asarray(_corpus(15, 24, seed=20 + i))), np.float32))
         jax.block_until_ready(
-            seg.search_projected(jnp.asarray(_queries(24, 4)), W, k=5,
-                                 mean=mean))
-    assert segment_jit_cache_sizes() == j0, \
+            pg.search_projected(jnp.asarray(_queries(24, 4)), W, k=5,
+                                mean=mean))
+    assert _jit_cache_sizes() == j0, \
         "an append recompiled the steady-state search path"
 
 
@@ -275,8 +166,8 @@ def test_steady_state_appends_do_not_recompile():
 
 def test_pre_segment_artifact_opens_as_single_base(tmp_path):
     """Backward compat: an artifact written before segments exist reads as
-    one base segment, and SegmentedIndex.load serves it bit-identically to
-    the flat loader."""
+    one base segment, and PagedIndex.load pages exactly its stored bytes
+    (and scale) as one base extent."""
     D = _corpus(500, 32)
     pruner = StaticPruner(cutoff=0.5).fit(jnp.asarray(D))
     idx = pruner.build_index(jnp.asarray(D), quantize_int8=True)
@@ -285,14 +176,16 @@ def test_pre_segment_artifact_opens_as_single_base(tmp_path):
     views = store.segments()
     assert len(views) == 1 and views[0].kind == "base"
     assert views[0].n == store.n and views[0].offset == 0
-    seg = SegmentedIndex.load(store)
-    flat = DenseIndex.load(IndexStore.open(store.path))
+    pg = PagedIndex.load(store)
+    assert [(e.kind, e.sealed) for e in pg.storage.extents] == [("base",
+                                                                True)]
+    np.testing.assert_array_equal(pg.storage.extent_rows(0),
+                                  store.read_rows(0, store.n))
+    np.testing.assert_array_equal(pg.storage.extents[0].scale,
+                                  store.scale())
     Q = _queries(32)
     qh = pruner.transform_queries(Q)
-    s0, i0 = flat.search(qh, k=10)
-    s1, i1 = seg.search(qh, k=10)
-    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
-    np.testing.assert_array_equal(np.asarray(s0), np.asarray(s1))
+    assert_matches_oracle(pg, qh, pg.search(qh, k=10), 10)
 
 
 @pytest.mark.parametrize("quantize", [False, True])
@@ -301,22 +194,22 @@ def test_segmented_store_round_trip(tmp_path, quantize):
     results survive; the manifest's global view stays validation-clean."""
     D = _corpus(600, 32)
     pruner = StaticPruner(cutoff=0.5).fit(jnp.asarray(D))
-    seg = SegmentedIndex.from_index(
+    pg = PagedIndex.from_index(
         pruner.build_index(jnp.asarray(D), quantize_int8=quantize),
-        delta_capacity=128)
+        page_rows=64, seal_rows=128)
     extra = _corpus(800, 32, seed=5)[600:]
-    seg = seg.append(np.asarray(pruner.prune_index(jnp.asarray(extra)),
-                                np.float32))
-    store = save_index(str(tmp_path / "st"), seg, pruner=pruner)
+    pg = pg.append(np.asarray(pruner.prune_index(jnp.asarray(extra)),
+                              np.float32))
+    store = save_index(str(tmp_path / "st"), pg, pruner=pruner)
     re = IndexStore.open(store.path)            # fresh open: full validation
     assert re.is_segmented and re.n == 800
     assert [v.kind for v in re.segments()] == ["base", "delta", "delta"]
     assert re.segments()[1].capacity == 128
-    loaded = SegmentedIndex.load(re)
-    assert loaded.n == seg.n
+    loaded = PagedIndex.load(re)
+    assert loaded.n == pg.n
     Q = _queries(32)
     qh = pruner.transform_queries(Q)
-    s0, i0 = seg.search(qh, k=10)
+    s0, i0 = pg.search(qh, k=10)
     s1, i1 = loaded.search(qh, k=10)
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
     np.testing.assert_array_equal(np.asarray(s0), np.asarray(s1))
@@ -330,10 +223,10 @@ def test_mixed_scale_store_refuses_flat_load(tmp_path):
     up.add_documents(9.0 * jnp.asarray(_corpus(40, 24, seed=5)))
     st = IndexStore.open(str(tmp_path / "st"))
     assert not st.flat_loadable
-    with pytest.raises(IndexStoreError, match="SegmentedIndex.load"):
+    with pytest.raises(IndexStoreError, match="PagedIndex.load"):
         DenseIndex.load(st)
     mesh = _mesh(1)
-    with pytest.raises(IndexStoreError, match="SegmentedIndex.load"):
+    with pytest.raises(IndexStoreError, match="PagedIndex.load"):
         ShardedDenseIndex.load(st, mesh)
 
 
@@ -349,11 +242,12 @@ def test_updater_store_mirror_is_bit_identical(tmp_path):
     up.add_documents(jnp.asarray(_corpus(20, 24, seed=7)))
     st = IndexStore.open(str(tmp_path / "st"))
     views = st.segments()
-    assert len(views) == 1 + len(up.index.deltas)
-    for v, d in zip(views[1:], up.index.deltas):
+    pst = up.index.storage
+    assert len(views) == len(pst.extents) > 1
+    for i, (v, e) in enumerate(zip(views, pst.extents)):
         np.testing.assert_array_equal(v.read_rows(0, v.n),
-                                      np.asarray(d.vectors[:d.n_real]))
-        np.testing.assert_array_equal(v.scale(), np.asarray(d.scale))
+                                      pst.extent_rows(i))
+        np.testing.assert_array_equal(v.scale(), e.scale)
     # and a cold start reproduces the exact same search results
     up2 = IndexUpdater.from_store(str(tmp_path / "st"))
     Q = _queries(24)
@@ -410,7 +304,7 @@ def test_store_append_migrating_base_segment(tmp_path):
     np.testing.assert_array_equal(np.load(
         os.path.join(re.path, re.manifest["scale_file"])), base.scale())
     # still servable end to end
-    loaded = SegmentedIndex.load(re)
+    loaded = PagedIndex.load(re)
     _, ids = loaded.search(pruner.transform_queries(_queries(16)), k=5)
     assert np.asarray(ids).max() < 305
 
@@ -439,20 +333,32 @@ def test_replace_segment_crash_orphans_ignored(tmp_path):
 
 
 def test_compact_merges_to_single_fresh_base(tmp_path):
+    """Compaction folds every delta extent into the base by pointer swaps:
+    the pages move to the pool, each extent keeps its bytes and scale, and
+    on disk only the lifecycle block changes."""
     D = _corpus(500, 24)
     up = IndexUpdater.build(jnp.asarray(D), cutoff=0.5, quantize_int8=True,
                             store_path=str(tmp_path / "st"),
-                            delta_capacity=128)
+                            delta_capacity=128, page_rows=32)
     extra = _corpus(700, 24, seed=5)[500:]
     up.add_documents(jnp.asarray(extra))
     assert up.delta_fraction > 0
+    before = up.index.storage
     up.compact()
-    assert len(up.index.deltas) == 0 and up.index.n == 700
+    st = up.index.storage
+    assert up.index.delta_pages == 0 and up.index.n == 700
     assert up.compactions == 1 and up.delta_fraction == 0.0
-    st = IndexStore.open(str(tmp_path / "st"))
-    assert len(st.segments()) == 1 and st.n == 700
+    assert up.last_compaction["pages_moved"] > 0
+    for i, e in enumerate(st.extents):
+        assert e.kind == "base" and e.sealed
+        np.testing.assert_array_equal(st.extent_rows(i),
+                                      before.extent_rows(i))
+        np.testing.assert_array_equal(e.scale, before.extents[i].scale)
+    re = IndexStore.open(str(tmp_path / "st"))
+    assert re.n == 700
+    assert all(e["kind"] == "base" for e in re.manifest["paged"]["extents"])
     assert not os.path.exists(str(tmp_path / "st") + ".tmp")
-    # every doc still retrievable under the fresh corpus-wide scale
+    # every doc still retrievable
     _, ids = up.search(jnp.asarray(D[123][None, :]), k=3)
     assert 123 in np.asarray(ids)[0].tolist()
     # further appends land on the compacted base's store
@@ -460,51 +366,18 @@ def test_compact_merges_to_single_fresh_base(tmp_path):
     assert IndexStore.open(str(tmp_path / "st")).n == 730
 
 
-def test_refit_preserves_sharded_base():
-    """A drift-triggered refit on a sharded deployment must rebuild the
-    base on the SAME mesh, not collapse it onto one device."""
-    mesh = _mesh(4)
-    D = _corpus(400, 32)
-    pruner = StaticPruner(cutoff=0.5).fit(jnp.asarray(D))
-    base = pruner.build_index(jnp.asarray(D), mesh=mesh, quantize_int8=True)
-    up = IndexUpdater(pruner=pruner, index=base, delta_capacity=128)
-    shifted = _corpus(500, 32, seed=9)
-    up.refit(jnp.asarray(shifted))
-    assert isinstance(up.index.base, ShardedDenseIndex)
-    assert up.index.base.mesh is mesh
-    assert up.index.base.vectors.dtype == jnp.int8
-    assert up.index.n == 500
-    _, ids = up.search(jnp.asarray(shifted[:3]), k=5)
-    assert np.asarray(ids).max() < 500
-
-
 def test_compact_reconciles_racing_appends():
-    """Appends that land while a compaction streams must survive the swap:
-    the tail rows re-append onto the fresh base."""
-    import time
+    """Appends that race a background compaction must survive it: the
+    compaction and the append serialise on the updater lock, so whichever
+    lands second builds on the first and no row is lost."""
     D = _corpus(400, 24)
     up = IndexUpdater.build(jnp.asarray(D), cutoff=0.5, delta_capacity=256)
     up.add_documents(jnp.asarray(_corpus(50, 24, seed=5)))
     racing = _corpus(30, 24, seed=6)
-
-    orig_iter = up._iter_dequant_rows
-    started = threading.Event()
-
-    def slow_iter(index, block_rows, store):
-        for blk in orig_iter(index, block_rows, store):
-            started.set()
-            time.sleep(0.02)                 # hold the stream open
-            yield blk
-
-    up._iter_dequant_rows = slow_iter
-    try:
-        th = up.compact_async(block_rows=40)
-        assert started.wait(30.0)
-        up.add_documents(jnp.asarray(racing))   # lands mid-stream
-        th.join(timeout=60.0)
-        assert not th.is_alive()
-    finally:
-        up._iter_dequant_rows = orig_iter
+    th = up.compact_async()
+    up.add_documents(jnp.asarray(racing))        # races the compaction
+    th.join(timeout=60.0)
+    assert not th.is_alive()
     assert up.index.n == 480
     assert up.compactions == 1
     # the racing rows answer exactly as in a brute-force search over all
@@ -542,21 +415,18 @@ def test_swap_under_load_soak():
     extra = _unit_corpus(200, seed=78)
     pruner = StaticPruner(cutoff=0.25).fit(jnp.asarray(D))
     base = DenseIndex.build(pruner.prune_index(jnp.asarray(D)))
-    seg = SegmentedIndex.from_index(base, delta_capacity=4096)
-    server = RetrievalServer(seg, pruner, k=1, max_batch=8, pipeline_depth=3)
-    up = IndexUpdater(pruner=pruner, index=seg, server=server,
+    pg = PagedIndex.from_index(base, seal_rows=4096)
+    server = RetrievalServer(pg, pruner, k=1, max_batch=8, pipeline_depth=3)
+    up = IndexUpdater(pruner=pruner, index=pg, server=server,
                       delta_capacity=4096)
     try:
-        # warm every steady-state shape: open the delta, then extend once
-        # at the soak's block size with rows that provably cannot widen
-        # the scale (0.5x rows already present — their per-dim absmax is
-        # strictly covered), so the non-widen update-slice jit compiles
-        # HERE, not mid-soak. Those 8 scaled rows get ids 104..111; the
+        # warm the steady state: open the delta, extend it once, and
+        # answer a query. Those 8 scaled rows get ids 104..111; the
         # clients below never query them.
         up.add_documents(jnp.asarray(extra[:8]))
         up.add_documents(jnp.asarray(0.5 * extra[:8]))
         server.query(D[0])
-        j0 = segment_jit_cache_sizes()
+        j0 = _jit_cache_sizes()
         swaps0 = server.swap_count
         n_known = 96 + 8                     # rows safe to self-retrieve
 
@@ -594,7 +464,7 @@ def test_swap_under_load_soak():
         app.join(timeout=30.0)
         assert not failures, f"misrouted/dropped replies: {failures[:5]}"
         assert server.swap_count > swaps0, "appends never swapped the index"
-        assert segment_jit_cache_sizes() == j0, \
+        assert _jit_cache_sizes() == j0, \
             "live appends recompiled the serving path"
         # every appended doc is now retrievable through the server
         n_final = up.index.n
@@ -620,9 +490,9 @@ def test_swap_during_compaction_under_traffic():
     pruner = StaticPruner(cutoff=0.25).fit(jnp.asarray(D))
     base = DenseIndex.build(pruner.prune_index(jnp.asarray(D)),
                             quantize_int8=True)
-    seg = SegmentedIndex.from_index(base, delta_capacity=1024)
-    server = RetrievalServer(seg, pruner, k=1, max_batch=8, pipeline_depth=3)
-    up = IndexUpdater(pruner=pruner, index=seg, server=server,
+    pg = PagedIndex.from_index(base, seal_rows=1024)
+    server = RetrievalServer(pg, pruner, k=1, max_batch=8, pipeline_depth=3)
+    up = IndexUpdater(pruner=pruner, index=pg, server=server,
                       delta_capacity=1024)
     try:
         up.add_documents(jnp.asarray(extra))
@@ -637,7 +507,7 @@ def test_swap_during_compaction_under_traffic():
             ok += 1
         th.join(timeout=60.0)
         assert server.swap_count == swaps_before + 1
-        assert len(up.index.deltas) == 0
+        assert up.index.delta_pages == 0
         for doc in (0, 95, 96, 159):
             q = D[doc] if doc < 96 else extra[doc - 96]
             _, ids = server.query(q, timeout=30.0)
@@ -664,27 +534,21 @@ def test_reply_carries_completion_timestamp():
 
 
 # ---------------------------------------------------------------------------
-# config layer: retrieval_cand live-delta cell
+# serve CLI: paged storage has no sharded form yet
 # ---------------------------------------------------------------------------
 
 
-def test_retrieval_cand_delta_rows_bundle():
-    """The serving-config cell wires the same cross-segment merge: base
-    sharded over the mesh + one replicated delta with its own scale and a
-    traced live count."""
-    import dataclasses
-    from repro.configs.registry import get_arch
-    from repro.configs.steps import BUNDLE_BUILDERS
-    if jax.device_count() < 4:
-        pytest.skip("needs 4 devices")
-    spec = get_arch("two-tower-retrieval")
-    cell = spec.cell("retrieval_cand")
-    cell = dataclasses.replace(cell, dims={**cell.dims,
-                                           "n_candidates": 2048,
-                                           "index_dim": 32, "int8": 1,
-                                           "delta_rows": 256})
-    mesh = jax.make_mesh((2, 2), ("dp", "model"))
-    bundle = BUNDLE_BUILDERS[spec.family](spec, cell, mesh)
-    assert bundle.meta["delta_rows"] == 256
-    out_s, out_i = jax.eval_shape(bundle.fn, *bundle.args)
-    assert out_s.shape == out_i.shape == (1, 100)
+@pytest.mark.parametrize("flag,value", [("--live-append", "100"),
+                                        ("--page-pool", "4")])
+def test_paged_flags_refuse_sharded(monkeypatch, capsys, flag, value):
+    """``--live-append`` and ``--page-pool`` select the paged index, which
+    has no per-shard pools: with ``--sharded`` the CLI refuses before it
+    builds anything."""
+    import sys
+    from repro.launch import serve
+    monkeypatch.setattr(sys, "argv", ["serve", flag, value, "--sharded"])
+    with pytest.raises(SystemExit) as exc:
+        serve.main()
+    assert exc.value.code == 2
+    assert (f"{flag} does not compose with --sharded yet"
+            in capsys.readouterr().err)
