@@ -16,7 +16,6 @@ Scores are always accumulated in fp32 regardless of index dtype.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import os
 from functools import partial
@@ -123,7 +122,7 @@ BUCKET = 128   # rows per select bucket: one lane width
 
 
 def _select_plan(k: int, block: int) -> int:
-    """Bucket width for ``_scan_topk``'s per-strip select, 0 for a direct
+    """Bucket width for ``_scan_topk``'s deferred select, 0 for a direct
     ``top_k`` over the strip.
 
     Bucketing pays when the k buckets it keeps are a small share of the
@@ -136,77 +135,127 @@ def _select_plan(k: int, block: int) -> int:
     return 0
 
 
-def _bucket_candidates(s3: jax.Array, bmax: jax.Array, at: jax.Array,
-                       k: int) -> tuple[jax.Array, jax.Array]:
-    """The (B, k·W) scores and ids of the k buckets with the largest maxima.
+def _score_strip(D: jax.Array, qt: jax.Array, start: jax.Array, block: int
+                 ) -> tuple[jax.Array, jax.Array]:
+    """The (B, block) scores of the strip that starts at row ``start``, and
+    the row it is read from. The last strip slides back to end at row n;
+    rows below ``start``, which the previous strip scored, read -inf."""
+    n = D.shape[0]
+    with jax.named_scope("score"):
+        at = jnp.minimum(start, n - block)
+        blk = jax.lax.dynamic_slice_in_dim(D, at, block, axis=0)
+        s = score_rows(qt, blk)                                  # (B, block)
+        ids = at + jnp.arange(block, dtype=jnp.int32)[None, :]
+        return jnp.where(ids >= start, s, -jnp.inf), at
 
-    ``s3`` is the strip as (B, block / W, W) buckets of W contiguous rows,
-    ``bmax`` each bucket's max. A strip entry outside the kept buckets is
-    preceded, in (score desc, id asc) order, by the max of each of the k
-    kept ones (``top_k`` breaks equal maxima towards the lower bucket), so
-    the strip's top-k lies in them. Kept buckets go in ascending order, so
-    candidate ids ascend and ``top_k``'s first-occurrence tie-break sees
-    them as it sees the strip.
+
+def _deferred_select(D: jax.Array, qt: jax.Array, k: int, block: int,
+                     W: int, starts: jax.Array
+                     ) -> tuple[jax.Array, jax.Array]:
+    """The bucketed scan: each strip leaves only its bucket maxima, and the
+    select runs once, after the last strip.
+
+    ``select/bucket``: the strip's (B, block / W) maxima over buckets of W
+    contiguous rows, stacked strip by strip. ``select/refine``: one
+    ``top_k`` over every maximum of the scan picks k buckets, which are
+    rescored slot by slot (B buckets of W rows at a time, by the strip's
+    own ``score_rows``) and masked as their strip was; one ``top_k`` over
+    the (B, k·W) candidates is the result.
+
+    Exact: an entry outside the kept buckets is preceded, in (score desc,
+    id asc) order, by the max of each kept one. ``top_k`` breaks equal
+    maxima towards the lower bucket, and buckets in strip-major order hold
+    ascending ids (the sliding last strip's rows at or above its start
+    exceed every earlier strip's), so the kept buckets, put in ascending
+    order, give ``top_k`` its candidates as the scan saw them.
     """
-    B, _, W = s3.shape
-    _, top = jax.lax.top_k(bmax, k)                            # (B, k)
-    top = jnp.sort(top, axis=1)
-    cs = jnp.take_along_axis(s3, top[:, :, None], axis=1)      # (B, k, W)
-    ci = at + top[:, :, None] * W + jnp.arange(W, dtype=jnp.int32)
-    return cs.reshape(B, k * W), ci.reshape(B, k * W)
+    n, d = D.shape
+    nb = block // W
+
+    def strip(_, start):
+        s, _ = _score_strip(D, qt, start, block)
+        with jax.named_scope("select"), jax.named_scope("bucket"):
+            return None, s.reshape(-1, nb, W).max(axis=2)        # (B, nb)
+
+    _, bmax = jax.lax.scan(strip, None, starts)         # (nblocks, B, nb)
+
+    with jax.named_scope("select"), jax.named_scope("refine"):
+        B = bmax.shape[1]
+        bmax = jnp.moveaxis(bmax, 0, 1).reshape(B, -1)
+        _, top = jax.lax.top_k(bmax, k)                          # (B, k)
+        top = jnp.sort(top, axis=1)
+        start = (top // nb) * block
+        row0 = jnp.minimum(start, n - block) + (top % nb) * W
+        rows = jnp.arange(W, dtype=jnp.int32)
+        diag = jnp.arange(B)
+
+        def rescore(_, slot):
+            r0, st = slot                                        # (B,), (B,)
+            ids = r0[:, None] + rows                             # (B, W)
+            blk = D.at[ids.reshape(-1)].get(mode="promise_in_bounds")
+            s = score_rows(qt, blk)                              # (B, B·W)
+            s = s.reshape(B, B, W)[diag, diag]                   # (B, W)
+            return None, (jnp.where(ids >= st[:, None], s, -jnp.inf), ids)
+
+        _, (cs, ci) = jax.lax.scan(rescore, None, (row0.T, start.T))
+        cs = jnp.moveaxis(cs, 0, 1).reshape(B, k * W)
+        ci = jnp.moveaxis(ci, 0, 1).reshape(B, k * W)
+        return _topk_merge(cs, ci, k)
 
 
 @partial(jax.jit, static_argnames=("k", "block", "vma_axes", "guard"))
 def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
                vma_axes: tuple[str, ...] | None = None, guard: str = "row"
                ) -> tuple[jax.Array, jax.Array]:
-    """Blocked exact search: stream row blocks of D, keep a running top-k.
+    """Blocked exact search: stream row blocks of D, select the top-k.
 
     Never materialises the full (B, n) score matrix — the jnp analogue of
-    the Pallas fused kernel, and the oracle it is tested against. Mirrors
-    the kernel's structure:
+    the Pallas fused kernel, and the oracle it is tested against:
 
       * the index blocks keep their storage dtype (int8 stays int8 in the
-        scan carry's xs) and each is scored by ``score_rows`` as the
-        rows' dtype selects: f32 rows by an f32 matmul at ``HIGHEST``;
-        int8 or bf16 rows, which bf16 holds exactly, convert to bf16 and
-        meet the query's three-term bf16 split (``query_terms``, made
-        once before the loop) in one matmul with f32 accumulation — no
-        full-index shadow copy, no strip in f32;
-      * exact bucketed select (``_select_plan``: k·W ≤ block / 8 with
-        W = 128): the strip's per-bucket maxima (B, block / W) pick the k
-        buckets that must hold its top-k; one ``top_k`` over the running
-        list followed by those buckets' (B, k·W) scores merges them —
-        never a sort over the whole strip. Otherwise ``top_k`` over the
-        strip alone, then a tiny (B, 2k) merge with the running list.
-        Both give the same scores and ids, ties included;
-      * block-skip guard: a strip that cannot improve the running top-k
-        skips selection entirely under ``lax.cond``; the strip's max is
-        the max of its bucket maxima, read once. ``guard="row"``
-        (default): row b improves iff ``max(s[b]) > min(run_s[b])``; the
-        strip is skipped iff *no* row improves (a strictly weaker skip
-        condition than the legacy ``guard="batch"`` global compare, so
-        mixed batches skip more often, never less) and the merge writes
-        back only improving rows. Results are bit-identical either way:
-        for a non-improving row the merge is already a no-op — strict
-        guard, and ascending-id strips lose first-occurrence ties.
-        Skipping on equality is exact for the same ascending-id reason.
+        scan's xs) and each is scored by ``score_rows`` as the rows' dtype
+        selects: f32 rows by an f32 matmul at ``HIGHEST``; int8 or bf16
+        rows, which bf16 holds exactly, convert to bf16 and meet the
+        query's three-term bf16 split (``query_terms``, made once before
+        the loop) in one matmul with f32 accumulation — no full-index
+        shadow copy, no strip in f32;
+      * deferred bucketed select (``_select_plan``: k·W ≤ block / 8 with
+        W = 128, over two strips or more): each strip keeps only its
+        per-bucket maxima, and the select runs once per call after the
+        loop (``_deferred_select``) — no per-strip ``top_k``, sort or
+        ``lax.cond``. ``guard`` selects nothing there;
+      * otherwise, a direct select: ``top_k`` over each strip, then a tiny
+        (B, 2k) merge with a running list, behind a block-skip guard: a
+        strip that cannot improve the running top-k skips selection under
+        ``lax.cond``. ``guard="row"`` (default): row b improves iff
+        ``max(s[b]) > min(run_s[b])``; the strip is skipped iff *no* row
+        improves (a strictly weaker skip condition than the legacy
+        ``guard="batch"`` global compare, so mixed batches skip more
+        often, never less) and the merge writes back only improving rows.
+        Results are bit-identical either way: for a non-improving row the
+        merge is already a no-op — strict guard, and ascending-id strips
+        lose first-occurrence ties. Skipping on equality is exact for the
+        same ascending-id reason.
+
+    Both selects give the same scores and ids, ties included.
 
     The index is never padded or copied: when ``block`` does not divide
     n, the last strip slides back to end at row n and masks the rows the
     previous strip already scored.
 
-    ``vma_axes``: when called inside shard_map over those axes, the scan
-    carry must be marked varying (compat.mark_varying) to typecheck.
+    ``vma_axes``: when called inside shard_map over those axes, the direct
+    path's scan carry must be marked varying (compat.mark_varying) to
+    typecheck.
 
     Device scopes (``jax.named_scope``: each op's HLO ``op_name`` holds
     ``/score/`` or ``/select/``, names a profile can follow across
     refactors): ``score`` is the query's split (once, before the loop)
-    and the strip's slice, conversion, matmul and mask;
-    ``select`` the improvement test and the whole guarded merge, per-strip
-    ``top_k`` included. On the bucketed path ``select`` nests ``bucket``
-    (the bucket maxima) and ``refine`` (the guarded merge: bucket
-    ``top_k``, gather, merge).
+    and the strip's slice, conversion, matmul and mask. On the direct
+    path ``select`` is the improvement test and the whole guarded merge,
+    per-strip ``top_k`` included. On the deferred path ``select`` nests
+    ``bucket`` (the strip's maxima, in the loop) and ``refine`` (once,
+    after it: the ``top_k`` of the maxima, the sort, the rescore of the
+    kept buckets and the final ``top_k``).
     ``project_queries`` adds ``project``; ``ShardedDenseIndex``'s id
     offset, pad mask and cut and ``_staged_topk_merge`` add ``merge``.
     """
@@ -238,37 +287,25 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
                     [jnp.full((B, k), -1, jnp.int32), ids], axis=1)
             return _topk_merge(s, ids, k)
 
+    starts = jnp.arange(nblocks, dtype=jnp.int32) * block
+    if W:
+        return _deferred_select(D, qt, k, block, W, starts)
+
     def body(carry, start):
         bs, bi = carry
-        with jax.named_scope("score"):
-            at = jnp.minimum(start, n - block)
-            blk = jax.lax.dynamic_slice_in_dim(D, at, block, axis=0)
-            s = score_rows(qt, blk)                              # (B, block)
-            ids = at + jnp.arange(block, dtype=jnp.int32)[None, :]
-            s = jnp.where(ids >= start, s, -jnp.inf)
+        s, at = _score_strip(D, qt, start, block)
 
         with jax.named_scope("select"):
-            if W:
-                with jax.named_scope("bucket"):
-                    # one (B, block / W, W) view for the maxima and the
-                    # gather: the TPU relayouts the strip once
-                    s = s.reshape(B, block // W, W)
-                    smax = s.max(axis=2)                         # (B, nb)
-            else:
-                smax = s
-            imp = jnp.max(smax, axis=1) > jnp.min(bs, axis=1)    # (B,)
+            imp = jnp.max(s, axis=1) > jnp.min(bs, axis=1)       # (B,)
 
             def merge(carry_in):
                 bs0, bi0 = carry_in
-                if W:
-                    ss, gi = _bucket_candidates(s, smax, at, k)
-                else:
-                    ss, si = jax.lax.top_k(s, kk)                # (B, kk)
-                    gi = at + si.astype(jnp.int32)
+                ss, si = jax.lax.top_k(s, kk)                    # (B, kk)
                 # running list first: at -inf ties its (-1) pads win the
                 # first-occurrence tie-break, matching the kernel's pads
                 cs = jnp.concatenate([bs0, ss], axis=1)
-                ci = jnp.concatenate([bi0, gi], axis=1)
+                ci = jnp.concatenate([bi0, at + si.astype(jnp.int32)],
+                                     axis=1)
                 ms, mi = _topk_merge(cs, ci, k)
                 if guard == "row":
                     # masked merge: non-improving rows keep their list
@@ -280,16 +317,13 @@ def _scan_topk(D: jax.Array, Q: jax.Array, k: int, block: int = 65536,
             if guard == "row":
                 can_improve = jnp.any(imp)
             else:
-                can_improve = jnp.max(smax) > jnp.min(bs)
-            with (jax.named_scope("refine") if W
-                  else contextlib.nullcontext()):
-                return jax.lax.cond(can_improve, merge, lambda c: c,
-                                    (bs, bi)), None
+                can_improve = jnp.max(s) > jnp.min(bs)
+            return jax.lax.cond(can_improve, merge, lambda c: c,
+                                (bs, bi)), None
 
     init = (jnp.full((B, k), -jnp.inf, jnp.float32), jnp.full((B, k), -1, jnp.int32))
     if vma_axes:
         init = compat.mark_varying(init, vma_axes)
-    starts = jnp.arange(nblocks, dtype=jnp.int32) * block
     (scores, ids), _ = jax.lax.scan(body, init, starts)
     return scores, ids
 

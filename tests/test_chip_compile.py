@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from hlo_text import loop_instructions
 from repro.core.index import ShardedDenseIndex, _dense_search_projected, _fill_rows, project_queries
 from repro.kernels import ops
 from repro.kernels.topk_score import topk_score_paged_pallas, topk_score_pallas
@@ -117,7 +118,8 @@ def test_served_dispatch_fits_one_chip_at_msmarco_scale(one_chip, compiled_kerne
     to f32, which this replaced, compiled to 485,888 temp bytes (JAX
     0.9); a (96, 65,536) f32 product or a tripled strip in HBM would add
     at least 25,165,824 B to that. f32 rows keep the f32 dot at
-    ``HIGHEST``."""
+    ``HIGHEST``. The select runs once after the strip loop: no
+    conditional is left in it."""
     scale = _spec(one_chip, (M,), jnp.float32) if dtype == jnp.int8 else None
     c = jax.jit(lambda d, s, w, q: _dense_search_projected(
         d, s, w, None, q, K, None, backend)).lower(
@@ -131,6 +133,8 @@ def test_served_dispatch_fits_one_chip_at_msmarco_scale(one_chip, compiled_kerne
     if backend == "pallas":
         return
     [(line, operands)] = _score_dots(hlo)
+    loop = loop_instructions(hlo, "/score/dot_general")
+    assert "conditional" not in {op for op, _ in loop}
     if dtype == jnp.int8:
         assert temp < 485_888 + 96 * 65536 * 4
         assert operands == [f"bf16[3,{B},{M}]", f"s8[65536,{M},1]"]
@@ -205,4 +209,8 @@ def test_unpruned_sharded_search_at_published_widths_on_v5e_2x2(topo):
     # it (518,144 B with JAX 0.9)
     strip = 65536 * D_IN * 4
     assert mem.temp_size_in_bytes < strip
-    assert "all-gather" in c.as_text()
+    hlo = c.as_text()
+    assert "all-gather" in hlo
+    # the select runs once after the strip loop
+    loop = loop_instructions(hlo, "/score/dot_general")
+    assert "conditional" not in {op for op, _ in loop}

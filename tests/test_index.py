@@ -165,6 +165,109 @@ def test_bucketed_select_sharded_one_device(monkeypatch):
         assert (_bits(g) == _bits(w)).all()
 
 
+def _strips_n(nstrips, block=8192):
+    """Rows for ``nstrips`` strips of ``block``: the last one slides back
+    and masks all but 1,000 of its rows."""
+    return (nstrips - 1) * block + 1000
+
+
+def _layout_data(layout, nstrips, B, dtype, rng, block=8192):
+    """``_tied_data`` over ``nstrips`` strips, where ``layout`` places the
+    best rows of every query (every layout but ``"tied"`` draws positive
+    queries, so that a row of larger values scores higher):
+
+    * ``"tied"``: as drawn, ties everywhere;
+    * ``"straddle"``: the k=8 best rows, tied, in the live part of the last
+      strip's straddling bucket (the bucket that holds rows the last strip
+      masks and rows it scores), the next best in its masked part;
+    * ``"straddle_masked"``: the 6 best rows there, and the 7th best in
+      its masked part, so a rescore that dropped the mask would return
+      that row twice;
+    * ``"shared_max"``: one best row copied into five buckets of every
+      strip, so more than k buckets across strips share one maximum.
+
+    Returns (D, Q, the expected leading ids of every query or None)."""
+    n = _strips_n(nstrips, block)
+    D, Q = _tied_data(n, 16, B, dtype, rng)
+    if layout == "tied":
+        return D, Q, None
+    from repro.core.index import BUCKET
+    D = np.array(D)
+    Q = jnp.asarray(rng.integers(1, 3, (B, 16)).astype(np.float32))
+    start, at = (nstrips - 1) * block, n - block
+    assert (start - at) % BUCKET == 24         # masked rows open the bucket
+    if layout == "straddle":
+        best = start + np.arange(8)
+        assert len({(r - at) // BUCKET for r in best}) == 1
+        D[start - 24:start] = 3
+        D[best] = 4
+    elif layout == "straddle_masked":
+        best = np.append(start + np.arange(6), start - 1)
+        D[best[:6]] = 4
+        D[start - 1] = 3
+    else:
+        best = np.sort([j * block + off for j in range(nstrips)
+                        for off in (100, 300, 500, 700, 900)])[:8]
+        D[best] = 4
+    return jnp.asarray(D), Q, best
+
+
+@pytest.mark.parametrize("B", [1, 32])
+@pytest.mark.parametrize("dtype", ["f32", "int8"])
+@pytest.mark.parametrize("nstrips", [2, 5])
+@pytest.mark.parametrize("layout", ["tied", "straddle", "straddle_masked",
+                                    "shared_max"])
+def test_deferred_select_bitwise_matches_direct(monkeypatch, layout, nstrips,
+                                                dtype, B):
+    """The select deferred to once a call returns the direct path's scores
+    (to the bit) and ids, ties included: two strips (one full, the last
+    sliding and masked) and five, with the top-k in the straddling bucket
+    of the last strip, with a row of its masked part among the top-k, and
+    with the k best buckets sharing one maximum across strips."""
+    from repro.core.index import BUCKET, _scan_topk, _select_plan
+    assert _select_plan(8, 8192) == BUCKET
+    D, Q, best = _layout_data(layout, nstrips, B, dtype,
+                              np.random.default_rng(nstrips * 100 + B))
+    got = _scan_topk(D, Q, 8, block=8192)
+    want = _direct_scan(monkeypatch, D, Q, k=8, block=8192)
+    for g, w in zip(got, want):
+        assert (_bits(g) == _bits(w)).all()
+    if best is not None:
+        assert (np.asarray(got[1])[:, :len(best)] == best[None, :]).all()
+
+
+@pytest.mark.parametrize("layout", ["tied", "straddle", "straddle_masked",
+                                    "shared_max"])
+def test_deferred_select_sharded_one_device(monkeypatch, layout):
+    """``ShardedDenseIndex``'s per-shard scan (under shard_map, no carry
+    left to mark varying) defers the select too, with the direct path's
+    bits."""
+    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    D, Q, _ = _layout_data(layout, 2, 32, "f32", np.random.default_rng(4))
+    got = ShardedDenseIndex.build(D, mesh).search(Q, k=8, block=8192)
+    want = _direct_scan(monkeypatch, D, Q, k=8, block=8192)
+    for g, w in zip(got, want):
+        assert (_bits(g) == _bits(w)).all()
+
+
+@pytest.mark.parametrize("nstrips", [2, 5])
+def test_deferred_select_untied_within_f32_bound(monkeypatch, nstrips):
+    """On untied float data the deferred select returns the direct path's
+    ids, with every score within the f32 accumulation bound of its
+    direct score."""
+    from repro.core.index import _scan_topk
+    rng = np.random.default_rng(15)
+    D = rng.standard_normal((_strips_n(nstrips), 32)).astype(np.float32)
+    Q = rng.standard_normal((32, 32)).astype(np.float32)
+    s1, i1 = _scan_topk(jnp.asarray(D), jnp.asarray(Q), 10, block=8192)
+    s0, i0 = _direct_scan(monkeypatch, jnp.asarray(D), jnp.asarray(Q),
+                          k=10, block=8192)
+    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i0))
+    bound = _f32_bound(Q.astype(np.float64), D.astype(np.float64))
+    gap = np.abs(np.asarray(s1, np.float64) - np.asarray(s0, np.float64))
+    assert (gap <= np.take_along_axis(bound, np.asarray(i0), 1)).all()
+
+
 @pytest.mark.parametrize("k,block,engaged", [
     (10, 65536, True),       # the served shape
     (8, 8192, True),
@@ -178,23 +281,31 @@ def test_select_plan(k, block, engaged):
 
 
 def test_bucketed_select_scopes_in_hlo():
-    """When the path engages, the compiled scan's op_names nest
-    ``/select/bucket/`` and ``/select/refine/`` under ``select``; the
-    direct path has neither."""
-    import re
+    """On the bucketed path the compiled strip loop scores the strip and
+    takes its ``/select/bucket/`` maxima, and holds no conditional, no
+    sort and no TopK; every ``/select/refine/`` op lies outside it. The
+    direct path keeps its guarded conditional and its ``/select/`` ops in
+    the loop, and has neither nested scope."""
+    from hlo_text import loop_instructions
     from repro.core.index import _scan_topk
     D, Q = _data(3 * 8192 + 1000, 16)
 
-    def names(block):
-        hlo = _scan_topk.lower(D, Q, 8, block=block).compile().as_text()
-        return re.findall(r'op_name="([^"]*)"', hlo)
+    def compiled(block):
+        return _scan_topk.lower(D, Q, 8, block=block).compile().as_text()
 
-    bucketed = names(8192)
-    assert any("/select/bucket/" in n for n in bucketed)
-    assert any("/select/refine/" in n for n in bucketed)
-    direct = names(256)
-    assert any("/select/" in n for n in direct)
-    assert not any("/bucket/" in n or "/refine/" in n for n in direct)
+    bucketed = compiled(8192)
+    loop = loop_instructions(bucketed, "/select/bucket/")
+    assert any("/score/" in line for _, line in loop)
+    assert not {"conditional", "sort", "topk"} & {op for op, _ in loop}
+    assert not any("TopK" in line or "/select/refine/" in line
+                   for _, line in loop)
+    assert "/select/refine/" in bucketed
+    direct = compiled(256)
+    loop = loop_instructions(direct, "/score/")
+    assert "conditional" in {op for op, _ in loop}
+    assert any("/select/" in line for _, line in loop)
+    names = re.findall(r'op_name="([^"]*)"', direct)
+    assert not any("/bucket/" in n or "/refine/" in n for n in names)
 
 
 # ---------------------------------------------------------------------------
